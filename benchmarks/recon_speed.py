@@ -80,6 +80,7 @@ from repro.core import recon_engine as RE
 from repro.core import tesseraq as TQ
 from repro.core.blocks import build_stages
 from repro.core.rtn import quantize_block_rtn
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import dp_size, make_data_mesh, make_mesh, tp_size
 from repro.models import get_model
 
@@ -398,4 +399,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

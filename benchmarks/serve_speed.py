@@ -89,6 +89,7 @@ from repro.core import pack_model, quantize_model
 from repro.core.qtensor import QTensor
 from repro.data.pipeline import DataConfig, SyntheticCorpus, calibration_batches
 from repro.eval.harness import parity_gate
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.scheduler import (Request, compile_sched_steps,
                                     make_workload, serve_lockstep,
                                     serve_scheduled)
@@ -641,4 +642,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -5,11 +5,18 @@ Per linear: grid-search (1) the equivalent-transformation exponent alpha for
 the per-input-channel scale  s_ch = mean|X|^alpha / norm , and (2) a clipping
 shrink factor on the group min/max — both against the layer reconstruction
 objective  || (X/s_ch) Q(W*s_ch) - X W ||_F^2  on a captured token subsample.
+
+The candidates are scored on the device, all of a linear's in one program
+(one host read per linear): each is a full fake-quantize and a
+(rows x in x out) product, so scoring on the host would move every
+candidate weight off the device and multiply it there.
 """
 from __future__ import annotations
 
+import functools
 import warnings
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 
@@ -27,6 +34,33 @@ def _act_scale(mean_abs: np.ndarray, alpha: float) -> np.ndarray:
     return np.clip(s, 1e-4, 1e4).astype(np.float32)
 
 
+def _layer_out(X, w):
+    """(n, in) x (..., in, out) in full f32 precision."""
+    hi = jax.lax.Precision.HIGHEST
+    if w.ndim == 2:
+        return jnp.matmul(X, w, precision=hi)
+    return jnp.einsum("ni,eio->eno", X, w, precision=hi)
+
+
+
+def _candidate_err(X, wf, y_ref, s_ch, clip, qcfg: QuantConfig):
+    """Layer reconstruction error of one (alpha, clip) candidate."""
+    fq = Q.fake_quantize(wf * s_ch[..., :, None], qcfg, gamma=clip,
+                         beta=clip)
+    y = _layer_out(X, fq / s_ch[..., :, None])
+    return jnp.mean(jnp.square(y - y_ref))
+
+
+@functools.partial(jax.jit, static_argnames=("qcfg",))
+def _candidate_errs(X, wf, s_all, clips, *, qcfg: QuantConfig):
+    """Errors of every candidate, one after another (``lax.map``, so only
+    one candidate's fake-quantized weight is live at a time)."""
+    y_ref = _layer_out(X, wf)
+    return jax.lax.map(
+        lambda sc: _candidate_err(X, wf, y_ref, sc[0], sc[1], qcfg),
+        (s_all, clips))
+
+
 def awq_leaf(w, stats, qcfg: QuantConfig):
     """Returns (fake-quant effective weight, qmeta).  w: (..., in, out)."""
     wf = np.asarray(w, np.float32)
@@ -35,21 +69,17 @@ def awq_leaf(w, stats, qcfg: QuantConfig):
         # no activations seen (shouldn't happen) -> fall back to RTN
         from repro.core.rtn import rtn_leaf
         return rtn_leaf(w, qcfg)
-    y_ref = X @ wf if wf.ndim == 2 else np.einsum("ni,eio->eno", X, wf)
-
-    best = (None, None, np.inf)
-    for alpha in ALPHA_GRID:
-        s_ch = _act_scale(stats.mean_abs, alpha)
-        wt = wf * s_ch[..., :, None]
-        for clip in CLIP_GRID:
-            fq = np.asarray(Q.fake_quantize(jnp.asarray(wt), qcfg,
-                                            gamma=clip, beta=clip))
-            w_eff = fq / s_ch[..., :, None]
-            y = X @ w_eff if wf.ndim == 2 else np.einsum("ni,eio->eno", X, w_eff)
-            err = float(np.mean((y - y_ref) ** 2))
-            if err < best[2]:
-                best = (alpha, clip, err)
-    alpha, clip, _ = best
+    grid = [(a, c) for a in ALPHA_GRID for c in CLIP_GRID]
+    s_all = np.stack([_act_scale(stats.mean_abs, a) for a, _ in grid])
+    clips = np.asarray([c for _, c in grid], np.float32)
+    # reprolint: ok[host-sync] — one read of the candidate errors per linear
+    errs = np.asarray(jax.device_get(_candidate_errs(
+        jax.device_put(X), jax.device_put(wf), jax.device_put(s_all),
+        jax.device_put(clips), qcfg=qcfg)))
+    # the first finite minimum in grid order; non-finite candidates never win
+    errs = np.where(np.isnan(errs), np.inf, errs)
+    i = int(np.argmin(errs))
+    alpha, clip = grid[i] if np.isfinite(errs[i]) else (None, None)
     if alpha is None:
         # every (alpha, clip) candidate scored non-finite (degenerate
         # capture stats — NaN/inf activations); fall back to the identity

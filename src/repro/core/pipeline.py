@@ -88,8 +88,9 @@ def quantize_model(cfg: ModelConfig, params: Dict, batches: List[Dict],
     Parallelism (``engine="sharded"``): reconstruction runs data-parallel
     over the mesh's DP axes; a ``model`` axis additionally shards each
     block's weights, rounding/DST variables and Adam state per the
-    ``launch.sharding.ParamSpec`` contract (tensor parallelism — TP=1 is
-    bit-identical to ``engine="device"``); a ``pod`` axis pipelines the
+    ``launch.sharding.ParamSpec`` contract (tensor parallelism — TP=1
+    reproduces ``engine="device"`` masks and codes at the tests' widths,
+    not at published ones: ROADMAP C2); a ``pod`` axis pipelines the
     block walk itself across pods (block k+1's capture overlaps block k's
     reconstruction on the next pod's devices), with per-stage wall-clock
     profiling in ``report["pipeline"]``.

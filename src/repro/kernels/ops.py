@@ -90,7 +90,12 @@ def _snap_block_k(block_k, K, group_size, ppb, bits):
         # to a divisor of the (larger) group
         bk = ((bk // group_size) * group_size if bk > group_size
               else math.gcd(bk, group_size))
-    # after the snap one of (bk, group_size) divides the other, so their
+    gpt = bk // group_size
+    if gpt > 1 and gpt % 8 and 8 % gpt:
+        # whole groups per tile must be a divisor or a multiple of 8 so the
+        # kernels can slice their scale rows at sublane-aligned offsets
+        bk = group_size << (gpt.bit_length() - 1)
+    # after the snaps one of (bk, group_size) divides the other, so their
     # max is their lcm: pad K to it and both the tile grid and the group
     # rows stay aligned
     align = max(bk, group_size)

@@ -18,8 +18,8 @@ Differences from the prefill kernel:
     multiply-reduce on the VPU avoids padding 1..4 rows up to the MXU's
     8-row granularity.
 
-Same group/tile contract as quant_matmul (bk % g == 0 or g % bk == 0),
-enforced by the wrapper in ops.py.
+Same group/tile contract and tile helpers as quant_matmul
+(``check_group_tile``), enforced by the wrapper in ops.py.
 """
 from __future__ import annotations
 
@@ -31,7 +31,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.qtensor import PACK_FACTOR
-from repro.kernels.quant_matmul import _CompilerParams, _unpack_tile
+from repro.kernels.quant_matmul import (check_group_tile, dequant_tile,
+                                        strip_rows, tile_group_rows)
 
 # below this many activation rows the MXU tile padding costs more than the
 # VPU broadcast-multiply-reduce; decode with a handful of busy slots lands here
@@ -40,27 +41,17 @@ _VPU_MAX_ROWS = 4
 
 def _gemv_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *,
                  bits: int, nk: int, bk: int, group_size: int):
-    ppb = PACK_FACTOR[bits]
-    fbits = 8 // ppb
     k = pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = _unpack_tile(p_ref[...], ppb, fbits)               # (bk, bn)
-    bn = codes.shape[1]
-    # K-resident scales: slice this K tile's group rows out of the full strip
-    gpt = max(bk // group_size, 1)
-    row0 = (k * bk) // group_size
-    s = pl.load(s_ref, (pl.dslice(row0, gpt), slice(None)))    # (gpt, bn)
-    z = pl.load(z_ref, (pl.dslice(row0, gpt), slice(None)))
-    cg = codes.reshape(gpt, bk // gpt, bn).astype(jnp.float32)
-    # round dequantized weights to the activation dtype BEFORE the product —
-    # the same contract as quant_matmul and the XLA path's dequantize(x.dtype),
-    # so backend parity stays a rounding-order question, not a dtype question
-    w = ((cg - z[:, None, :]) * s[:, None, :]).reshape(bk, bn) \
-        .astype(x_ref.dtype)
+    # K-resident scales: this K tile's group rows come out of the full strip
+    rows = functools.partial(tile_group_rows, k=k, bk=bk,
+                             group_size=group_size)
+    w = dequant_tile(p_ref[...], rows(s_ref), rows(z_ref), bits=bits,
+                     dtype=x_ref.dtype)
     x = x_ref[...]
     if x.shape[0] <= _VPU_MAX_ROWS:
         # bf16 x bf16 products are exact in f32, so this differs from the
@@ -109,11 +100,9 @@ def quant_gemv(x: jax.Array, packed: jax.Array, scale: jax.Array,
             "ng % tp == 0)")
     bn, bk = min(block_n, N), min(block_k, K)
     assert N % bn == 0 and K % bk == 0, (N, K, bn, bk)
-    if bk % group_size and group_size % bk:
-        raise ValueError(f"bk={bk} and group_size={group_size} must divide "
-                         "one another")
+    check_group_tile(bk, group_size)
     nk = K // bk
-    ng = K // group_size
+    ns = strip_rows(K // group_size)
 
     kernel = functools.partial(_gemv_kernel, bits=bits, nk=nk, bk=bk,
                                group_size=group_size)
@@ -124,13 +113,13 @@ def quant_gemv(x: jax.Array, packed: jax.Array, scale: jax.Array,
             pl.BlockSpec((M, bk), lambda j, k: (0, k)),
             pl.BlockSpec((bk // ppb, bn), lambda j, k: (k, j)),
             # full K strip of scales per N tile, sliced in-kernel
-            pl.BlockSpec((ng, bn), lambda j, k: (0, j)),
-            pl.BlockSpec((ng, bn), lambda j, k: (0, j)),
+            pl.BlockSpec((ns, bn), lambda j, k: (0, j)),
+            pl.BlockSpec((ns, bn), lambda j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((M, bn), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((M, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, packed, scale, zero)

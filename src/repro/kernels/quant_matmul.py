@@ -14,6 +14,11 @@ to the TPU memory hierarchy:
 
 Group boundaries must align with the K tile (bk % group_size == 0 or
 group_size % bk == 0), enforced by the wrapper in ops.py.
+
+Mosaic constraints the layout follows: uint8 codes widen to int32 before any
+shift or float cast (the chip has no uint8 -> float convert), and scales and
+zeros arrive as a K-resident ``(ng, bn)`` strip whose rows are sliced in the
+kernel at sublane-aligned offsets (see :func:`tile_group_rows`).
 """
 from __future__ import annotations
 
@@ -26,81 +31,102 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.qtensor import PACK_FACTOR
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+_SUBLANES = 8     # f32 rows per vreg: dynamic row offsets must align to it
 
 
-def _unpack_tile(p, ppb: int, fbits: int):
-    """(bk//ppb, bn) uint8 -> (bk, bn) uint8 codes, matching qtensor.pack."""
+def unpack_tile(p, ppb: int, fbits: int):
+    """(bk//ppb, bn) uint8 -> (bk, bn) int32 codes, matching qtensor.pack."""
+    p = p.astype(jnp.int32)
     mask = (1 << fbits) - 1
     parts = [(p >> (f * fbits)) & mask for f in range(ppb)]
     w = jnp.stack(parts, axis=1)                 # (bk//ppb, ppb, bn)
     return w.reshape(p.shape[0] * ppb, p.shape[1])
 
 
-def _qmm_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *,
-                bits: int, nk: int, groups_per_tile: int):
-    ppb = PACK_FACTOR[bits]
-    fbits = 8 // ppb
+def strip_rows(ng: int) -> int:
+    """Row extent of the K-resident scale/zero block: ``ng`` rounded up to
+    whole sublane tiles, so an aligned 8-row window never leaves it (rows
+    past ``ng`` are edge padding and never selected)."""
+    return -(-ng // _SUBLANES) * _SUBLANES
 
-    @pl.when(pl.program_id(2) == 0)
+
+def tile_group_rows(ref, k, *, bk: int, group_size: int):
+    """The (gpt, bn) scale/zero rows of K tile ``k`` out of a K-resident
+    ``(strip_rows(ng), bn)`` strip, ``gpt = max(bk // group_size, 1)``.
+
+    The chip loads dynamic row ranges only at multiples of 8 (or a single
+    row), so 2 or 4 rows per tile come out of their aligned 8-row window
+    by a static select."""
+    if group_size >= bk:
+        # the tile sits inside one group: one row, advancing every
+        # group_size // bk K steps
+        return ref[pl.ds((k * bk) // group_size, 1), :]
+    gpt = bk // group_size
+    row0 = k * gpt
+    if gpt % _SUBLANES == 0:
+        return ref[pl.ds(pl.multiple_of(row0, _SUBLANES), gpt), :]
+    win = ref[pl.ds(pl.multiple_of((row0 // _SUBLANES) * _SUBLANES,
+                                   _SUBLANES), _SUBLANES), :]
+    off = row0 % _SUBLANES
+    rows = win[:gpt]
+    for o in range(gpt, _SUBLANES, gpt):
+        rows = jnp.where(off == o, win[o:o + gpt], rows)
+    return rows
+
+
+def dequant_tile(packed, s, z, *, bits: int, dtype):
+    """Unpack a (bk//ppb, bn) packed tile and dequantize it with its
+    (gpt, bn) scale/zero rows into ``dtype`` (the activation dtype).
+
+    Scales and zeros round to ``dtype`` first and the result rounds once
+    more, exactly as the XLA path's ``QTensor.dequantize(x.dtype)`` does:
+    with integer codes, ``code - zero`` is exact and the product of two
+    ``dtype`` values is exact in f32, so both backends build bit-identical
+    weights and differ only in the matmul's accumulation order."""
+    ppb = PACK_FACTOR[bits]
+    codes = unpack_tile(packed, ppb, 8 // ppb)                 # (bk, bn)
+    bk, bn = codes.shape
+    gpt = s.shape[0]
+    s = s.astype(dtype).astype(jnp.float32)
+    z = z.astype(dtype).astype(jnp.float32)
+    cg = codes.reshape(gpt, bk // gpt, bn).astype(jnp.float32)
+    w = (cg - z[:, None, :]) * s[:, None, :]
+    return w.reshape(bk, bn).astype(dtype)
+
+
+def _qmm_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *,
+                bits: int, nk: int, bk: int, group_size: int, k_axis: int):
+    """One (bm, bn) output tile, accumulated over the K grid axis
+    ``k_axis`` (2, or 3 when a squeezed expert axis leads the grid)."""
+    k = pl.program_id(k_axis)
+
+    @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = _unpack_tile(p_ref[...], ppb, fbits)               # (bk, bn) uint8
-    bk, bn = codes.shape
-    g = bk // groups_per_tile
-    cg = codes.reshape(groups_per_tile, g, bn).astype(jnp.float32)
-    w = (cg - z_ref[...][:, None, :]) * s_ref[...][:, None, :]
-    w = w.reshape(bk, bn).astype(x_ref.dtype)
+    rows = functools.partial(tile_group_rows, k=k, bk=bk,
+                             group_size=group_size)
+    w = dequant_tile(p_ref[...], rows(s_ref), rows(z_ref), bits=bits,
+                     dtype=x_ref.dtype)
     acc_ref[...] += jnp.dot(x_ref[...], w,
                             preferred_element_type=jnp.float32)
 
-    @pl.when(pl.program_id(2) == nk - 1)
+    @pl.when(k == nk - 1)
     def _done():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _qmm_expert_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *,
-                       bits: int, nk: int, groups_per_tile: int):
-    """Expert-batched variant: every ref carries a leading singleton expert
-    dim and the K grid axis moves to program_id(3)."""
-    ppb = PACK_FACTOR[bits]
-    fbits = 8 // ppb
-
-    @pl.when(pl.program_id(3) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    codes = _unpack_tile(p_ref[0], ppb, fbits)                 # (bk, bn)
-    bk, bn = codes.shape
-    g = bk // groups_per_tile
-    cg = codes.reshape(groups_per_tile, g, bn).astype(jnp.float32)
-    w = (cg - z_ref[0][:, None, :]) * s_ref[0][:, None, :]
-    w = w.reshape(bk, bn).astype(x_ref.dtype)
-    acc_ref[...] += jnp.dot(x_ref[0], w,
-                            preferred_element_type=jnp.float32)
-
-    @pl.when(pl.program_id(3) == nk - 1)
-    def _done():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _group_tile_index(bk: int, group_size: int):
-    """Scale/zero (rows_per_tile, row_index_fn(k)) for the two alignment
-    branches shared by the single and expert-batched kernels."""
-    if bk % group_size == 0:
-        # small groups: >=1 whole group per K tile, scale rows advance with k
-        return bk // group_size, lambda k: k
-    if group_size % bk == 0:
-        # large groups spanning several K tiles: each tile sits inside ONE
-        # group, so a single scale/zero row is fetched and the row index
-        # advances once every (group_size // bk) K steps
-        tiles_per_group = group_size // bk
-        return 1, lambda k: k // tiles_per_group
-    raise ValueError(f"bk={bk} and group_size={group_size} must divide "
-                     "one another")
+def check_group_tile(bk: int, group_size: int):
+    """The group/tile contract of both kernels: one of bk, group_size
+    divides the other, and a tile holding several groups holds a divisor
+    or a multiple of 8 of them (``tile_group_rows``'s aligned windows)."""
+    if bk % group_size and group_size % bk:
+        raise ValueError(f"bk={bk} and group_size={group_size} must divide "
+                         "one another")
+    gpt = bk // group_size
+    if gpt > 1 and gpt % _SUBLANES and _SUBLANES % gpt:
+        raise ValueError(f"bk={bk} holds {gpt} groups of {group_size}; "
+                         f"need a divisor or a multiple of {_SUBLANES}")
 
 
 def quant_matmul(x: jax.Array, packed: jax.Array, scale: jax.Array,
@@ -136,26 +162,27 @@ def quant_matmul(x: jax.Array, packed: jax.Array, scale: jax.Array,
             "requires ng % tp == 0)")
     bm, bn, bk = min(block_m, M), min(block_n, N), min(block_k, K)
     assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
-    gpt, row_of = _group_tile_index(bk, group_size)
-    sz_index = lambda i, j, k: (row_of(k), j)
+    check_group_tile(bk, group_size)
     nk = K // bk
+    ns = strip_rows(K // group_size)
 
     grid = (M // bm, N // bn, nk)
-    kernel = functools.partial(_qmm_kernel, bits=bits, nk=nk,
-                               groups_per_tile=gpt)
+    kernel = functools.partial(_qmm_kernel, bits=bits, nk=nk, bk=bk,
+                               group_size=group_size, k_axis=2)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // ppb, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((gpt, bn), sz_index),
-            pl.BlockSpec((gpt, bn), sz_index),
+            # K-resident scale/zero strips: fetched once per (i, j)
+            pl.BlockSpec((ns, bn), lambda i, j, k: (0, j)),
+            pl.BlockSpec((ns, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, packed, scale, zero)
@@ -188,25 +215,28 @@ def quant_matmul_experts(x: jax.Array, packed: jax.Array, scale: jax.Array,
             f"inconsistent with (E={E}, K={K}, group_size={group_size})")
     bm, bn, bk = min(block_m, M), min(block_n, N), min(block_k, K)
     assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
-    gpt, row_of = _group_tile_index(bk, group_size)
+    check_group_tile(bk, group_size)
     nk = K // bk
+    ns = strip_rows(ng)
 
+    # the expert dim is squeezed out of every block, so the body is the
+    # single-matrix kernel with its K axis moved to program_id(3)
+    kernel = functools.partial(_qmm_kernel, bits=bits, nk=nk, bk=bk,
+                               group_size=group_size, k_axis=3)
     grid = (E, M // bm, N // bn, nk)
-    kernel = functools.partial(_qmm_expert_kernel, bits=bits, nk=nk,
-                               groups_per_tile=gpt)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda e, i, j, k: (e, i, k)),
-            pl.BlockSpec((1, bk // ppb, bn), lambda e, i, j, k: (e, k, j)),
-            pl.BlockSpec((1, gpt, bn), lambda e, i, j, k: (e, row_of(k), j)),
-            pl.BlockSpec((1, gpt, bn), lambda e, i, j, k: (e, row_of(k), j)),
+            pl.BlockSpec((None, bm, bk), lambda e, i, j, k: (e, i, k)),
+            pl.BlockSpec((None, bk // ppb, bn), lambda e, i, j, k: (e, k, j)),
+            pl.BlockSpec((None, ns, bn), lambda e, i, j, k: (e, 0, j)),
+            pl.BlockSpec((None, ns, bn), lambda e, i, j, k: (e, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j)),
+        out_specs=pl.BlockSpec((None, bm, bn), lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

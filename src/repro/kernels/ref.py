@@ -4,17 +4,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.qtensor import PACK_FACTOR, unpack
+from repro.core.qtensor import PACK_FACTOR, QTensor
 
 
 def quant_matmul_ref(x, packed, scale, zero, *, bits: int, group_size: int):
-    K = packed.shape[0] * PACK_FACTOR[bits]
-    codes = unpack(packed, bits, K, axis=0).astype(jnp.float32)
-    ng = K // group_size
-    cg = codes.reshape(ng, group_size, -1)
-    w = (cg - zero[:, None, :]) * scale[:, None, :]
-    w = w.reshape(K, -1).astype(x.dtype)
-    return (x @ w).astype(x.dtype)
+    """The XLA serve path: ``QTensor.dequantize(x.dtype)``, then one
+    matmul."""
+    shape = (packed.shape[0] * PACK_FACTOR[bits], packed.shape[1])
+    w = QTensor(packed, scale, zero, bits, group_size, shape)
+    return (x @ w.dequantize(x.dtype)).astype(x.dtype)
 
 
 def int8_matmul_ref(x_q, w_q, x_scale, w_scale, *, out_dtype=jnp.bfloat16):

@@ -159,8 +159,6 @@ class RooflineTerms:
 def cost_terms(compiled, hlo_text: str, chips: int, default_group: int,
                scale: float = 1.0) -> Dict:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):           # older jax: one dict per program
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0)) * scale
     bts = float(ca.get("bytes accessed", 0.0)) * scale
     coll = collective_bytes(hlo_text, default_group)

@@ -12,13 +12,10 @@ from jax.sharding import PartitionSpec as P
 
 
 def _mk(shape, axes):
-    """jax.make_mesh across versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer jax."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated), the
+    mode every sharding rule in ``launch/sharding.py`` is written for."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -48,10 +45,9 @@ def make_data_mesh(n_devices=None):
     the caller does not hand one in — on a host platform forced to N devices
     this is the N-way calibration mesh the CI multi-device job exercises.
 
-    Memoized per device set: distinct-but-equal Mesh objects defeat jit's
-    tracing cache on jax 0.4.x, so every caller that resolves the default
-    mesh twice (e.g. one reconstruction per block) must get the SAME object
-    back or each block recompiles its inner loop."""
+    Memoized per device set, so every caller that resolves the default
+    mesh (e.g. one reconstruction per block) gets the SAME object back and
+    the per-mesh engine and step caches keyed on it stay at one entry."""
     n = len(jax.devices()) if n_devices is None else int(n_devices)
     key = (n, tuple(d.id for d in jax.devices()[:n]))
     if key not in _DATA_MESH_CACHE:
@@ -68,8 +64,7 @@ def serve_mesh(tp: int = 1, n_devices=None):
     ``--tp N`` flag on the serve CLI and bench), remaining devices on
     ``data``.  CLI, bench and tests all build the serve mesh through here
     so they agree on shape and axis names — and on identity: memoized per
-    (device set, tp) for the same jit-tracing-cache reason as
-    :func:`make_data_mesh`."""
+    (device set, tp) like :func:`make_data_mesh`."""
     n = len(jax.devices()) if n_devices is None else int(n_devices)
     tp = int(tp)
     if tp < 1:
@@ -134,9 +129,8 @@ def pod_submeshes(mesh) -> list:
     The pipelined block walk places block k's reconstruction on submesh
     ``k % n_pods`` and block k+1's capture forward on the next one, so the
     two phases run on disjoint device sets and genuinely overlap.  Memoized
-    per device grid (same reason as ``make_data_mesh``: distinct-but-equal
-    Mesh objects defeat jit's tracing cache on jax 0.4.x, and the walk
-    resolves the same pod's submesh once per block)."""
+    per device grid like ``make_data_mesh`` (the walk resolves the same
+    pod's submesh once per block)."""
     ax = pod_axis(mesh)
     if ax is None:
         return [mesh]
@@ -201,17 +195,3 @@ def batch_spec(mesh) -> P:
     if not dp:
         return P()
     return P(dp if len(dp) > 1 else dp[0])
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions: newer jax exposes
-    ``jax.shard_map(..., check_vma=)``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``.  Replication
-    checking is disabled on both — the bodies we wrap use ``axis_index``,
-    which the older checkers reject."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)

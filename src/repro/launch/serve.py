@@ -38,6 +38,7 @@ from repro.core import pack_model, quantize_model, quantized_memory_report
 from repro.core.qtensor import PACK_FACTOR
 from repro.core.tesseraq import TesseraQConfig
 from repro.data.pipeline import DataConfig, SyntheticCorpus, calibration_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import validate_single_pod
 from repro.launch.steps import cache_donate_argnums, make_serve_steps
 from repro.models import get_model
@@ -313,11 +314,13 @@ def main(argv=None):
                                 share_prefix=args.share_prefix,
                                 mesh=mesh, tp_shard=mesh is not None)
         lat = sched.latency_steps
+        dev = jax.devices()[0]
         print(f"[serve] scheduled {args.requests} requests over "
               f"{args.slots} slots in {sched.steps} decode steps "
               f"({sched.useful_tokens} useful tokens, occupancy "
               f"{sched.occupancy:.2f}, decode "
-              f"{sched.decode_tok_s:.1f} tok/s, backend={args.backend})")
+              f"{sched.decode_tok_s:.1f} tok/s, backend={args.backend}, "
+              f"device {dev.platform}/{dev.device_kind})")
         print(f"[serve] latency (decode steps): mean {lat['mean']:.1f} "
               f"p50 {lat['p50']:.0f} p90 {lat['p90']:.0f} "
               f"p99 {lat['p99']:.0f}")
@@ -346,10 +349,11 @@ def main(argv=None):
                            mesh=mesh, tp_shard=mesh is not None)
     B, gen = args.requests, args.gen
     dt = stats.prefill_secs + stats.decode_secs
+    dev = jax.devices()[0]
     print(f"[serve] {B} requests x {gen} tokens in {dt:.2f}s "
           f"(prefill {stats.prefill_tok_s:.1f} tok/s, decode "
           f"{stats.decode_tok_s:.1f} tok/s, backend={args.backend}, "
-          f"CPU simulation)")
+          f"device {dev.platform}/{dev.device_kind})")
     print("[serve] sample generations (token ids):")
     toks = stats.tokens
     for b in range(min(B, 4)):
@@ -359,4 +363,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -246,7 +246,7 @@ def _make_tp_serve_steps(cfg: ModelConfig, mesh, *, act_bits=None,
                          page_size: int = 0):
     """Serve steps under the tensor-parallel contract.
 
-    Both steps run the family forward inside ``shard_map_compat`` over the
+    Both steps run the family forward inside ``jax.shard_map`` over the
     FULL serve mesh: the ``model`` axis carries the contract's splits, any
     ``data`` axes replicate (P() specs).  Everything placement-related —
     the plan, the per-shard config, the spec trees — resolves at TRACE
@@ -261,7 +261,7 @@ def _make_tp_serve_steps(cfg: ModelConfig, mesh, *, act_bits=None,
     from jax.sharding import PartitionSpec as P
 
     from repro.launch import sharding as shp
-    from repro.launch.mesh import shard_map_compat, validate_single_pod
+    from repro.launch.mesh import validate_single_pod
 
     validate_single_pod(mesh, "make_serve_steps(tp_shard=True)")
     model = get_model(cfg)
@@ -301,11 +301,11 @@ def _make_tp_serve_steps(cfg: ModelConfig, mesh, *, act_bits=None,
             lp = spec.localize_params(p, plan)
             return lmodel.prefill(lp, b, c, ctx, start_pos=sp, ptab=pt)
 
-        return shard_map_compat(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspecs, replicate(batch), cspecs, P(),
                       replicate(ptab)),
-            out_specs=(P(), cspecs),
+            out_specs=(P(), cspecs), check_vma=False,
         )(params, batch, cache, start, ptab)
 
     def decode_step(params, cache, tokens, pos, active=None, ptab=None):
@@ -317,11 +317,11 @@ def _make_tp_serve_steps(cfg: ModelConfig, mesh, *, act_bits=None,
             lp = spec.localize_params(p, plan)
             return lmodel.decode_step(lp, c, t, po, dctx, active=a, ptab=pt)
 
-        return shard_map_compat(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspecs, cspecs, P(), P(), replicate(active),
                       replicate(ptab)),
-            out_specs=(P(), cspecs),
+            out_specs=(P(), cspecs), check_vma=False,
         )(params, cache, tokens, pos, active, ptab)
 
     return model, prefill_step, decode_step

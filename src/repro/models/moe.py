@@ -152,9 +152,9 @@ def moe_ffn(mp: dict, x: jax.Array, cfg: ModelConfig, ctx: Ctx) -> jax.Array:
                             backend=ctx.kernel_backend)
         return jax.lax.psum(y, ax)
 
-    y = mesh_mod.shard_map_compat(
+    y = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(dp), P(dp), P(dp), P(ax), P(ax), P(ax)),
-        out_specs=P(dp),
+        out_specs=P(dp), check_vma=False,
     )(x2d, idx, gate, mp["w_gate"], mp["w_up"], mp["w_down"])
     return y.reshape(B, S, d)

@@ -53,12 +53,7 @@ def compressed_psum(x: jax.Array, mesh, axis: str = "pod") -> jax.Array:
     """int8-on-the-wire psum over ``axis``: quantize locally, all-reduce the
     int8 payload (summed in int32 to avoid overflow: log2(127*n_pods) bits),
     dequantize with the max scale.  Per-tensor scale is psum-maxed first
-    (one scalar), so the payload collective is 1 byte/element.
-
-    Routed through ``shard_map_compat``: calling ``jax.shard_map`` directly
-    crashes on the pinned jax 0.4.x (it only exists on newer jax — the exact
-    incompatibility the shim was built for)."""
-    from repro.launch.mesh import shard_map_compat
+    (one scalar), so the payload collective is 1 byte/element."""
     P = jax.sharding.PartitionSpec
 
     def body(xl):
@@ -69,5 +64,5 @@ def compressed_psum(x: jax.Array, mesh, axis: str = "pod") -> jax.Array:
         total = jax.lax.psum(q.astype(jnp.int32), axis)
         return total.astype(jnp.float32) * smax
 
-    return shard_map_compat(body, mesh=mesh, in_specs=P(axis),
-                            out_specs=P(axis))(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(axis),
+                         out_specs=P(axis), check_vma=False)(x)

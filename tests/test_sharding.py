@@ -56,12 +56,9 @@ def test_make_data_mesh_spans_all_devices():
 
 
 def test_compressed_psum_matches_fp32_psum():
-    """``compressed_psum`` regression: it used to call ``jax.shard_map``
-    directly, which does not exist on the pinned jax 0.4.x (the exact
-    incompatibility ``shard_map_compat`` shims) — every call crashed with
-    AttributeError.  Now it must run on a pod mesh and reduce within int8
+    """``compressed_psum`` must run on a pod mesh and reduce within int8
     quantization error of the fp32 psum.  Runs 8-way under the CI
-    multidevice job; a 1-device mesh still covers the shim dispatch."""
+    multidevice job; a 1-device mesh still covers the shard_map path."""
     from repro.launch.mesh import make_mesh
     from repro.optim.compression import compressed_psum
     D = len(jax.devices())

@@ -12,10 +12,10 @@ Four shapes of the PR 4 bug class:
    is itself ``functools.lru_cache``/``cache``-decorated.
 3. A Mesh constructed locally (``jax.sharding.Mesh``/``make_mesh``/
    ``make_production_mesh``) flowing into a ``jit``/``shard_map`` built in
-   the same function: distinct-but-equal Mesh objects miss jax 0.4.x's
-   tracing cache, so every call recompiles — the exact 24x regression
-   PR 4 debugged.  ``make_data_mesh``/``pod_submeshes`` return memoized
-   meshes and are exempt.
+   the same function: the wrapper is rebuilt on every call of that
+   function, so every call retraces and recompiles — the exact 24x
+   regression PR 4 debugged.  ``make_data_mesh``/``pod_submeshes`` return
+   memoized meshes for the callers' keyed caches and are exempt.
 4. A serve-step BUILDER (``make_serve_steps``/``make_sched_steps``/
    ``_make_tp_serve_steps``) invoked in a loop without a cache guard: each
    call constructs fresh (possibly shard_map-wrapped) step closures, so
@@ -34,7 +34,7 @@ from tools.reprolint.core import (FileContext, Violation, call_name,
 
 RULE = "jit-cache"
 
-_WRAP_LAST = {"jit", "shard_map", "shard_map_compat"}
+_WRAP_LAST = {"jit", "shard_map"}
 
 
 def _is_wrap(node: ast.Call) -> bool:
@@ -42,7 +42,7 @@ def _is_wrap(node: ast.Call) -> bool:
     if not name:
         return False
     last = name.split(".")[-1]
-    if last in ("shard_map", "shard_map_compat"):
+    if last == "shard_map":
         return True
     return last == "jit" and name in ("jit", "jax.jit")
 
@@ -176,8 +176,8 @@ def check(ctx: FileContext):
                         RULE, ctx.path, n.lineno,
                         f"`{call_name(n.func)}` closes over Mesh `{name}` "
                         f"constructed in `{fn.name}` (line "
-                        f"{mesh_names[name].lineno}): distinct-but-equal "
-                        f"Mesh objects defeat the jit tracing cache on "
-                        f"jax 0.4.x — reuse a memoized mesh "
+                        f"{mesh_names[name].lineno}): the wrapper is rebuilt "
+                        f"and recompiled on every call — build it once "
+                        f"behind a keyed cache over a memoized mesh "
                         f"(make_data_mesh/pod_submeshes)"))
     return out
