@@ -354,10 +354,13 @@ def one_chip(cfg, seed: int, phases: Phases) -> None:
         res = phases.run(f"serve {backend}/dense timed", serve_scheduled, cfg,
                          packed, reqs, slots=SLOTS, max_seq=max_seq,
                          kernel_backend=backend, compiled=steps)
-        print(f"[serve] {backend}/dense: decode {res.decode_tok_s:.1f} tok/s, "
-              f"prefill {res.prefill_tok_s:.1f} tok/s, {res.steps} decode "
-              f"steps over {SLOTS} slots, occupancy {res.occupancy:.2f}",
-              flush=True)
+        # host seconds: an admission's first-token sync also waits for the
+        # decode steps dispatched ahead of it, so "prefill" counts those too
+        print(f"[serve] {backend}/dense: decode {res.decode_tok_s:.1f} tok/s "
+              f"over host seconds outside admissions, prompt "
+              f"{res.prefill_tok_s:.1f} tok/s over host seconds inside "
+              f"admissions, {res.steps} decode steps over {SLOTS} slots, "
+              f"occupancy {res.occupancy:.2f}", flush=True)
 
 
 # ---------------------------------------------------------------------------

@@ -53,6 +53,13 @@ chunks interleaved one-per-iteration with decode (store-agnostic — chunk
 steps run at full cache width, so dense and paged chunked prefill remain
 bit-identical at the same chunk schedule), and ``share_prefix=True`` lets
 paged chunked admission reuse full prompt-prefix pages copy-on-write.
+
+``serve_scheduled`` marks the phases of its loop with ``jax.profiler``
+annotations (the ``SPAN_*`` names below).  Outside a profiler trace they
+cost on the order of a microsecond of host time each; inside one they land
+in the same trace file as the device's operations, on the same clock, so
+an idle gap of the chip can be put down to the phase of the loop the host
+was in.
 """
 from __future__ import annotations
 
@@ -70,6 +77,17 @@ from repro.launch.mesh import validate_single_pod
 from repro.launch.steps import (cache_donate_argnums, make_paged_install_step,
                                 make_sched_steps)
 from repro.models.common import (DenseCacheStore, PagedCacheStore, write_slot)
+
+
+# Host spans of ``serve_scheduled`` (event name, stats):
+SPAN_ADMIT = "serve.admit"              # one whole-prefill admission: rid, slot, plen
+SPAN_PREFILL = "serve.prefill"          # ├ B=1 cache init + prefill dispatch
+SPAN_FIRST_TOKEN = "serve.first_token"  # ├ the first token's device->host sync
+SPAN_INSTALL = "serve.install"          # └ slot install + its block_until_ready
+SPAN_CHUNK = "serve.chunk"              # one chunked-prefill iteration: rid, slot, start, end
+SPAN_DECODE = "serve.decode"            # one decode step's dispatch + bookkeeping: step_num, live
+SPAN_DRAIN = "serve.drain"              # the final wait for the last decode step
+SPAN_FETCH = "serve.fetch"              # after the loop, the per-step tokens to host: steps
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,6 +162,18 @@ class ServeResult:
     Mode-specific extras (e.g. lock-step's wasted-token accounting) ride in
     ``extra``.  Mapping-style ``result["key"]`` access resolves attributes
     (falling back to ``extra``) so result handling can migrate gradually.
+
+    The timings are host seconds, not a split of the device's time.  For
+    ``serve_scheduled``, ``prefill_secs`` is the host time spent inside
+    admissions, from the prefill's dispatch through the first token's sync
+    and the slot install; that sync also waits for every decode step
+    dispatched ahead of it, so it counts decode work too.  ``decode_secs``
+    is the rest of the loop, ``prefill_tok_s`` and ``decode_tok_s`` are
+    prompt and decode tokens over those seconds.  The device's own split is
+    in a profiler trace: the ``SPAN_*`` host spans beside the prefill and
+    decode programs' executions.  ``serve_requests`` blocks on its prefill
+    before decoding, so its two timings do split the work (``serve_lockstep``
+    sums them over its batches).
     """
     mode: str                               # "uniform"|"scheduled"|"lockstep"
     store: str                              # "dense" | "paged"
@@ -474,24 +504,32 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
                 continue
             # ---- whole prefill at full cache width ------------------------
             tp0 = time.time()
-            batch = {"tokens": put(req.prompt[None])}
-            for k, v in (req.extras or {}).items():
-                batch[k] = put(v[None])
-            c1 = place_cache(model.init_cache(1, max_seq))
-            lg1, c1 = steps_.prefill(params, batch, c1)
-            # reprolint: ok[host-sync] — the only per-admission sync (counted); explicit device_get so transfer_guard allows it
-            tok0 = int(np.asarray(jax.device_get(jnp.argmax(lg1, -1)))[0])
-            if paged:
-                cache = steps_.install(cache, c1, i32(s), push(cstore.ptab_h[s]),
-                                       plen=_prefill_len(cfg, req))
-            else:
-                cache = steps_.write_slot(cache, c1, i32(s))
-            # the argmax sync above already drained the dispatch queue, so
-            # blocking here charges ONLY the slot install to the admission
-            # window instead of letting it leak into decode_secs
-            jax.block_until_ready(cache)   # reprolint: ok[host-sync] — admission-window timing boundary
-            dirty |= finish_prefill(s, req, tok0, lg1)
-            ptab_dirty |= paged      # budget-1 admissions release pages
+            with jax.profiler.TraceAnnotation(
+                    SPAN_ADMIT, rid=req.rid, slot=s,
+                    plen=_prefill_len(cfg, req)):
+                with jax.profiler.TraceAnnotation(SPAN_PREFILL):
+                    batch = {"tokens": put(req.prompt[None])}
+                    for k, v in (req.extras or {}).items():
+                        batch[k] = put(v[None])
+                    c1 = place_cache(model.init_cache(1, max_seq))
+                    lg1, c1 = steps_.prefill(params, batch, c1)
+                with jax.profiler.TraceAnnotation(SPAN_FIRST_TOKEN):
+                    # reprolint: ok[host-sync] — the only per-admission sync (counted); explicit device_get so transfer_guard allows it
+                    tok0 = int(np.asarray(jax.device_get(jnp.argmax(lg1, -1)))[0])
+                with jax.profiler.TraceAnnotation(SPAN_INSTALL):
+                    if paged:
+                        cache = steps_.install(cache, c1, i32(s),
+                                               push(cstore.ptab_h[s]),
+                                               plen=_prefill_len(cfg, req))
+                    else:
+                        cache = steps_.write_slot(cache, c1, i32(s))
+                    # the argmax sync above already drained the dispatch
+                    # queue, so blocking here charges ONLY the slot install
+                    # to the admission window instead of letting it leak
+                    # into decode_secs
+                    jax.block_until_ready(cache)   # reprolint: ok[host-sync] — admission-window timing boundary
+                dirty |= finish_prefill(s, req, tok0, lg1)
+                ptab_dirty |= paged  # budget-1 admissions release pages
             prefill_secs += time.time() - tp0
         # ---- one prefill chunk for the in-flight request ------------------
         if inflight is not None:
@@ -500,26 +538,30 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
             cur = inflight["cursor"]
             plen = len(req.prompt)   # chunkable families are text-only
             end = min(cur + prefill_chunk, plen)
-            chunk = {"tokens": put(req.prompt[None, cur:end])}
-            if paged:
-                lg1, cache = steps_.prefill(params, chunk, cache, i32(cur),
-                                            push(cstore.ptab_h[s:s + 1]))
-            else:
-                lg1, inflight["c1"] = steps_.prefill(params, chunk,
-                                                     inflight["c1"],
-                                                     i32(cur))
-            inflight["cursor"] = end
-            if end == plen:
-                # reprolint: ok[host-sync] — per-admission sync, chunked path (same contract as above)
-                tok0 = int(np.asarray(jax.device_get(jnp.argmax(lg1, -1)))[0])
-                if not paged:
-                    cache = steps_.write_slot(cache, inflight["c1"], i32(s))
-                jax.block_until_ready(cache)   # reprolint: ok[host-sync] — admission-window timing boundary
-                dirty |= finish_prefill(s, req, tok0, lg1)
-                ptab_dirty |= paged
-                inflight = None
-            else:
-                jax.block_until_ready(lg1)   # reprolint: ok[host-sync] — honest prefill attribution
+            with jax.profiler.TraceAnnotation(SPAN_CHUNK, rid=req.rid, slot=s,
+                                              start=cur, end=end):
+                chunk = {"tokens": put(req.prompt[None, cur:end])}
+                if paged:
+                    lg1, cache = steps_.prefill(params, chunk, cache,
+                                                i32(cur),
+                                                push(cstore.ptab_h[s:s + 1]))
+                else:
+                    lg1, inflight["c1"] = steps_.prefill(params, chunk,
+                                                         inflight["c1"],
+                                                         i32(cur))
+                inflight["cursor"] = end
+                if end == plen:
+                    # reprolint: ok[host-sync] — per-admission sync, chunked path (same contract as above)
+                    tok0 = int(np.asarray(jax.device_get(jnp.argmax(lg1, -1)))[0])
+                    if not paged:
+                        cache = steps_.write_slot(cache, inflight["c1"],
+                                                  i32(s))
+                    jax.block_until_ready(cache)   # reprolint: ok[host-sync] — admission-window timing boundary
+                    dirty |= finish_prefill(s, req, tok0, lg1)
+                    ptab_dirty |= paged
+                    inflight = None
+                else:
+                    jax.block_until_ready(lg1)   # reprolint: ok[host-sync] — honest prefill attribution
             prefill_secs += time.time() - tp0
         if not active_h.any():
             if not pending and inflight is None:
@@ -537,52 +579,57 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
             else:
                 t += 1                           # chunk-only iteration
             continue
-        if dirty:
-            active_d = push(active_h)
-        if ptab_dirty:
-            ptab_d = push(cstore.ptab_h)
-        # ---- one masked decode step over every slot -----------------------
-        if paged:
-            logits, tok, pos, cache = steps_.decode(params, cache, tok, pos,
-                                                    active_d, ptab_d)
-        else:
-            logits, tok, pos, cache = steps_.decode(params, cache, tok, pos,
-                                                    active_d)
-        if collect_logits:
-            # eager per-step fetch of ACTIVE rows only: bounded device
-            # memory (regression-tested in tests/test_scheduler.py)
-            # reprolint: ok[host-sync] — eager fetch only when collect_logits=True; opt-in debugging path
-            lg_np = np.asarray(jax.device_get(logits), np.float32)
-            for s in np.flatnonzero(active_h):
-                res[slot_rid[s]]["logits"].append(lg_np[s])
-        del logits
-        trace.append((active_h.copy(), slot_rid.copy(), tok))
-        steps += 1
-        occupancy_acc += int(active_h.sum())
-        t += 1
-        # ---- budget completions (host-known, zero sync) -------------------
-        done = active_h & (remaining == 1)
-        remaining[active_h] -= 1
-        if done.any():
-            for s in np.flatnonzero(done):
-                res[slot_rid[s]]["finish_step"] = t
-                slot_rid[s] = -1
-                cstore.release(int(s))
-            active_h[done] = False
-            active_d = push(active_h)
-            if paged:
+        live = int(active_h.sum())
+        with jax.profiler.StepTraceAnnotation(SPAN_DECODE, step_num=steps,
+                                              live=live):
+            if dirty:
+                active_d = push(active_h)
+            if ptab_dirty:
                 ptab_d = push(cstore.ptab_h)
+            # ---- one masked decode step over every slot -------------------
+            if paged:
+                logits, tok, pos, cache = steps_.decode(
+                    params, cache, tok, pos, active_d, ptab_d)
+            else:
+                logits, tok, pos, cache = steps_.decode(
+                    params, cache, tok, pos, active_d)
+            if collect_logits:
+                # eager per-step fetch of ACTIVE rows only: bounded device
+                # memory (regression-tested in tests/test_scheduler.py)
+                # reprolint: ok[host-sync] — eager fetch only when collect_logits=True; opt-in debugging path
+                lg_np = np.asarray(jax.device_get(logits), np.float32)
+                for s in np.flatnonzero(active_h):
+                    res[slot_rid[s]]["logits"].append(lg_np[s])
+            del logits
+            trace.append((active_h.copy(), slot_rid.copy(), tok))
+            steps += 1
+            occupancy_acc += live
+            t += 1
+            # ---- budget completions (host-known, zero sync) ---------------
+            done = active_h & (remaining == 1)
+            remaining[active_h] -= 1
+            if done.any():
+                for s in np.flatnonzero(done):
+                    res[slot_rid[s]]["finish_step"] = t
+                    slot_rid[s] = -1
+                    cstore.release(int(s))
+                active_h[done] = False
+                active_d = push(active_h)
+                if paged:
+                    ptab_d = push(cstore.ptab_h)
 
-    tok.block_until_ready()                      # reprolint: ok[host-sync] — closes the timed region
+    with jax.profiler.TraceAnnotation(SPAN_DRAIN):
+        tok.block_until_ready()                  # reprolint: ok[host-sync] — closes the timed region
     total_secs = time.time() - t_start
     decode_secs = max(total_secs - prefill_secs, 1e-9)
 
     # ---- reconstruct per-request streams (host transfers OFF the clock) ---
-    for mask, rids, tok_d in trace:
-        # reprolint: ok[host-sync] — off-clock stream reconstruction; timed region already closed
-        tok_np = np.asarray(jax.device_get(tok_d))
-        for s in np.flatnonzero(mask):
-            res[rids[s]]["tokens"].append(int(tok_np[s]))
+    with jax.profiler.TraceAnnotation(SPAN_FETCH, steps=len(trace)):
+        for mask, rids, tok_d in trace:
+            # reprolint: ok[host-sync] — off-clock stream reconstruction; timed region already closed
+            tok_np = np.asarray(jax.device_get(tok_d))
+            for s in np.flatnonzero(mask):
+                res[rids[s]]["tokens"].append(int(tok_np[s]))
 
     useful = 0
     latencies = []

@@ -319,7 +319,8 @@ def main(argv=None):
               f"{args.slots} slots in {sched.steps} decode steps "
               f"({sched.useful_tokens} useful tokens, occupancy "
               f"{sched.occupancy:.2f}, decode "
-              f"{sched.decode_tok_s:.1f} tok/s, backend={args.backend}, "
+              f"{sched.decode_tok_s:.1f} tok/s over the host seconds "
+              f"outside admissions, backend={args.backend}, "
               f"device {dev.platform}/{dev.device_kind})")
         print(f"[serve] latency (decode steps): mean {lat['mean']:.1f} "
               f"p50 {lat['p50']:.0f} p90 {lat['p90']:.0f} "

@@ -2,6 +2,8 @@
 masking, admission determinism, compile-once decode, family coverage, and
 packed-backend parity (the serve-path acceptance gates in miniature)."""
 import dataclasses
+import glob
+import os
 
 import jax
 import jax.numpy as jnp
@@ -9,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_reduced_config
+from repro.launch import scheduler as S
 from repro.launch.scheduler import (Request, compile_sched_steps,
                                     make_workload, serve_lockstep,
                                     serve_scheduled)
@@ -339,3 +342,101 @@ def test_decode_compiles_once_with_pallas_kernels(dense):
                             compiled=comp)
     assert sched["steps"] > 0
     assert comp.decode._cache_size() == 1
+
+
+# -- host spans in the profiler's trace --------------------------------------
+
+def _traced_spans(log_dir, run):
+    """Run ``run()`` under the profiler; return its result and the serve
+    loop's host spans as (name, start_ns, end_ns, stats), in start order."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        out = run()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    spans = [(e.name, e.start_ns, e.end_ns, dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("serve.")]
+    return out, sorted(spans, key=lambda x: x[1])
+
+
+def _inside(spans, name, outer):
+    return [x for x in spans
+            if x[0] == name and outer[1] <= x[1] and x[2] <= outer[2]]
+
+
+@pytest.mark.parametrize("store", ["dense", "paged"])
+def test_serve_spans_mark_each_phase(dense, tmp_path, store):
+    """Whole-prefill admissions: one ``serve.admit`` per request holding
+    exactly one prefill, first-token sync and install; one ``serve.decode``
+    step span per decode step, numbered in order; one drain and one fetch;
+    and the tokens are those of the same run without the profiler."""
+    cfg, _, params = dense
+    reqs = make_workload(cfg.vocab_size, n_requests=5, seed=11,
+                         prompt_lens=(4, 8), budgets=(1, 6), mean_gap=1.0)
+    kw = dict(slots=2, max_seq=16, store=store, page_size=4)
+    plain = serve_scheduled(cfg, params, reqs, **kw)
+    res, spans = _traced_spans(
+        tmp_path, lambda: serve_scheduled(cfg, params, reqs, **kw))
+    for q in reqs:
+        np.testing.assert_array_equal(res["requests"][q.rid]["tokens"],
+                                      plain["requests"][q.rid]["tokens"])
+
+    admits = [x for x in spans if x[0] == S.SPAN_ADMIT]
+    assert sorted(x[3]["rid"] for x in admits) == sorted(q.rid for q in reqs)
+    plens = {q.rid: len(q.prompt) for q in reqs}
+    for a in admits:
+        assert 0 <= a[3]["slot"] < kw["slots"]
+        assert a[3]["plen"] == plens[a[3]["rid"]]
+        for child in (S.SPAN_PREFILL, S.SPAN_FIRST_TOKEN, S.SPAN_INSTALL):
+            assert len(_inside(spans, child, a)) == 1, (child, a)
+    assert sum(x[0] == S.SPAN_PREFILL for x in spans) == len(reqs)
+    assert not any(x[0] == S.SPAN_CHUNK for x in spans)
+
+    decodes = [x for x in spans if x[0] == S.SPAN_DECODE]
+    assert [x[3]["step_num"] for x in decodes] == list(range(res.steps))
+    assert all(1 <= x[3]["live"] <= kw["slots"] for x in decodes)
+    assert sum(x[3]["live"] for x in decodes) == res.decode_tokens
+    # no admission or decode span overlaps another
+    loop = sorted(admits + decodes, key=lambda x: x[1])
+    assert all(a[2] <= b[1] for a, b in zip(loop, loop[1:]))
+
+    drain = [x for x in spans if x[0] == S.SPAN_DRAIN]
+    fetch = [x for x in spans if x[0] == S.SPAN_FETCH]
+    assert len(drain) == len(fetch) == 1
+    assert fetch[0][3]["steps"] == res.steps
+    assert decodes[-1][2] <= drain[0][1] <= drain[0][2] <= fetch[0][1]
+
+
+@pytest.mark.parametrize("store", ["dense", "paged"])
+def test_serve_spans_one_per_prefill_chunk(dense, tmp_path, store):
+    """Chunked prefill: one ``serve.chunk`` per chunk, carrying the
+    request, its slot and the chunk's token range; no whole-prefill
+    admission spans."""
+    cfg, _, params = dense
+    reqs = make_workload(cfg.vocab_size, n_requests=3, seed=12,
+                         prompt_lens=(5, 11), budgets=(2, 4))
+    chunk = 4
+    kw = dict(slots=2, max_seq=16, store=store, page_size=4,
+              prefill_chunk=chunk)
+    plain = serve_scheduled(cfg, params, reqs, **kw)
+    res, spans = _traced_spans(
+        tmp_path, lambda: serve_scheduled(cfg, params, reqs, **kw))
+    for q in reqs:
+        np.testing.assert_array_equal(res["requests"][q.rid]["tokens"],
+                                      plain["requests"][q.rid]["tokens"])
+    chunks = [x[3] for x in spans if x[0] == S.SPAN_CHUNK]
+    want = [(q.rid, lo, min(lo + chunk, len(q.prompt)))
+            for q in sorted(reqs, key=lambda r: (r.arrival, r.rid))
+            for lo in range(0, len(q.prompt), chunk)]
+    assert [(c["rid"], c["start"], c["end"]) for c in chunks] == want
+    assert all(0 <= c["slot"] < kw["slots"] for c in chunks)
+    assert not any(x[0] == S.SPAN_ADMIT for x in spans)
+    assert sum(x[0] == S.SPAN_DECODE for x in spans) == res.steps

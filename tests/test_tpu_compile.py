@@ -116,3 +116,35 @@ def test_paged_decode_attention_compiles(one_chip):
     _compile(lambda q, k, v, t, n, p, a: paged_decode_attention(
         q, k, v, t, kv_len=n, q_pos=p, active=a), q, pool, pool, ptab,
         kv_len, q_pos, active)
+
+
+def _custom_call_names(text):
+    return {line.split(" = ", 1)[0].strip().lstrip("%").rsplit(".", 1)[0]
+            for line in text.splitlines()
+            if "custom_call_target=\"tpu_custom_call\"" in line}
+
+
+def test_decode_attention_keeps_its_name_inside_a_step(one_chip,
+                                                       monkeypatch):
+    """Inside a larger jitted program the attention kernels keep their
+    wrappers' names, which the benchmark reads from the device trace."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    q, kv_len, q_pos, active, sds = _attn_operands(one_chip)
+    kv = sds((B, 1024, HKV, D), jnp.bfloat16)
+    psz, W = 16, 1024 // 16
+    pool = sds((B * W, psz, HKV, D), jnp.bfloat16)
+    ptab = sds((B, W), jnp.int32)
+
+    def step(q, k, v, pool, t, n, p, a):
+        dense = ops.decode_attention_op(q, k, v, kv_len=n, q_pos=p, active=a,
+                                        scale=D ** -0.5, chunk=1 << 30)
+        paged = ops.paged_decode_attention_op(q, pool, pool, t, kv_len=n,
+                                              q_pos=p, active=a,
+                                              scale=D ** -0.5)
+        return (dense * 2 + paged).sum()
+
+    text = jax.jit(step).lower(q, kv, kv, pool, ptab, kv_len, q_pos,
+                               active).compile().as_text()
+    assert _custom_call_names(text) == {"decode_attention_op",
+                                        "paged_decode_attention_op"}
