@@ -110,25 +110,18 @@ def _snap_block_k(block_k, K, group_size, ppb, bits):
     return bk, Kp
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "group_size",
-                                             "block_n", "block_k"))
-def quant_gemv_op(x, packed, scale, zero, *, bits: int, group_size: int,
-                  block_n=128, block_k=256):
-    """Decode-shaped wrapper: M (the live-slot count) is NEVER padded; only
-    N and K grow to tile multiples, with the same all-K-keyed-operands
-    padding contract as quant_matmul_op."""
-    M, K = x.shape
+@functools.partial(jax.jit, static_argnames=("bits", "group_size"))
+def quant_gemv_op(x, packed, scale, zero, *, bits: int, group_size: int):
+    """Decode-shaped wrapper: M (the live-slot count) is NEVER padded and K
+    needs no padding; N grows to a multiple of 128 when it is wider than
+    one lane tile.  ``x`` comes in K order: the kernel puts it in the
+    codes' plane order itself, so a call is one ``quant_gemv_op`` kernel
+    in the device trace and no other op."""
     N = packed.shape[1]
-    ppb = PACK_FACTOR[bits]
-    bn = min(block_n, N)
-    bk, Kp = _snap_block_k(block_k, K, group_size, ppb, bits)
-    out = quant_gemv(_pad_rows_to(x, Kp, axis=1),
-                     _pad_to(_pad_rows_to(packed, Kp // ppb), bn, 1),
-                     _pad_to(_pad_rows_to(scale, Kp // group_size), bn, 1),
-                     _pad_to(_pad_rows_to(zero, Kp // group_size), bn, 1),
-                     bits=bits, group_size=group_size,
-                     block_n=bn, block_k=bk,
-                     interpret=_interpret())
+    lanes = 128 if N > 128 else N
+    out = quant_gemv(x, _pad_to(packed, lanes, 1), _pad_to(scale, lanes, 1),
+                     _pad_to(zero, lanes, 1), bits=bits,
+                     group_size=group_size, interpret=_interpret())
     return out[:, :N]
 
 
@@ -136,8 +129,10 @@ def qtensor_matmul(x: jax.Array, w: QTensor) -> jax.Array:
     """x: (..., K) bf16 x QTensor -> (..., N) via the Pallas kernels.
 
     Shape-based dispatch: decode-sized batches (M <= DECODE_GEMV_MAX_ROWS
-    flattened rows — one token per live slot) hit the fused dequant-GEMV;
-    prefill-sized batches keep the MXU-tiled quant_matmul."""
+    flattened rows — one token per live slot) take the packed GEMV, which
+    applies scale and zero per group to each group's partial product;
+    prefill-sized batches keep the MXU-tiled quant_matmul, which
+    dequantizes each weight tile and shares it across 256-row M tiles."""
     if w.act_scale is not None:
         x = x / w.act_scale.astype(x.dtype)
     lead = x.shape[:-1]

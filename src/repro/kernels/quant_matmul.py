@@ -81,8 +81,11 @@ def dequant_tile(packed, s, z, *, bits: int, dtype):
     Scales and zeros round to ``dtype`` first and the result rounds once
     more, exactly as the XLA path's ``QTensor.dequantize(x.dtype)`` does:
     with integer codes, ``code - zero`` is exact and the product of two
-    ``dtype`` values is exact in f32, so both backends build bit-identical
-    weights and differ only in the matmul's accumulation order."""
+    ``dtype`` values is exact in f32, so ``quant_matmul`` and the XLA path
+    build bit-identical weights and differ only in the matmul's
+    accumulation order.  The decode GEMV (``quant_gemv``) does not use
+    this: it never rounds the weight, and applies scale and zero per
+    group."""
     ppb = PACK_FACTOR[bits]
     codes = unpack_tile(packed, ppb, 8 // ppb)                 # (bk, bn)
     bk, bn = codes.shape
@@ -117,7 +120,7 @@ def _qmm_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *,
 
 
 def check_group_tile(bk: int, group_size: int):
-    """The group/tile contract of both kernels: one of bk, group_size
+    """The group/tile contract of the GEMM kernels: one of bk, group_size
     divides the other, and a tile holding several groups holds a divisor
     or a multiple of 8 of them (``tile_group_rows``'s aligned windows)."""
     if bk % group_size and group_size % bk:

@@ -174,7 +174,7 @@ def test_quant_matmul_k_padding(bits, K, group, block_k):
 # -- decode-shaped fused dequant-GEMV ---------------------------------------
 
 @pytest.mark.parametrize("bits", [2, 3, 4])
-@pytest.mark.parametrize("M", [1, 2, 3, 5, 8, 24])
+@pytest.mark.parametrize("M", [1, 2, 3, 5, 8, 24, 16, 32])
 def test_quant_gemv_slot_sweep(bits, M):
     """Decode batches (M = live slots, 1..slots) through the GEMV kernel
     match the oracle — grouped, at every deployed bit-width."""
@@ -194,28 +194,75 @@ def test_quant_gemv_slot_sweep(bits, M):
                                rtol=1e-3, atol=1e-3)
 
 
-@pytest.mark.parametrize("bits", [2, 4])
-@pytest.mark.parametrize("K,group", [
-    (128, 128),    # per-channel: one scale row resident across all K tiles
-    (48, 16),      # K pads 48 -> 64: GEMV K-padding contract
-    (256, 64),     # several groups per K strip, sliced in-kernel
+@pytest.mark.parametrize("bits", [2, 3, 4])
+@pytest.mark.parametrize("K,group,N,M", [
+    # per-channel: one group, one unit of 128 rows
+    pytest.param(128, 128, 40, 3, id="128-128"),
+    # groups smaller than a lane tile: unaligned x columns
+    pytest.param(48, 16, 40, 3, id="48-16"),
+    pytest.param(256, 64, 40, 3, id="256-64"),
+    # several grid steps along N (5 of 128 columns), 8 groups along K
+    pytest.param(1024, 128, 640, 1, id="1024-128-640-m1"),
+    pytest.param(1024, 128, 640, 16, id="1024-128-640-m16"),
+    pytest.param(1024, 128, 640, 32, id="1024-128-640-m32"),
+    # 10 groups: one rolled trip of the group loop and two after it
+    pytest.param(1280, 128, 384, 16, id="1280-128-384-m16"),
+    # per-channel over 4 units of 256 rows, scaled once
+    pytest.param(1024, 1024, 256, 8, id="perchannel-1024-m8"),
+    # groups larger than a unit: 2 units of 256 rows each
+    pytest.param(2048, 512, 128, 4, id="2048-512-m4"),
+    # per-channel in units of 200 rows; N pads 200 -> 256
+    pytest.param(1000, 1000, 200, 5, id="perchannel-1000-n200-m5"),
 ])
-def test_quant_gemv_grouping_and_padding(bits, K, group):
+def test_quant_gemv_grouping_and_padding(bits, K, group, N, M):
     from repro.kernels.ops import quant_gemv_op
-    M, N = 3, 40
-    rng = np.random.default_rng(bits * 10 + K)
+    rng = np.random.default_rng(bits * 10 + K + M)
     codes = rng.integers(0, 1 << bits, (K, N)).astype(np.uint8)
     scale = (rng.random((K // group, N)).astype(np.float32) + 0.5) * 0.1
     zero = rng.integers(0, 1 << bits, (K // group, N)).astype(np.float32)
     packed = pack(jnp.asarray(codes), bits, axis=0)
     x = jnp.asarray(rng.normal(size=(M, K)), jnp.float32)
     got = quant_gemv_op(x, packed, jnp.asarray(scale), jnp.asarray(zero),
-                        bits=bits, group_size=group, block_k=64)
+                        bits=bits, group_size=group)
     want = ref.quant_matmul_ref(x, packed, jnp.asarray(scale),
                                 jnp.asarray(zero), bits=bits,
                                 group_size=group)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-3, atol=1e-3)
+
+
+@pytest.mark.parametrize("bits", [2, 3, 4])
+def test_quant_gemv_bf16_within_the_xla_paths_weight_rounding(bits):
+    """bf16 activations.  The XLA path (``ref.quant_matmul_ref``) rounds
+    each weight ``(q - z) s`` to bf16 before its product; the GEMV applies
+    scale and zero to each group's f32 partial instead.  So the two differ
+    by at most that rounding of each weight, 2**-9 of it, summed over K,
+    plus each one's rounding of its bf16 output, 2**-9 of the result.  The
+    GEMV is the nearer of the two to the float32 product."""
+    from repro.core.qtensor import QTensor
+    from repro.kernels.ops import quant_gemv_op
+    M, K, N, g = 16, 1024, 256, 128
+    rng = np.random.default_rng(bits)
+    codes = rng.integers(0, 1 << bits, (K, N)).astype(np.uint8)
+    scale = jnp.asarray((rng.random((K // g, N)) + 0.5) * 0.1, jnp.float32)
+    zero = jnp.asarray(rng.integers(0, 1 << bits, (K // g, N)), jnp.float32)
+    packed = pack(jnp.asarray(codes), bits, axis=0)
+    x = jnp.asarray(rng.normal(size=(M, K)), jnp.bfloat16)
+    got = np.asarray(quant_gemv_op(x, packed, scale, zero, bits=bits,
+                                   group_size=g), np.float64)
+    want = np.asarray(ref.quant_matmul_ref(x, packed, scale, zero, bits=bits,
+                                           group_size=g), np.float64)
+    # scale and zero rounded to bf16 as both paths round them; the weight
+    # itself exact (a small integer times a bf16 value)
+    w = np.asarray(QTensor(packed, scale.astype(jnp.bfloat16).astype(
+        jnp.float32), zero.astype(jnp.bfloat16).astype(jnp.float32), bits,
+        g, (K, N)).dequantize(jnp.float32), np.float64)
+    xf = np.asarray(x, np.float64)
+    exact = xf @ w
+    bound = 2.0 ** -9 * (np.abs(xf) @ np.abs(w)) \
+        + 2.0 ** -9 * (np.abs(got) + np.abs(want))
+    assert np.all(np.abs(got - want) <= bound)
+    assert np.linalg.norm(got - exact) < np.linalg.norm(want - exact)
 
 
 @pytest.mark.parametrize("bits", [2, 3, 4])

@@ -56,21 +56,36 @@ def _compile(fn, *args):
     assert "tpu_custom_call" in text, "kernel did not lower to Mosaic"
 
 
-def _packed_operands(one_chip, bits, group, M, *, n=N, lead=()):
+def _packed_operands(one_chip, bits, group, M, *, k=K, n=N, lead=()):
     ppb = PACK_FACTOR[bits]
     sds = lambda shape, dt: jax.ShapeDtypeStruct(lead + shape, dt,
                                                  sharding=one_chip)
-    return (sds((M, K), jnp.bfloat16), sds((K // ppb, n), jnp.uint8),
-            sds((K // group, n), jnp.float32),
-            sds((K // group, n), jnp.float32))
+    return (sds((M, k), jnp.bfloat16), sds((k // ppb, n), jnp.uint8),
+            sds((k // group, n), jnp.float32),
+            sds((k // group, n), jnp.float32))
 
 
-@pytest.mark.parametrize("M", [1, 8])
-@pytest.mark.parametrize("bits", [2, 4])
-def test_quant_gemv_compiles(one_chip, bits, M):
+# tinyllama's widths, then Mistral-7B's four GEMV shapes (q/o, k/v,
+# gate/up, down) at 16 and 32 slots: the kernel's tiles come from the
+# shapes, so a layout the chip's compiler refuses or a VMEM overrun at
+# any of them shows here
+_MISTRAL = [(4096, 4096), (4096, 1024), (4096, 14336), (14336, 4096)]
+
+
+@pytest.mark.parametrize("bits,M,k,n", [
+    pytest.param(2, 1, K, N, id="2-1"), pytest.param(2, 8, K, N, id="2-8"),
+    pytest.param(4, 1, K, N, id="4-1"), pytest.param(4, 8, K, N, id="4-8"),
+] + [pytest.param(2, M, k, n, id=f"mistral-w2-{k}x{n}-m{M}")
+     for k, n in _MISTRAL for M in (16, 32)]
+  + [pytest.param(b, M, 4096, 14336, id=f"mistral-w{b}-4096x14336-m{M}")
+     for b in (3, 4) for M in (16, 32)]
+  # a K too deep for the VMEM budget even at 128 columns: the kernel asks
+  # the compiler for the VMEM it needs
+  + [pytest.param(4, 32, 65536, 1024, id="deep-k-w4-65536x1024-m32")])
+def test_quant_gemv_compiles(one_chip, bits, M, k, n):
     _compile(lambda x, p, s, z: quant_gemv(x, p, s, z, bits=bits,
                                            group_size=128),
-             *_packed_operands(one_chip, bits, 128, M))
+             *_packed_operands(one_chip, bits, 128, M, k=k, n=n))
 
 
 # block_k picks the scale-row path of ``tile_group_rows``: 16 groups per
@@ -122,6 +137,28 @@ def _custom_call_names(text):
     return {line.split(" = ", 1)[0].strip().lstrip("%").rsplit(".", 1)[0]
             for line in text.splitlines()
             if "custom_call_target=\"tpu_custom_call\"" in line}
+
+
+def test_quant_gemv_is_one_named_kernel_inside_a_step(one_chip,
+                                                     monkeypatch):
+    """Each GEMV call inside a larger jitted program is one Mosaic kernel
+    named ``quant_gemv_op`` and nothing else of its own: ``x``'s plane
+    order is made inside the kernel, so the step holds no extra op per
+    call.  The benchmark counts these kernels, one per block linear."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    x, p, s, z = _packed_operands(one_chip, 2, 128, 32, k=4096, n=1024)
+    a = jax.ShapeDtypeStruct((4096,), jnp.bfloat16, sharding=one_chip)
+
+    def step(x, p, s, z, a):
+        h = ops.quant_gemv_op(x / a, p, s, z, bits=2, group_size=128)
+        return ops.quant_gemv_op(x * 2, p, s, z, bits=2, group_size=128) + h
+
+    text = jax.jit(step).lower(x, p, s, z, a).compile().as_text()
+    assert _custom_call_names(text) == {"quant_gemv_op"}
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "jit(quant_gemv_op)/" not in text.replace(
+        "jit(quant_gemv_op)/pallas_call", "")
 
 
 def test_decode_attention_keeps_its_name_inside_a_step(one_chip,
