@@ -7,12 +7,12 @@ from __future__ import annotations
 
 import importlib
 
-from repro.configs.base import (ModelConfig, MoEConfig, QuantConfig, ShapeConfig,
-                                SSMConfig, SHAPES, SHAPES_BY_NAME)
+from repro.configs.base import (MLAConfig, ModelConfig, MoEConfig, QuantConfig,
+                                ShapeConfig, SSMConfig, SHAPES, SHAPES_BY_NAME)
 
 ARCH_IDS = (
     "qwen3-moe-30b-a3b",
-    "moonshot-v1-16b-a3b",
+    "moonlight-16b-a3b",
     "zamba2-1.2b",
     "rwkv6-3b",
     "smollm-135m",
@@ -39,5 +39,5 @@ def get_reduced_config(arch: str) -> ModelConfig:
     return importlib.import_module(_MODULES[arch]).reduced()
 
 
-__all__ = ["ModelConfig", "MoEConfig", "QuantConfig", "ShapeConfig", "SSMConfig",
+__all__ = ["MLAConfig", "ModelConfig", "MoEConfig", "QuantConfig", "ShapeConfig", "SSMConfig",
            "SHAPES", "SHAPES_BY_NAME", "ARCH_IDS", "get_config", "get_reduced_config"]

@@ -73,6 +73,37 @@ class MoEConfig:
     top_k: int
     capacity_factor: float = 1.25
     router_dtype: str = "float32"
+    # DeepSeek-V3-style expert layers (family "mla_moe"): experts chosen by
+    # the top ``top_k`` of sigmoid(x W_r) + a per-expert bias, weighted by
+    # the chosen sigmoids normalised to sum 1 times ``routed_scaling``;
+    # ``shared_experts`` always-on experts fused into one SwiGLU of width
+    # shared_experts * d_ff; the first ``dense_layers`` layers are a plain
+    # SwiGLU of width ``dense_d_ff`` instead
+    shared_experts: int = 0
+    routed_scaling: float = 1.0
+    dense_layers: int = 0
+    dense_d_ff: int = 0
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434) without a
+    query low-rank projection: each head's key is ``qk_nope_head_dim``
+    lanes decompressed from a ``kv_lora_rank`` latent plus one rotary
+    ``qk_rope_head_dim`` key shared by every head."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Lanes of one cached token: the latent and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -87,7 +118,7 @@ class SSMConfig:
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                   # dense | moe | ssm | rwkv | hybrid | encdec | vlm
+    family: str                   # dense | moe | mla_moe | ssm | rwkv | hybrid | encdec | vlm
     num_layers: int
     d_model: int
     num_heads: int
@@ -96,6 +127,7 @@ class ModelConfig:
     vocab_size: int
     head_dim: Optional[int] = None          # default d_model // num_heads
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     # hybrid (zamba2): one shared attention block applied every `attn_every` layers
     attn_every: int = 0
@@ -121,8 +153,27 @@ class ModelConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // self.num_heads
 
+    def _mla_moe_counts(self) -> Tuple[int, int]:
+        """(all, per-token active) parameters of an ``mla_moe`` model."""
+        d, f, m, e = self.d_model, self.d_ff, self.mla, self.moe
+        H = self.num_heads
+        attn = (d * H * m.qk_head_dim + d * m.latent_dim
+                + m.kv_lora_rank * H * (m.qk_nope_head_dim + m.v_head_dim)
+                + H * m.v_head_dim * d)
+        shared = 3 * d * f * e.shared_experts
+        emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
+        n_moe = self.num_layers - e.dense_layers
+        dense = e.dense_layers * (attn + 3 * d * e.dense_d_ff)
+        total = dense + n_moe * (attn + shared + 3 * d * f * e.num_experts
+                                 + d * e.num_experts) + emb
+        active = dense + n_moe * (attn + shared + 3 * d * f * e.top_k
+                                  + d * e.num_experts) + emb
+        return total, active
+
     def param_count(self) -> int:
         """Analytic parameter count (embedding + blocks + head)."""
+        if self.family == "mla_moe":
+            return self._mla_moe_counts()[0]
         d, f, L = self.d_model, self.d_ff, self.num_layers
         hd = self.resolved_head_dim
         q = self.num_heads * hd
@@ -157,6 +208,8 @@ class ModelConfig:
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE: top-k experts only)."""
+        if self.family == "mla_moe":
+            return self._mla_moe_counts()[1]
         if self.family != "moe":
             return self.param_count()
         d, f, L = self.d_model, self.d_ff, self.num_layers

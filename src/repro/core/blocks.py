@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import encdec, hybrid, rwkv, ssm, transformer, vlm
+from repro.models import encdec, hybrid, mla_moe, rwkv, ssm, transformer, vlm
 from repro.models.common import Ctx, DEFAULT_CTX, take_layer
 
 # Leaf names that are quantizable linear weights.  Everything else (norms,
@@ -27,6 +27,7 @@ QUANT_LEAF_NAMES = frozenset({
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
     "wr", "wg", "ck", "cv", "cr",                 # rwkv time/channel mix
     "in_proj", "out_proj",                        # mamba2
+    "wkv_a", "wkv_b",                             # latent attention
 })
 
 
@@ -111,6 +112,29 @@ def build_stages(cfg: ModelConfig, ctx: Ctx = DEFAULT_CTX) -> list:
 
         get, set_ = _stacked_getset("blocks")
         return [Stage("decoder", cfg.num_layers, get, set_, init_x, apply)]
+
+    if fam == "mla_moe":
+        # two runs of blocks: the leading dense layers, then the expert
+        # layers, one stream through both
+        def init_x(params, batch, saved):
+            return params["embed"][batch["tokens"]]
+
+        def apply(bp, x, aux):
+            out, _, _ = mla_moe.block(bp, x, cfg, ctx,
+                                      positions=jnp.arange(x.shape[1]))
+            return out
+
+        stages = []
+        for (key, _), n, first in zip(
+                mla_moe.STACKS,
+                (cfg.moe.dense_layers,
+                 cfg.num_layers - cfg.moe.dense_layers), (True, False)):
+            get, set_ = _stacked_getset(key)
+            stages.append(Stage(
+                key, n, get, set_,
+                init_x if first else (lambda p, b, s: None), apply,
+                pack_target=(lambda k: lambda i: (k, i))(key)))
+        return stages
 
     if fam == "rwkv":
         def init_x(params, batch, saved):

@@ -8,9 +8,10 @@ the block *uncompiled* with ``layers.matmul`` / ``layers.expert_matmul``
 temporarily patched to record (weight-identity -> stats); weight identities
 are mapped back to param paths.
 
-MoE expert weights see their own capacity-gathered inputs (zero-padded slots
-dilute ``mean_abs`` by a uniform factor that cancels under AWQ's relative
-scale search — documented approximation).
+MoE expert weights see their own capacity-gathered (or, for the dropless
+layer, expert-sorted and tile-padded) inputs: zero-padded slots dilute
+``mean_abs`` by a uniform factor that cancels under AWQ's relative scale
+search — documented approximation.
 
 Stream utilities (``split_minibatches`` / ``shard_stream`` /
 ``capture_minibatch``) keep the calibration streams device-resident between
@@ -129,7 +130,7 @@ def capture_block_inputs(apply: Callable, bp, xs, auxs=None, *,
     by_id = {id(get_path(bp, p)): p for p in paths}
     stats = {p: LinearStats() for p in paths}
 
-    orig_mm, orig_emm = L.matmul, L.expert_matmul
+    orig_mm, orig_emm, orig_gmm = L.matmul, L.expert_matmul, L.grouped_matmul
 
     def rec(w, x):
         p = by_id.get(id(w))
@@ -144,11 +145,17 @@ def capture_block_inputs(apply: Callable, bp, xs, auxs=None, *,
         rec(w, a)
         return orig_emm(a, w, backend)
 
-    L.matmul, L.expert_matmul = patched_mm, patched_emm
+    def patched_gmm(x, w, layout, backend=None):
+        rec(w, x)
+        return orig_gmm(x, w, layout, backend)
+
+    L.matmul, L.expert_matmul, L.grouped_matmul = (patched_mm, patched_emm,
+                                                   patched_gmm)
     try:
         for i, x in enumerate(xs):
             aux = auxs[i] if auxs is not None else None
             apply(bp, x, aux)
     finally:
-        L.matmul, L.expert_matmul = orig_mm, orig_emm
+        L.matmul, L.expert_matmul, L.grouped_matmul = (orig_mm, orig_emm,
+                                                       orig_gmm)
     return stats

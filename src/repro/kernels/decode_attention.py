@@ -28,6 +28,11 @@ handles one (slot, chunk) and every KV head of it: a k/v block is
 ``(chunk, Hkv, D)``, whose last two dims span the whole array as the chip's
 tiling requires, and the heads are walked inside the kernel.  The per-slot
 scalars (``kv_len``, ``q_pos``, ``active``) ride in as scalar prefetch.
+
+Latent attention (``v=None``): the cache is one latent array (B, S, D)
+that a single KV head serves to every query head, as K and, its first
+``dv`` lanes, as V, so each cached row is fetched once, as one (chunk, D)
+block.
 """
 from __future__ import annotations
 
@@ -41,12 +46,12 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _decode_attn_kernel(len_ref, pos_ref, act_ref, q_ref, k_ref, v_ref,
-                        o_ref, m_ref, l_ref, acc_ref, *,
-                        csz: int, nc: int, scale: float):
+def _online_softmax(len_ref, pos_ref, act_ref, q_ref, o_ref, m_ref, l_ref,
+                    acc_ref, kv, *, csz: int, nc: int, scale: float):
     """Online softmax of one slot over chunk ``c`` of its cache, for every
-    KV head.  The dense and paged kernels differ only in which block the
-    index maps fetch, so they share this body."""
+    KV head; ``kv(h)`` gives head ``h``'s (K, V) chunk as float32.  The
+    dense, paged and latent kernels differ in the blocks their index maps
+    fetch and in ``kv``, so they share this body."""
     b = pl.program_id(0)
     c = pl.program_id(1)
 
@@ -65,8 +70,7 @@ def _decode_attn_kernel(len_ref, pos_ref, act_ref, q_ref, k_ref, v_ref,
         live = (kpos < kv_len) & (kpos <= q_pos)
         for h in range(q_ref.shape[0]):
             q = q_ref[h].astype(jnp.float32) * scale           # (G, D)
-            kb = k_ref[:, h, :].astype(jnp.float32)            # (csz, D)
-            vb = v_ref[:, h, :].astype(jnp.float32)
+            kb, vb = kv(h)                                     # (csz, D)
             s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = jnp.where(live, s, _NEG_INF)
@@ -88,38 +92,67 @@ def _decode_attn_kernel(len_ref, pos_ref, act_ref, q_ref, k_ref, v_ref,
         o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+def _decode_attn_kernel(len_ref, pos_ref, act_ref, q_ref, k_ref, v_ref,
+                        o_ref, m_ref, l_ref, acc_ref, **kw):
+    def kv(h):
+        return (k_ref[:, h, :].astype(jnp.float32),
+                v_ref[:, h, :].astype(jnp.float32))
+    _online_softmax(len_ref, pos_ref, act_ref, q_ref, o_ref, m_ref, l_ref,
+                    acc_ref, kv, **kw)
+
+
+def _latent_attn_kernel(len_ref, pos_ref, act_ref, q_ref, kv_ref, o_ref,
+                        m_ref, l_ref, acc_ref, *, dv: int, **kw):
+    """One latent row block (csz, D) serves as K and, its first ``dv``
+    lanes, as V: each cached row is read from HBM once."""
+    def kv(h):
+        kb = kv_ref[...].astype(jnp.float32)
+        return kb, kb[:, :dv]
+    _online_softmax(len_ref, pos_ref, act_ref, q_ref, o_ref, m_ref, l_ref,
+                    acc_ref, kv, **kw)
+
+
 def _paged_decode_attn_kernel(ptab_ref, *refs, **kw):
     # the page table is consumed by the k/v index maps only
     _decode_attn_kernel(*refs, **kw)
 
 
 def _attend(kernel, q, k, v, scalars, kv_index, *, csz: int, nc: int,
-            interpret: bool):
-    """The pallas_call both decode kernels share: grid (slots, chunks),
+            interpret: bool, dv=None):
+    """The pallas_call the decode kernels share: grid (slots, chunks),
     ``scalars`` as scalar prefetch, k/v blocks of ``(csz, Hkv, D)`` placed
-    by ``kv_index``."""
+    by ``kv_index``.  With ``v=None`` k is the latent (B, S, D), one block
+    of ``(csz, D)`` a step, and the output is ``dv`` lanes wide."""
     B, Hkv, G, D = q.shape
     q_spec = pl.BlockSpec((None, Hkv, G, D), lambda b, c, *_: (b, 0, 0, 0))
-    kv_spec = pl.BlockSpec((None, csz, Hkv, D), kv_index)
+    if v is None:
+        in_specs = [q_spec, pl.BlockSpec((None, csz, D), kv_index)]
+        operands = (q, k)
+    else:
+        kv_spec = pl.BlockSpec((None, csz, Hkv, D), kv_index)
+        in_specs = [q_spec, kv_spec, kv_spec]
+        operands = (q, k, v)
+        dv = D
+    out_spec = pl.BlockSpec((None, Hkv, G, dv), lambda b, c, *_: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(B, nc),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
+        in_specs=in_specs,
+        out_specs=out_spec,
         scratch_shapes=[
             pltpu.VMEM((Hkv, G, 128), jnp.float32),  # running max (col 0 live)
             pltpu.VMEM((Hkv, G, 128), jnp.float32),  # running sum (col 0 live)
-            pltpu.VMEM((Hkv, G, D), jnp.float32),    # output accumulator
+            pltpu.VMEM((Hkv, G, dv), jnp.float32),   # output accumulator
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*scalars, q, k, v)
+    )(*scalars, *operands)
 
 
 def _slot_scalars(B, kv_len, q_pos, active):
@@ -171,10 +204,11 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                    csz=psz, nc=W, interpret=interpret)
 
 
-def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array | None, *,
                      kv_len: jax.Array, q_pos: jax.Array,
                      active: jax.Array | None = None,
                      scale: float | None = None, chunk: int = 512,
+                     dv: int | None = None,
                      interpret: bool = False) -> jax.Array:
     """q: (B, Hkv, G, D); k/v: (B, S, Hkv, D) — the scheduler cache layout,
     slot dim on axis B(=0 here, axis 1 of the stacked cache), consumed
@@ -182,10 +216,28 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     length and query position.  active: (B,) bool occupancy, or None for
     all-live (lockstep serving).
 
-    Returns (B, Hkv, G, D) in q.dtype; rows of inactive slots are zero.
+    ``v=None`` is latent attention: k is one (B, S, D) array that a single
+    KV head (Hkv == 1) reads as K and, its first ``dv`` lanes, as V.
+
+    Returns (B, Hkv, G, D) in q.dtype (D = ``dv`` for latent attention);
+    rows of inactive slots are zero.
     """
     B, Hkv, G, D = q.shape
     S = k.shape[1]
+    if v is None:
+        if Hkv != 1 or k.shape != (B, S, D) or not dv or dv > D:
+            raise ValueError(f"latent attention wants q (B, 1, G, D) and a "
+                             f"latent (B, S, D) with 0 < dv <= D; got q "
+                             f"{q.shape}, k {k.shape}, dv {dv}")
+        scale = float(D) ** -0.5 if scale is None else scale
+        csz = min(chunk, S)
+        nc = pl.cdiv(S, csz)
+        kernel = functools.partial(_latent_attn_kernel, csz=csz, nc=nc,
+                                   scale=scale, dv=dv)
+        return _attend(kernel, q, k, None,
+                       _slot_scalars(B, kv_len, q_pos, active),
+                       lambda b, c, *_: (b, c, 0), csz=csz, nc=nc,
+                       interpret=interpret, dv=dv)
     if k.shape != (B, S, Hkv, D) or v.shape != (B, S, Hkv, D):
         raise ValueError(f"cache-lane layout mismatch: q {q.shape} vs "
                          f"k {k.shape} / v {v.shape}; under tensor-parallel "

@@ -18,12 +18,15 @@ from repro.kernels.decode_attention import (decode_attention,
                                             paged_decode_attention)
 from repro.kernels.int8_matmul import int8_matmul
 from repro.kernels.quant_gemv import quant_gemv
+from repro.kernels.quant_gmm import quant_gmm
 from repro.kernels.quant_matmul import quant_matmul, quant_matmul_experts
 from repro.kernels.soft_round import soft_round
 
 # decode batches (M = live slots) at or below this row count dispatch to the
-# decode-shaped GEMV kernel instead of the prefill-tiled matmul
-DECODE_GEMV_MAX_ROWS = 32
+# decode-shaped GEMV kernel instead of the prefill-tiled matmul (64: the
+# slots of a latent-attention model, whose cache is a quarter of a GQA
+# model's, at the memory a 32-slot GQA model fills)
+DECODE_GEMV_MAX_ROWS = 64
 
 
 def _interpret() -> bool:
@@ -208,6 +211,38 @@ def qtensor_expert_matmul_unrolled(a: jax.Array, w: QTensor) -> jax.Array:
     return jnp.stack(outs)
 
 
+@functools.partial(jax.jit, static_argnames=("bits", "group_size",
+                                             "row_tile"))
+def quant_gmm_op(x, packed, scale, zero, tile_expert, n_tiles, *, bits: int,
+                 group_size: int, row_tile: int):
+    """Dropless grouped matmul (see kernels/quant_gmm.py): N grows to a
+    multiple of 128 when it is wider than one lane tile; nothing else is
+    padded.  A call is one ``quant_gmm_op`` kernel in the device trace."""
+    N = packed.shape[-1]
+    lanes = 128 if N > 128 else N
+    out = quant_gmm(x, _pad_to(packed, lanes, 2), _pad_to(scale, lanes, 2),
+                    _pad_to(zero, lanes, 2), tile_expert, n_tiles, bits=bits,
+                    group_size=group_size, row_tile=row_tile,
+                    interpret=_interpret())
+    return out[:, :N]
+
+
+def qtensor_gmm(x: jax.Array, w: QTensor, tile_expert, n_tiles, *,
+                row_tile: int) -> jax.Array:
+    """Expert-sorted rows (T * row_tile, K) x expert-stacked QTensor (E, K,
+    N) -> (T * row_tile, N): tile ``t`` against expert
+    ``tile_expert[t]``, the first ``n_tiles`` tiles real."""
+    if w.act_scale is not None:
+        x = x / w.act_scale.astype(x.dtype)
+    if w.packed.ndim != 3:
+        raise ValueError(f"expected an expert-stacked QTensor, got "
+                         f"packed.ndim={w.packed.ndim}")
+    return quant_gmm_op(x, w.packed, w.scale.astype(jnp.float32),
+                        w.zero.astype(jnp.float32), tile_expert, n_tiles,
+                        bits=w.bits, group_size=w.group_size,
+                        row_tile=row_tile)
+
+
 @functools.partial(jax.jit, static_argnames=("out_dtype",))
 def int8_matmul_op(x_q, w_q, x_scale, w_scale, out_dtype=jnp.bfloat16):
     return int8_matmul(x_q, w_q, x_scale, w_scale, out_dtype=out_dtype,
@@ -259,16 +294,18 @@ def soft_round_op(base, nu, hard, v, scale, zero, *, qmax: int,
 # that inside a decode step the kernel keeps this wrapper's name: the device
 # trace shows it as ``decode_attention_op`` (``paged_decode_attention_op``)
 # and the benchmark reads it by that name.
-@functools.partial(jax.jit, static_argnames=("scale", "chunk"))
+@functools.partial(jax.jit, static_argnames=("scale", "chunk", "dv"))
 def decode_attention_op(q, k, v, *, kv_len, q_pos, active=None, scale=None,
-                        chunk: int = 512):
+                        chunk: int = 512, dv=None):
     """Slot-aware decode attention (see kernels/decode_attention.py).
 
     q: (B, Hkv, G, D); k/v: (B, S, Hkv, D) in the scheduler's cache-lane
-    layout; kv_len/q_pos: (B,); active: (B,) occupancy or None."""
+    layout; kv_len/q_pos: (B,); active: (B,) occupancy or None.  With
+    ``v=None`` the cache is one latent array k (B, S, D) read by a single
+    KV head (Hkv == 1), V its first ``dv`` lanes."""
     return decode_attention(q, k, v, kv_len=kv_len, q_pos=q_pos,
                             active=active, scale=scale, chunk=chunk,
-                            interpret=_interpret())
+                            dv=dv, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("scale",))

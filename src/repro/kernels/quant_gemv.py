@@ -144,46 +144,48 @@ def _each_cols(x_ref, size: int, fn):
             fn(i, x_ref[:, i * size:(i + 1) * size])
 
 
-def _gemv_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, xp_ref, xs_ref, *,
-                 ppb: int, unit: int, upg: int):
-    """One ``(M, bn)`` output block over all of K.
+def _fill_planes(x_ref, xp_ref, xs_ref, *, ppb: int, unit: int, upg: int):
+    """x_ref (M, K) in K order to the plane-order scratch xp_ref (K // unit,
+    M, unit), field ``f``'s columns times ``2**-(f * fbits)``, and each
+    group's sum of ``x`` to xs_ref (K // group_size, M, 1) float32."""
+    fbits = 8 // ppb
+    rows = unit // ppb
+    dt = x_ref.dtype
+    f32 = jnp.float32
+    # x's columns to plane order by a matrix on the MXU.  Its one nonzero a
+    # column is a power of two, so the product is exact in any dtype, and
+    # it takes the place of the shift that would bring each field of a
+    # byte down to bit 0
+    k = jax.lax.broadcasted_iota(jnp.int32, (unit, unit), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (unit, unit), 1)
+    weight = jnp.exp2(-((k % ppb) * fbits).astype(f32))
+    perm = jnp.where(c == (k % ppb) * rows + k // ppb, weight,
+                     0.0).astype(dt)
+    exact = jax.lax.Precision.HIGHEST if dt == f32 else None
 
-    x_ref: (M, K) in K order; p_ref: (K // ppb, bn) uint8; s_ref / z_ref:
-    (K // group_size, bn) float32.  Scratch, filled by the first grid step
-    for the rest: xp_ref (K // unit, M, unit), ``x`` in plane order with
-    field ``f``'s columns times ``2**-(f * fbits)``; xs_ref (K //
-    group_size, M, 1) float32, each group's sum of ``x``."""
+    def to_planes(u, xu):
+        xp_ref[u] = jnp.dot(xu, perm, precision=exact,
+                            preferred_element_type=f32).astype(dt)
+        if upg == 1:
+            group_sum(u, xu)
+
+    def group_sum(g, xg):
+        xs_ref[g] = jnp.sum(xg.astype(f32), axis=1, keepdims=True)
+    _each_cols(x_ref, unit, to_planes)
+    if upg > 1:
+        _each_cols(x_ref, unit * upg, group_sum)
+
+
+def _group_dot(p_ref, s_ref, z_ref, xp_ref, xs_ref, shape, *, ppb: int,
+               unit: int, upg: int):
+    """The ``shape`` (M, bn) product over all of K, float32: p_ref (K // ppb,
+    bn) uint8 codes, s_ref / z_ref (K // group_size, bn) float32, x from
+    the plane-order scratch :func:`_fill_planes` filled."""
     fbits = 8 // ppb
     mask = (1 << fbits) - 1
     rows = unit // ppb
-    dt = x_ref.dtype
-    M, bn = o_ref.shape
+    dt = xp_ref.dtype
     f32 = jnp.float32
-
-    @pl.when(pl.program_id(0) == 0)
-    def _plane_order():
-        # x's columns to plane order by a matrix on the MXU, once per call.
-        # Its one nonzero a column is a power of two, so the product is
-        # exact in any dtype, and it takes the place of the shift that
-        # would bring each field of a byte down to bit 0
-        k = jax.lax.broadcasted_iota(jnp.int32, (unit, unit), 0)
-        c = jax.lax.broadcasted_iota(jnp.int32, (unit, unit), 1)
-        weight = jnp.exp2(-((k % ppb) * fbits).astype(f32))
-        perm = jnp.where(c == (k % ppb) * rows + k // ppb, weight,
-                         0.0).astype(dt)
-        exact = jax.lax.Precision.HIGHEST if dt == f32 else None
-
-        def to_planes(u, xu):
-            xp_ref[u] = jnp.dot(xu, perm, precision=exact,
-                                preferred_element_type=f32).astype(dt)
-            if upg == 1:
-                group_sum(u, xu)
-
-        def group_sum(g, xg):
-            xs_ref[g] = jnp.sum(xg.astype(f32), axis=1, keepdims=True)
-        _each_cols(x_ref, unit, to_planes)
-        if upg > 1:
-            _each_cols(x_ref, unit * upg, group_sum)
 
     def unit_dot(u, part):
         """Unit ``u``'s codes against its x columns, added to ``part``
@@ -211,7 +213,26 @@ def _gemv_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, xp_ref, xs_ref, *,
         z = z_ref[pl.ds(g, 1), :].astype(dt).astype(f32)
         return acc + s * (part - z * xsum)
 
-    acc = _unrolled(s_ref.shape[0], group, jnp.zeros((M, bn), f32))
+    return _unrolled(s_ref.shape[0], group, jnp.zeros(shape, f32))
+
+
+def _gemv_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, xp_ref, xs_ref, *,
+                 ppb: int, unit: int, upg: int):
+    """One ``(M, bn)`` output block over all of K.
+
+    x_ref: (M, K) in K order; p_ref: (K // ppb, bn) uint8; s_ref / z_ref:
+    (K // group_size, bn) float32.  Scratch, filled by the first grid step
+    for the rest: xp_ref (K // unit, M, unit), ``x`` in plane order with
+    field ``f``'s columns times ``2**-(f * fbits)``; xs_ref (K //
+    group_size, M, 1) float32, each group's sum of ``x``."""
+    kw = dict(ppb=ppb, unit=unit, upg=upg)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _plane_order():
+        # once per call: x stays the same for every column block
+        _fill_planes(x_ref, xp_ref, xs_ref, **kw)
+
+    acc = _group_dot(p_ref, s_ref, z_ref, xp_ref, xs_ref, o_ref.shape, **kw)
     o_ref[...] = acc.astype(o_ref.dtype)
 
 

@@ -36,9 +36,10 @@ don't time themselves anyway).
 Per-request outputs are bit-identical to serving the same request alone
 through ``serve_requests`` at the same cache width: active rows see exactly
 the arguments the plain loop passes, and every op in the decode path is
-batch-row independent.  (Exception: MoE capacity dispatch couples rows by
-construction — tokens compete for per-expert capacity slots — so MoE gets
-determinism, not alone-parity.)
+batch-row independent.  (Exception: the ``moe`` family's capacity dispatch
+couples rows by construction — tokens compete for per-expert capacity
+slots — so it gets determinism, not alone-parity; the ``mla_moe`` family
+routes dropless and keeps alone-parity.)
 
 ``store="paged"`` swaps the dense per-slot lanes for a vLLM-style paged KV
 cache (``models.common.PagedCacheStore``): token leaves live in a fixed
@@ -191,6 +192,13 @@ class ServeResult:
     latency_steps: Dict[str, float]
     cache_stats: Dict[str, Any]
     extra: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    # per decode step, the "step" entries of the family's step record (for
+    # mla_moe "experts_touched" / "largest_group", (steps, expert layers)
+    # int32), fetched after the loop with the tokens; the "token" entries
+    # land in each request's record, one entry per position of prompt and
+    # decode (for mla_moe "experts", (expert layers, positions, top_k))
+    step_counters: Dict[str, np.ndarray] = dataclasses.field(
+        default_factory=dict)
 
     def __getitem__(self, key: str):
         if key in self.extra:
@@ -375,6 +383,7 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
             f"run wants {'page_size=%d' % page_size if paged else 'dense'}")
     model = steps_.model
     spec = model.cache_spec
+    params = model.serve_params(params)
 
     # TP serving: commit params/cache — and every host push below — to the
     # ServeSpec placement ONCE.  Anything left committed to device 0 would
@@ -442,7 +451,8 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
            for r in order}
     pending = deque(order)
     inflight = None       # at most one chunked prefill in flight
-    trace = []            # (active snapshot, slot->rid snapshot, tok)
+    trace = []            # (active, slot->rid snapshots, tok, record|None)
+    prefill_rec = {}      # rid -> the family's prefill record, if any
     t = 0                 # scheduler clock, in decode steps dispatched
     steps = 0
     occupancy_acc = 0
@@ -512,7 +522,9 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
                     for k, v in (req.extras or {}).items():
                         batch[k] = put(v[None])
                     c1 = place_cache(model.init_cache(1, max_seq))
-                    lg1, c1 = steps_.prefill(params, batch, c1)
+                    lg1, c1, *rec = steps_.prefill(params, batch, c1)
+                    if rec:
+                        prefill_rec[req.rid] = rec[0]
                 with jax.profiler.TraceAnnotation(SPAN_FIRST_TOKEN):
                     # reprolint: ok[host-sync] — the only per-admission sync (counted); explicit device_get so transfer_guard allows it
                     tok0 = int(np.asarray(jax.device_get(jnp.argmax(lg1, -1)))[0])
@@ -542,13 +554,12 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
                                               start=cur, end=end):
                 chunk = {"tokens": put(req.prompt[None, cur:end])}
                 if paged:
-                    lg1, cache = steps_.prefill(params, chunk, cache,
-                                                i32(cur),
-                                                push(cstore.ptab_h[s:s + 1]))
+                    lg1, cache, *_ = steps_.prefill(
+                        params, chunk, cache, i32(cur),
+                        push(cstore.ptab_h[s:s + 1]))
                 else:
-                    lg1, inflight["c1"] = steps_.prefill(params, chunk,
-                                                         inflight["c1"],
-                                                         i32(cur))
+                    lg1, inflight["c1"], *_ = steps_.prefill(
+                        params, chunk, inflight["c1"], i32(cur))
                 inflight["cursor"] = end
                 if end == plen:
                     # reprolint: ok[host-sync] — per-admission sync, chunked path (same contract as above)
@@ -588,10 +599,10 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
                 ptab_d = push(cstore.ptab_h)
             # ---- one masked decode step over every slot -------------------
             if paged:
-                logits, tok, pos, cache = steps_.decode(
+                logits, tok, pos, cache, *rec = steps_.decode(
                     params, cache, tok, pos, active_d, ptab_d)
             else:
-                logits, tok, pos, cache = steps_.decode(
+                logits, tok, pos, cache, *rec = steps_.decode(
                     params, cache, tok, pos, active_d)
             if collect_logits:
                 # eager per-step fetch of ACTIVE rows only: bounded device
@@ -601,7 +612,8 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
                 for s in np.flatnonzero(active_h):
                     res[slot_rid[s]]["logits"].append(lg_np[s])
             del logits
-            trace.append((active_h.copy(), slot_rid.copy(), tok))
+            trace.append((active_h.copy(), slot_rid.copy(), tok,
+                          rec[0] if rec else None))
             steps += 1
             occupancy_acc += live
             t += 1
@@ -624,12 +636,30 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
     decode_secs = max(total_secs - prefill_secs, 1e-9)
 
     # ---- reconstruct per-request streams (host transfers OFF the clock) ---
+    step_counters = {}
     with jax.profiler.TraceAnnotation(SPAN_FETCH, steps=len(trace)):
-        for mask, rids, tok_d in trace:
+        for mask, rids, tok_d, _ in trace:
             # reprolint: ok[host-sync] — off-clock stream reconstruction; timed region already closed
             tok_np = np.asarray(jax.device_get(tok_d))
             for s in np.flatnonzero(mask):
                 res[rids[s]]["tokens"].append(int(tok_np[s]))
+        if prefill_rec:
+            # reprolint: ok[host-sync] — off-clock fetch of the step records, one call for the wave
+            steps_rec, pre_rec = jax.device_get(
+                ([t[3] for t in trace], prefill_rec))
+            step_counters = {name: np.stack([r["step"][name]
+                                             for r in steps_rec])
+                             for name in (steps_rec[0]["step"]
+                                          if steps_rec else ())}
+            per_token = {rid: {n: [a[:, 0]] for n, a in r["token"].items()}
+                         for rid, r in pre_rec.items()}
+            for (mask, rids, _, _), r in zip(trace, steps_rec):
+                for s in np.flatnonzero(mask):
+                    for n, a in r["token"].items():
+                        per_token[rids[s]][n].append(a[:, s, None])
+            for rid, recs in per_token.items():
+                res[rid].update({n: np.concatenate(parts, axis=1)
+                                 for n, parts in recs.items()})
 
     useful = 0
     latencies = []
@@ -656,6 +686,7 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
         cache_stats=cstore.stats(),
         extra={"prefill_chunk": prefill_chunk if chunk_ok else 0,
                "share_prefix": share_ok},
+        step_counters=step_counters,
     )
 
 

@@ -158,6 +158,7 @@ def serve_requests(cfg, model, params, prompts, *, gen: int,
     pstep, dstep = compiled if compiled is not None else compile_serve_steps(
         cfg, kernel_backend=kernel_backend, act_bits=act_bits, mesh=mesh,
         tp_shard=tp_shard)
+    params = model.serve_params(params)
 
     # TP serving: commit params/cache to their ServeSpec placement ONCE,
     # off the timed loop — otherwise every jitted step dispatch reshards

@@ -85,6 +85,9 @@ PARAM_RULES = {
     "ck": ("fsdp", "tensor"), "cv": ("tensor", "fsdp"), "cr": ("fsdp", "tensor"),
     # mamba2
     "in_proj": ("fsdp", None), "out_proj": ("tensor", "fsdp"),
+    # latent attention: the latent is shared by every head, its
+    # decompression is per head
+    "wkv_a": ("fsdp", None), "wkv_b": ("fsdp", "tensor"),
     # embeddings / head
     "embed": ("vocab", "fsdp"), "head": ("fsdp", "vocab"),
     "router": (None, None),

@@ -186,7 +186,7 @@ def make_serve_steps(cfg: ModelConfig, mesh=None, *, act_bits=None,
                      attn_chunk: int = 512, extra_overrides=None,
                      kv_bits=None, kernel_backend=None,
                      decode_attn_chunk: int = 1 << 30, page_size: int = 0,
-                     tp_shard: bool = False):
+                     tp_shard: bool = False, record: bool = False):
     """``kernel_backend`` ("xla" | "pallas" | None = env/default) selects the
     QTensor matmul path for BOTH the prefill and decode steps — this is the
     explicit per-run dispatch the serving launcher and benchmarks use.
@@ -204,8 +204,18 @@ def make_serve_steps(cfg: ModelConfig, mesh=None, *, act_bits=None,
     shard_map over ``tp_axis(mesh)`` with per-leaf specs derived from the
     contract, packed QTensor leaves reaching the kernels as LOCAL shards.
     This is opt-in — the default ``mesh=`` path keeps today's GSPMD
-    annotation-only behavior (used by the dry-run's serve sharding cells)."""
+    annotation-only behavior (used by the dry-run's serve sharding cells).
+
+    ``record=True``: for a family with ``Model.prefill_record`` /
+    ``decode_record`` both steps return ``(logits, cache, record)``, the
+    record a dict of device arrays: ``"step"`` entries describe a decode
+    step, ``"token"`` entries have an entry per token (the slot axis, or
+    the prompt's positions, at axis 1)."""
     if tp_shard:
+        if cfg.family == "mla_moe":
+            raise NotImplementedError(
+                "tensor-parallel serving of latent attention is not "
+                "implemented (no ServeSpec split table for the family)")
         if mesh is None:
             raise ValueError("make_serve_steps: tp_shard=True requires a "
                              "mesh (build one with launch.mesh.serve_mesh)")
@@ -228,13 +238,18 @@ def make_serve_steps(cfg: ModelConfig, mesh=None, *, act_bits=None,
                     kernel_backend=kernel_backend, kv_bits=kv_bits,
                     page_size=page_size)
 
+    prefill = (model.prefill_record if record and model.prefill_record
+               else model.prefill)
+    decode = (model.decode_record if record and model.decode_record
+              else model.decode_step)
+
     def prefill_step(params, batch, cache, start_pos=0, ptab=None):
-        return model.prefill(params, batch, cache, ctx,
-                             start_pos=start_pos, ptab=ptab)
+        return prefill(params, batch, cache, ctx, start_pos=start_pos,
+                       ptab=ptab)
 
     def decode_step(params, cache, tokens, pos, active=None, ptab=None):
-        return model.decode_step(params, cache, tokens, pos, dctx,
-                                 active=active, ptab=ptab)
+        return decode(params, cache, tokens, pos, dctx, active=active,
+                      ptab=ptab)
 
     return model, prefill_step, decode_step
 
@@ -408,12 +423,16 @@ def make_sched_steps(cfg: ModelConfig, mesh=None, *, max_seq: int,
     Active rows see EXACTLY the arguments the plain serve loop passes
     (same pos, same kv_len), which is what makes scheduled decode
     bit-compatible with serving a request alone.
+
+    For a family with ``Model.prefill_record`` / ``decode_record`` the
+    prefill returns its record as a third output and the decode step as a
+    fifth (see ``make_serve_steps``).
     """
     model, prefill_step, decode_step = make_serve_steps(
         cfg, mesh, act_bits=act_bits, attn_chunk=attn_chunk,
         extra_overrides=extra_overrides, kv_bits=kv_bits,
         kernel_backend=kernel_backend, decode_attn_chunk=decode_attn_chunk,
-        page_size=page_size, tp_shard=tp_shard)
+        page_size=page_size, tp_shard=tp_shard, record=True)
 
     def sched_decode_step(params, cache, tok, pos, active, ptab=None):
         write_pos = jnp.where(active, pos, max_seq)
@@ -422,12 +441,12 @@ def make_sched_steps(cfg: ModelConfig, mesh=None, *, max_seq: int,
         # (paged: write_pos == max_seq maps past the page table, where
         # page_write_tokens' sentinel index drops the write — the paged
         # analog of update_cache's out-of-range masked no-op)
-        logits, cache = decode_step(params, cache, tok, write_pos,
-                                    active=active, ptab=ptab)
+        logits, cache, *record = decode_step(params, cache, tok, write_pos,
+                                             active=active, ptab=ptab)
         nxt = jnp.argmax(logits, -1).astype(jnp.int32)
         tok = jnp.where(active, nxt, tok)
         pos = jnp.where(active, pos + 1, pos)
-        return logits, tok, pos, cache
+        return (logits, tok, pos, cache, *record)
 
     return model, prefill_step, sched_decode_step
 

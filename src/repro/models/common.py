@@ -207,12 +207,15 @@ class CacheSpec:
     its outputs).  ``shareable`` marks families whose full prompt-prefix
     pages may be shared copy-on-write across requests (requires
     ``chunkable`` plus a prompt that is fully described by its token ids).
+    ``pageable`` marks families whose token leaves the paged store may pool
+    (their steps walk a page table).
     """
     family: str
     leaves: Tuple[Tuple[str, LeafSpec], ...]
     slot_axis: int = CACHE_SLOT_AXIS
     chunkable: bool = False
     shareable: bool = False
+    pageable: bool = True
 
     def leaf(self, path: str) -> LeafSpec:
         for p, ls in self.leaves:
@@ -435,6 +438,10 @@ class PagedCacheStore:
         if num_pages < 1:
             raise ValueError(f"need at least one page, got {num_pages}")
         self.spec = model.cache_spec
+        if not self.spec.pageable:
+            raise NotImplementedError(
+                f"family {self.spec.family!r} has no paged serving: its "
+                f"CacheSpec is not pageable; use the dense store")
         self.slots, self.max_seq = slots, max_seq
         self.page_size, self.num_pages = page_size, num_pages
         self.W = max_seq // page_size

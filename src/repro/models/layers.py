@@ -91,6 +91,31 @@ def expert_matmul(a: jax.Array, w, backend: Optional[str] = None) -> jax.Array:
     return jnp.einsum("ecd,edf->ecf", a, w)
 
 
+def grouped_matmul(x: jax.Array, w, layout, backend: Optional[str] = None
+                   ) -> jax.Array:
+    """Dropless grouped matmul: rows of ``x`` (rows, K) sorted by expert and
+    padded per expert to whole tiles as ``layout``
+    (``models.mla_moe.GroupLayout``) places them, against expert-stacked
+    ``w`` (E, K, N).  Rows of tiles past the real ones come back
+    unspecified; callers read only the rows the layout gives a pair.
+
+    The pallas backend runs the ``quant_gmm`` kernel.  Otherwise each tile
+    is multiplied by its expert's weight, gathered per tile: a plain batched
+    matmul that ``vmap`` and ``grad`` (the reconstruction engine) go
+    through."""
+    if isinstance(w, QTensor):
+        if resolve_backend(backend) == "pallas":
+            from repro.kernels.ops import qtensor_gmm
+            return qtensor_gmm(x, w, layout.tile_expert, layout.n_tiles,
+                               row_tile=layout.tm)
+        if w.act_scale is not None:
+            x = x / w.act_scale.astype(x.dtype)
+        w = w.dequantize(x.dtype)
+    xt = x.reshape(-1, layout.tm, x.shape[-1])
+    wt = jnp.take(w.astype(x.dtype), layout.tile_expert, axis=0)
+    return jnp.einsum("tmk,tkn->tmn", xt, wt).reshape(x.shape[0], -1)
+
+
 def fake_quant_act(x: jax.Array, bits: int, symmetric: bool = True) -> jax.Array:
     """Per-token dynamic activation quantization (simulated).
 

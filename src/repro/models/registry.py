@@ -7,6 +7,10 @@
     decode_step(params, cache, tokens, pos, ctx, active, ptab) -> (logits, cache)
     init_cache(batch, max_seq, dtype) -> cache
     cache_spec: CacheSpec                           (declared cache layout)
+    prefill_record / decode_record: the same steps returning also
+        a dict of device arrays the scheduler fetches after the wave
+        (None for families that record nothing)
+    serve_params(params) -> params                  (once, before serving)
 Batches are dicts: {"tokens"} (+ "frames" for encdec, "patches" for vlm).
 
 ``cache_spec`` is the explicit cache contract (see README "Cache
@@ -20,12 +24,12 @@ runs pass None and families without token leaves ignore it.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable
+from typing import Callable, Optional
 
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import encdec, hybrid, rwkv, transformer, vlm
+from repro.models import encdec, hybrid, mla_moe, rwkv, transformer, vlm
 from repro.models.common import (CacheSpec, DEFAULT_CTX, LEAF_FIXED,
                                  LEAF_STATE, LEAF_TOKEN, LeafSpec)
 
@@ -48,6 +52,9 @@ _FIXED = LeafSpec(LEAF_FIXED)
 #   vlm    — the image-patch prefix (prefix-LM mask) complicates chunk
 #            boundaries; kept whole-prefill, never shared (patch
 #            embeddings aren't captured by prompt-token identity).
+#   mla_moe — one latent token leaf per layer kind; prefill attends over
+#            the prompt's own latents only, and the decode kernel reads
+#            dense slot lanes: whole prefill on the dense store only.
 CACHE_SPECS = {
     "dense": CacheSpec("dense", (("k", _TOKEN), ("v", _TOKEN)),
                        chunkable=True, shareable=True),
@@ -60,6 +67,9 @@ CACHE_SPECS = {
     "encdec": CacheSpec("encdec", (("self_k", _TOKEN), ("self_v", _TOKEN),
                                    ("cross_k", _FIXED), ("cross_v", _FIXED))),
     "vlm": CacheSpec("vlm", (("k", _TOKEN), ("v", _TOKEN))),
+    "mla_moe": CacheSpec("mla_moe", (("latent", _TOKEN),
+                                     ("latent_dense", _TOKEN)),
+                         pageable=False),
 }
 
 
@@ -72,6 +82,13 @@ class Model:
     decode_step: Callable
     init_cache: Callable
     cache_spec: CacheSpec
+    # prefill / decode that also return (..., record): a dict of device
+    # arrays the scheduler fetches after the wave (the mla_moe family's
+    # routing counters and per-token experts); None for families without
+    prefill_record: Optional[Callable] = None
+    decode_record: Optional[Callable] = None
+    # the params as serving wants them, made once before serving
+    serve_params: Callable = lambda params: params
 
 
 def get_model(cfg: ModelConfig) -> Model:
@@ -91,6 +108,28 @@ def get_model(cfg: ModelConfig) -> Model:
             init_cache=lambda batch, max_seq, dtype=jnp.bfloat16:
                 transformer.init_cache(cfg, batch, max_seq, dtype),
             cache_spec=spec,
+        )
+    if fam == "mla_moe":
+        def prefill_record(p, b, c, ctx=DEFAULT_CTX, start_pos=0, ptab=None):
+            return mla_moe.prefill(p, cfg, b["tokens"], c, ctx,
+                                   start_pos=start_pos, ptab=ptab)
+
+        def decode_record(p, c, t, pos, ctx=DEFAULT_CTX, active=None,
+                          ptab=None):
+            return mla_moe.decode_step(p, cfg, c, t, pos, ctx, active=active,
+                                       ptab=ptab)
+        return Model(
+            cfg,
+            init_params=lambda key: mla_moe.init_params(cfg, key),
+            loss_fn=lambda p, b, ctx=DEFAULT_CTX: mla_moe.loss_fn(p, cfg, b, ctx),
+            prefill=lambda *a, **kw: prefill_record(*a, **kw)[:2],
+            decode_step=lambda *a, **kw: decode_record(*a, **kw)[:2],
+            init_cache=lambda batch, max_seq, dtype=jnp.bfloat16:
+                mla_moe.init_cache(cfg, batch, max_seq, dtype),
+            cache_spec=spec,
+            prefill_record=prefill_record,
+            decode_record=decode_record,
+            serve_params=lambda params: mla_moe.serve_params(params, cfg),
         )
     if fam == "rwkv":
         return Model(

@@ -56,6 +56,9 @@ def test_prefill_decode_consistency(arch):
     if fam in ("dense", "moe"):
         from repro.models import transformer as T
         full = T.forward(params, cfg, tokens)
+    elif fam == "mla_moe":
+        from repro.models import mla_moe as MM
+        full = MM.forward(params, cfg, tokens)
     elif fam == "rwkv":
         from repro.models import rwkv as R
         full = R.forward(params, cfg, tokens)

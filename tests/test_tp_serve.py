@@ -43,7 +43,7 @@ needs4 = pytest.mark.skipif(
            "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
 
 # one arch per family (vlm/hybrid wrap dense; moe/encdec/rwkv/ssm distinct)
-FAMILY_ARCHS = ["llama2-7b", "moonshot-v1-16b-a3b", "whisper-small",
+FAMILY_ARCHS = ["llama2-7b", "qwen3-moe-30b-a3b", "whisper-small",
                 "rwkv6-3b", "zamba2-1.2b", "paligemma-3b"]
 
 

@@ -1,7 +1,9 @@
 """Ahead-of-time compiles of the serve-path Pallas kernels for a described
 TPU v5e chip, at tinyllama-1.1b widths (K=2048, N=5632; 4 KV heads of 64,
-8 query heads per KV head) and, for the expert GEMM, qwen3-moe-30b-a3b's
-(K=2048, N=768 per expert).
+8 query heads per KV head), for the expert GEMM at qwen3-moe-30b-a3b's
+(K=2048, N=768 per expert), and for the grouped expert matmul and the
+latent decode attention at Moonlight-16B-A3B's (64 experts of 2048 x 1408;
+a 576-wide latent read by 16 heads over 64 slots of 1472).
 
 Interpret mode, which the rest of the suite runs the kernels in, accepts
 layouts the chip's compiler refuses (uint8 -> float casts, scale blocks
@@ -21,6 +23,7 @@ from repro.core.qtensor import PACK_FACTOR
 from repro.kernels.decode_attention import (decode_attention,
                                             paged_decode_attention)
 from repro.kernels.quant_gemv import quant_gemv
+from repro.kernels.quant_gmm import quant_gmm
 from repro.kernels.quant_matmul import quant_matmul, quant_matmul_experts
 
 K, N = 2048, 5632
@@ -107,6 +110,38 @@ def test_quant_matmul_experts_compiles(one_chip):
     _compile(lambda x, p, s, z: quant_matmul_experts(x, p, s, z, bits=2,
                                                      group_size=128),
              *_packed_operands(one_chip, 2, 128, 128, n=N_EXPERT, lead=(8,)))
+
+
+# Moonlight's expert matmuls: gate/up (2048 x 1408) and down (1408 x
+# 2048) over 64 experts, at a decode step's rows (64 slots x top-6 in
+# tiles of 16) and a 1280-token prefill's (tiles of 128)
+@pytest.mark.parametrize("k,n,tm,rows", [
+    (2048, 1408, 16, 384), (1408, 2048, 16, 384),
+    (2048, 1408, 128, 7680), (1408, 2048, 128, 7680),
+], ids=["gate-decode", "down-decode", "gate-prefill", "down-prefill"])
+def test_quant_gmm_compiles(one_chip, k, n, tm, rows):
+    E = 64
+    tiles = -(-rows // tm) + E
+    x, p, s, z = _packed_operands(one_chip, 2, 128, tiles * tm, k=k, n=n)
+    lead = lambda a: jax.ShapeDtypeStruct((E,) + a.shape, a.dtype,
+                                          sharding=one_chip)
+    te = jax.ShapeDtypeStruct((tiles,), jnp.int32, sharding=one_chip)
+    nt = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    _compile(lambda x, p, s, z, te, nt: quant_gmm(
+        x, p, s, z, te, nt, bits=2, group_size=128, row_tile=tm),
+        x, lead(p), lead(s), lead(z), te, nt)
+
+
+def test_latent_decode_attention_compiles(one_chip):
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    slots, S, heads, r, rope = 64, 1472, 16, 512, 64
+    vec = sds((slots,), jnp.int32)
+    _compile(lambda q, c, n, p, a: decode_attention(
+        q, c, None, kv_len=n, q_pos=p, active=a, scale=192 ** -0.5,
+        chunk=1 << 30, dv=r),
+        sds((slots, 1, heads, r + rope), jnp.bfloat16),
+        sds((slots, S, r + rope), jnp.bfloat16), vec, vec,
+        sds((slots,), jnp.bool_))
 
 
 def _attn_operands(one_chip):
