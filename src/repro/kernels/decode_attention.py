@@ -6,10 +6,14 @@ slots are live in an occupancy vector.  The XLA decode fast path computes
 dense (slots, heads, max_seq) scores and masks post-hoc — every retired or
 empty slot still pays full attention FLOPs and full cache reads.
 
-This kernel reads the cache-lane layout directly (k/v blocks are indexed
-``(b, c, 0, 0)`` straight into the (slots, S, Hkv, D) cache — no transpose,
-no copy) and makes the occupancy vector and ragged per-slot lengths part of
-the kernel contract:
+This kernel reads the cache-lane layout directly and makes the occupancy
+vector and ragged per-slot lengths part of the kernel contract.  The decode
+step hands it the whole stacked leaf (layers, slots, S, Hkv, D) and the
+layer's index, which rides in as one more scalar prefetch: k/v blocks are
+indexed ``(layer, b, c, 0, 0)`` straight into the cache the step carries
+and updates in place — no slice of the layer, no transpose, no copy.  A
+per-layer (slots, S, Hkv, D) lane without an index (tests, the paged pool's
+fallback, callers outside the decode step) is read as ``(b, c, 0, 0)``:
 
   * ``active``: inactive slots skip ALL compute via ``@pl.when`` and emit
     zeros (their accumulator never initializes past zero);
@@ -23,16 +27,17 @@ preserves the scheduler's bit-identity contract (scheduled tokens ==
 serving the request alone at the same max_seq).
 
 q layout: (B, Hkv, G, D) — GQA query groups folded next to their KV head.
-k/v: (B, S, Hkv, D), the scheduler's native cache layout.  One program
-handles one (slot, chunk) and every KV head of it: a k/v block is
-``(chunk, Hkv, D)``, whose last two dims span the whole array as the chip's
-tiling requires, and the heads are walked inside the kernel.  The per-slot
-scalars (``kv_len``, ``q_pos``, ``active``) ride in as scalar prefetch.
+k/v: (B, S, Hkv, D), the scheduler's native cache layout, or the stacked
+(L, B, S, Hkv, D) leaf with ``layer``.  One program handles one (slot,
+chunk) and every KV head of it: a k/v block is ``(chunk, Hkv, D)``, whose
+last two dims span the whole array as the chip's tiling requires, and the
+heads are walked inside the kernel.  The scalars (``layer`` when given, then
+the per-slot ``kv_len``, ``q_pos``, ``active``) ride in as scalar prefetch.
 
-Latent attention (``v=None``): the cache is one latent array (B, S, D)
-that a single KV head serves to every query head, as K and, its first
-``dv`` lanes, as V, so each cached row is fetched once, as one (chunk, D)
-block.
+Latent attention (``v=None``): the cache is one latent array (B, S, D), or
+the stacked (L, B, S, D) leaf with ``layer``, that a single KV head serves
+to every query head, as K and, its first ``dv`` lanes, as V, so each cached
+row is fetched once, as one (chunk, D) block.
 """
 from __future__ import annotations
 
@@ -44,6 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+_VMEM_DEFAULT = 16 * 2**20      # a kernel's scoped VMEM unless it asks
 
 
 def _online_softmax(len_ref, pos_ref, act_ref, q_ref, o_ref, m_ref, l_ref,
@@ -112,28 +118,37 @@ def _latent_attn_kernel(len_ref, pos_ref, act_ref, q_ref, kv_ref, o_ref,
                     acc_ref, kv, **kw)
 
 
-def _paged_decode_attn_kernel(ptab_ref, *refs, **kw):
-    # the page table is consumed by the k/v index maps only
-    _decode_attn_kernel(*refs, **kw)
+def _index_only(kernel):
+    """``kernel`` behind a leading scalar-prefetch ref (the page table, or
+    the layer index) that only the k/v index maps consume."""
+    def wrapped(index_ref, *refs, **kw):
+        kernel(*refs, **kw)
+    return wrapped
 
 
 def _attend(kernel, q, k, v, scalars, kv_index, *, csz: int, nc: int,
-            interpret: bool, dv=None):
+            interpret: bool, dv=None, lead: int = 0):
     """The pallas_call the decode kernels share: grid (slots, chunks),
     ``scalars`` as scalar prefetch, k/v blocks of ``(csz, Hkv, D)`` placed
-    by ``kv_index``.  With ``v=None`` k is the latent (B, S, D), one block
-    of ``(csz, D)`` a step, and the output is ``dv`` lanes wide."""
+    by ``kv_index``, under ``lead`` squeezed leading (layer) axes.  With
+    ``v=None`` k is the latent (B, S, D), one block of ``(csz, D)`` a
+    step, and the output is ``dv`` lanes wide."""
     B, Hkv, G, D = q.shape
+    sq = (None,) * (1 + lead)
     q_spec = pl.BlockSpec((None, Hkv, G, D), lambda b, c, *_: (b, 0, 0, 0))
     if v is None:
-        in_specs = [q_spec, pl.BlockSpec((None, csz, D), kv_index)]
+        in_specs = [q_spec, pl.BlockSpec(sq + (csz, D), kv_index)]
         operands = (q, k)
     else:
-        kv_spec = pl.BlockSpec((None, csz, Hkv, D), kv_index)
+        kv_spec = pl.BlockSpec(sq + (csz, Hkv, D), kv_index)
         in_specs = [q_spec, kv_spec, kv_spec]
         operands = (q, k, v)
         dv = D
     out_spec = pl.BlockSpec((None, Hkv, G, dv), lambda b, c, *_: (b, 0, 0, 0))
+    # double-buffered k/v blocks and one head's float32 chunk of each: a
+    # wide cache's blocks outgrow the default scoped VMEM of a kernel
+    need = (2 * sum(a.dtype.itemsize for a in operands[1:]) * csz
+            * k.shape[-1] * (1 if v is None else Hkv) + 2 * 4 * csz * D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(B, nc),
@@ -150,7 +165,9 @@ def _attend(kernel, q, k, v, scalars, kv_index, *, csz: int, nc: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=need + need // 4 if need > _VMEM_DEFAULT
+            else None),
         interpret=interpret,
     )(*scalars, *operands)
 
@@ -195,8 +212,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     if ptab.shape != (B, W):
         raise ValueError(f"ptab {ptab.shape} is not (B={B}, W)")
     scale = float(D) ** -0.5 if scale is None else scale
-    kernel = functools.partial(_paged_decode_attn_kernel, csz=psz, nc=W,
-                               scale=scale)
+    kernel = functools.partial(_index_only(_decode_attn_kernel), csz=psz,
+                               nc=W, scale=scale)
     return _attend(kernel, q, k_pool, v_pool,
                    (ptab.astype(jnp.int32),
                     *_slot_scalars(B, kv_len, q_pos, active)),
@@ -207,6 +224,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array | None, *,
                      kv_len: jax.Array, q_pos: jax.Array,
                      active: jax.Array | None = None,
+                     layer: jax.Array | None = None,
                      scale: float | None = None, chunk: int = 512,
                      dv: int | None = None,
                      interpret: bool = False) -> jax.Array:
@@ -216,40 +234,47 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array | None, *,
     length and query position.  active: (B,) bool occupancy, or None for
     all-live (lockstep serving).
 
-    ``v=None`` is latent attention: k is one (B, S, D) array that a single
-    KV head (Hkv == 1) reads as K and, its first ``dv`` lanes, as V.
+    ``layer`` (int32 scalar): k/v are the stacked leaves (L, B, S, Hkv, D)
+    and the kernel reads layer ``layer`` of them in place.
+
+    ``v=None`` is latent attention: k is one (B, S, D) array, or (L, B, S,
+    D) with ``layer``, that a single KV head (Hkv == 1) reads as K and, its
+    first ``dv`` lanes, as V.
 
     Returns (B, Hkv, G, D) in q.dtype (D = ``dv`` for latent attention);
     rows of inactive slots are zero.
     """
     B, Hkv, G, D = q.shape
-    S = k.shape[1]
-    if v is None:
-        if Hkv != 1 or k.shape != (B, S, D) or not dv or dv > D:
-            raise ValueError(f"latent attention wants q (B, 1, G, D) and a "
-                             f"latent (B, S, D) with 0 < dv <= D; got q "
-                             f"{q.shape}, k {k.shape}, dv {dv}")
-        scale = float(D) ** -0.5 if scale is None else scale
-        csz = min(chunk, S)
-        nc = pl.cdiv(S, csz)
-        kernel = functools.partial(_latent_attn_kernel, csz=csz, nc=nc,
-                                   scale=scale, dv=dv)
-        return _attend(kernel, q, k, None,
-                       _slot_scalars(B, kv_len, q_pos, active),
-                       lambda b, c, *_: (b, c, 0), csz=csz, nc=nc,
-                       interpret=interpret, dv=dv)
-    if k.shape != (B, S, Hkv, D) or v.shape != (B, S, Hkv, D):
-        raise ValueError(f"cache-lane layout mismatch: q {q.shape} vs "
-                         f"k {k.shape} / v {v.shape}; under tensor-parallel "
-                         "serving Hkv is the SHARD-local KV-head count — "
-                         "cache lanes shard over heads with q, so a "
-                         "mismatch means the cache specs and the param "
-                         "plan disagree (launch.sharding.ServeSpec)")
+    lead = 0 if layer is None else 1
+    S = k.shape[lead + 1]
     scale = float(D) ** -0.5 if scale is None else scale
     csz = min(chunk, S)
     nc = pl.cdiv(S, csz)
-    kernel = functools.partial(_decode_attn_kernel, csz=csz, nc=nc,
-                               scale=scale)
-    return _attend(kernel, q, k, v, _slot_scalars(B, kv_len, q_pos, active),
-                   lambda b, c, *_: (b, c, 0, 0),
-                   csz=csz, nc=nc, interpret=interpret)
+    scalars = _slot_scalars(B, kv_len, q_pos, active)
+    if layer is not None:
+        scalars = (jnp.asarray(layer, jnp.int32).reshape(1),) + scalars
+    if v is None:
+        if Hkv != 1 or k.shape[lead:] != (B, S, D) or not dv or dv > D:
+            raise ValueError(f"latent attention wants q (B, 1, G, D) and a "
+                             f"latent ([L,] B, S, D) with 0 < dv <= D; got "
+                             f"q {q.shape}, k {k.shape}, dv {dv}")
+        kernel = functools.partial(_latent_attn_kernel, csz=csz, nc=nc,
+                                   scale=scale, dv=dv)
+        index = ((lambda b, c, *_: (b, c, 0)) if layer is None else
+                 (lambda b, c, lr, *_: (lr[0], b, c, 0)))
+    else:
+        if k.shape[lead:] != (B, S, Hkv, D) or v.shape != k.shape:
+            raise ValueError(
+                f"cache-lane layout mismatch: q {q.shape} vs k {k.shape} / "
+                f"v {v.shape}; under tensor-parallel serving Hkv is the "
+                "SHARD-local KV-head count — cache lanes shard over heads "
+                "with q, so a mismatch means the cache specs and the param "
+                "plan disagree (launch.sharding.ServeSpec)")
+        kernel = functools.partial(_decode_attn_kernel, csz=csz, nc=nc,
+                                   scale=scale)
+        index = ((lambda b, c, *_: (b, c, 0, 0)) if layer is None else
+                 (lambda b, c, lr, *_: (lr[0], b, c, 0, 0)))
+    if layer is not None:
+        kernel = _index_only(kernel)
+    return _attend(kernel, q, k, v, scalars, index, csz=csz, nc=nc,
+                   interpret=interpret, dv=dv, lead=lead)

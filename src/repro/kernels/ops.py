@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core.qtensor import PACK_FACTOR, QTensor
 from repro.kernels import ref
+from repro.kernels.cache_write import cache_write
 from repro.kernels.decode_attention import (decode_attention,
                                             paged_decode_attention)
 from repro.kernels.int8_matmul import int8_matmul
@@ -290,22 +291,32 @@ def soft_round_op(base, nu, hard, v, scale, zero, *, qmax: int,
                       qmax=qmax, dst=dst, interpret=_interpret())
 
 
+@jax.jit
+def cache_write_op(leaf, new, layer, pos):
+    """A decode step's in-place cache write (see kernels/cache_write.py):
+    ``new`` (B, *tail) into the stacked leaf (L, B, S, *tail) at ``(layer,
+    b, pos[b])``, rows at ``pos >= S`` dropped.  Jitted on its own so the
+    trace names the kernel ``cache_write_op``."""
+    return cache_write(leaf, new, layer, pos, interpret=_interpret())
+
+
 # Decode attention is jitted on its own, like the matmul wrappers above, so
 # that inside a decode step the kernel keeps this wrapper's name: the device
 # trace shows it as ``decode_attention_op`` (``paged_decode_attention_op``)
 # and the benchmark reads it by that name.
 @functools.partial(jax.jit, static_argnames=("scale", "chunk", "dv"))
-def decode_attention_op(q, k, v, *, kv_len, q_pos, active=None, scale=None,
-                        chunk: int = 512, dv=None):
+def decode_attention_op(q, k, v, *, kv_len, q_pos, active=None, layer=None,
+                        scale=None, chunk: int = 512, dv=None):
     """Slot-aware decode attention (see kernels/decode_attention.py).
 
     q: (B, Hkv, G, D); k/v: (B, S, Hkv, D) in the scheduler's cache-lane
-    layout; kv_len/q_pos: (B,); active: (B,) occupancy or None.  With
-    ``v=None`` the cache is one latent array k (B, S, D) read by a single
-    KV head (Hkv == 1), V its first ``dv`` lanes."""
+    layout, or the stacked (L, B, S, Hkv, D) leaves with ``layer`` (int32
+    scalar), read in place; kv_len/q_pos: (B,); active: (B,) occupancy or
+    None.  With ``v=None`` the cache is one latent array k ([L,] B, S, D)
+    read by a single KV head (Hkv == 1), V its first ``dv`` lanes."""
     return decode_attention(q, k, v, kv_len=kv_len, q_pos=q_pos,
-                            active=active, scale=scale, chunk=chunk,
-                            dv=dv, interpret=_interpret())
+                            active=active, layer=layer, scale=scale,
+                            chunk=chunk, dv=dv, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("scale",))

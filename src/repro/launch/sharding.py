@@ -349,8 +349,8 @@ def cache_shardings(mesh, cache_struct, cfg: ModelConfig):
                 spec[hdim] = tp
             elif leaf.ndim == 5 and leaf.shape[2] % mesh.shape[tp] == 0:
                 # GQA with kv_heads < TP degree: shard the *sequence* dim —
-                # decode uses a masked (non-scatter) cache write and a
-                # single-row softmax, both of which partition over seq with
+                # decode's row write is a scatter batched over slots and
+                # its softmax a single row, which partition over seq with
                 # only two small psums (§Perf iteration A1/A3)
                 spec[2] = tp
             elif leaf.ndim == 5 and leaf.shape[-1] % mesh.shape[tp] == 0:

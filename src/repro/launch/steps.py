@@ -411,11 +411,15 @@ def make_sched_steps(cfg: ModelConfig, mesh=None, *, max_seq: int,
     compilation (fixed slot count, ``active`` as a traced bool vector)
     serves every occupancy the scheduler passes through:
 
-      * inactive slots write at position ``max_seq`` — out of range, so the
-        masked cache write in ``models.common.update_cache`` is a no-op and
-        a finished slot's KV state stops changing the moment it completes
-        (recurrence families — rwkv/ssm state — ignore ``pos``; their slot
-        state is simply dead weight until admission overwrites it whole);
+      * inactive slots write at position ``max_seq`` — past the lane, so
+        the dense store's in-place row write (``models.common.write_rows``
+        and its kernel, the masked select of ``update_cache`` for the
+        families that scan the cache through ``xs``) and the paged
+        scatter (``page_write_tokens``) drop the row, never clamping it
+        onto the last position: a finished slot's KV state stops changing
+        the moment it completes (recurrence families — rwkv/ssm state —
+        ignore ``pos``; their slot state is simply dead weight until
+        admission overwrites it whole);
       * the greedy next token is selected on device and frozen for inactive
         slots (``where(active, argmax, tok)``), as is ``pos`` — a finished
         request's token stream and write cursor never move again.
@@ -438,9 +442,9 @@ def make_sched_steps(cfg: ModelConfig, mesh=None, *, max_seq: int,
         write_pos = jnp.where(active, pos, max_seq)
         # occupancy reaches the kernel: the slot-aware decode attention
         # skips dead slots instead of computing-then-masking their rows.
-        # (paged: write_pos == max_seq maps past the page table, where
-        # page_write_tokens' sentinel index drops the write — the paged
-        # analog of update_cache's out-of-range masked no-op)
+        # write_pos == max_seq is past every lane: the dense row write drops
+        # it, and (paged) it maps past the page table, where
+        # page_write_tokens' sentinel index drops the write
         logits, cache, *record = decode_step(params, cache, tok, write_pos,
                                              active=active, ptab=ptab)
         nxt = jnp.argmax(logits, -1).astype(jnp.int32)

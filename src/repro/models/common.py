@@ -171,6 +171,26 @@ def layer_loop(step, carry, xs, unroll: bool):
     return carry, stacked
 
 
+def cache_layer_loop(step, carry, cache, xs, unroll: bool):
+    """:func:`layer_loop` with the stacked cache in the carry, not in
+    ``xs``/``ys``: ``step(carry, cache, layer, x) -> (carry, cache, y)``
+    gets the whole stacked cache and the layer's index (int32), writes
+    its rows in place (:func:`write_rows`) and reads its layer by index.
+    Returns (carry, cache, ys).  The one-token decode over a dense store
+    takes this loop: a cache in ``xs``/``ys`` is sliced out, rewritten and
+    restacked every layer, copies of the whole cache a step."""
+    n = jax.tree_util.tree_leaves(xs)[0].shape[0]
+
+    def body(c, x):
+        h, kv = c
+        h, kv, y = step(h, kv, x[1], x[0])
+        return (h, kv), y
+
+    (carry, cache), ys = layer_loop(
+        body, (carry, cache), (xs, jnp.arange(n, dtype=jnp.int32)), unroll)
+    return carry, cache, ys
+
+
 # --------------------------------------------------------------------------
 # cache layout contract (CacheSpec) + slot plumbing
 # --------------------------------------------------------------------------
@@ -282,14 +302,16 @@ def read_slot(cache, slot):
 
 
 def update_cache(cache_k, cache_v, k, v, pos):
-    """Insert k,v (B, S_new, H, D) into caches (B, S_max, H, D) at ``pos``.
+    """Insert k,v (B, S_new, H, D) into one layer's caches (B, S_max, H, D)
+    at ``pos``.
 
     ``pos`` is (B,) per-request write offsets (ragged batches supported).
-    Decode (S_new == 1) uses a broadcast-compare masked write instead of a
-    scatter: a scatter onto a sequence-sharded cache forces GSPMD into an
-    "involuntary full rematerialization" (replicate + repartition of the
-    whole multi-TB cache), while the masked write partitions cleanly
-    (§Perf iteration A1).
+    A one-token write (S_new == 1) is a broadcast-compare masked select
+    over the whole lane: a position past the lane (``pos == S_max``, as
+    inactive slots get) writes nothing.  Families whose decode scans the
+    cache through ``xs`` (encdec, hybrid) use it; the dense store's
+    one-token decode of the transformer family writes in place instead
+    (:func:`cache_layer_loop`, :func:`write_rows`).
     """
     B, S_new = k.shape[0], k.shape[1]
     if S_new == 1:
@@ -303,6 +325,37 @@ def update_cache(cache_k, cache_v, k, v, pos):
     cache_k = cache_k.at[b, idx].set(k.astype(cache_k.dtype))
     cache_v = cache_v.at[b, idx].set(v.astype(cache_v.dtype))
     return cache_k, cache_v
+
+
+def write_rows(leaf, layer, rows, pos, backend=None):
+    """Write each slot's one new row into a stacked token leaf, in place.
+
+    leaf (L, B, S, *tail); layer int32 scalar; rows (B, *tail); pos (B,).
+    Slot ``b``'s row lands at ``(layer, b, pos[b])`` and nothing else of
+    the leaf changes; a row whose position lies past the lane (``pos ==
+    S``, as the scheduler gives inactive slots) is dropped, never clamped
+    onto the last row.  Under a donated cache carried through the layer
+    loop the buffer is updated in place.
+
+    ``backend`` "pallas" writes through one aliased kernel call
+    (``kernels/cache_write.py``): the chip runs XLA's scatter as a loop of
+    a few ops a slot.  Otherwise one scatter, batched over the slot axis
+    (each slot writes its own row, so GSPMD partitions it along a
+    batch-sharded cache)."""
+    from repro.models.layers import resolve_backend
+    if resolve_backend(backend) == "pallas":
+        from repro.kernels.ops import cache_write_op
+        return cache_write_op(leaf, rows, layer, pos)
+    B = rows.shape[0]
+    idx = jnp.stack([jnp.broadcast_to(layer, (B,)), pos],
+                    axis=-1).astype(jnp.int32)
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=tuple(range(1, rows.ndim)),
+        inserted_window_dims=(0, 2), scatter_dims_to_operand_dims=(0, 2),
+        operand_batching_dims=(1,), scatter_indices_batching_dims=(0,))
+    return jax.lax.scatter(leaf, idx, rows.astype(leaf.dtype), dnums,
+                           unique_indices=True,
+                           mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
 
 
 # --------------------------------------------------------------------------
@@ -338,7 +391,7 @@ def page_write_tokens(pool, vals, ptab, pos, page_size: int):
     positions.  Rows whose position lands beyond the table (the
     scheduler's ``pos = max_seq`` freeze for inactive slots) get the
     sentinel page index P, out of range, and ``mode="drop"`` discards
-    them — the paged analog of ``update_cache``'s masked no-op write."""
+    them, as :func:`write_rows` drops them from a dense lane."""
     P = pool.shape[0]
     W = ptab.shape[1]
     B, S = vals.shape[:2]

@@ -273,7 +273,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     prefix_len: Optional[int] = None,
                     backend: Optional[str] = None,
                     active: Optional[jax.Array] = None,
-                    pages: Optional[tuple] = None) -> jax.Array:
+                    pages: Optional[tuple] = None,
+                    layer: Optional[jax.Array] = None) -> jax.Array:
     """Chunked attention with GQA support.
 
     q: (B, Sq, Hq, D); k, v: (B, Sk, Hkv, D) with Hq % Hkv == 0.
@@ -291,9 +292,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     other path gathers the virtual slot-major cache — shaped exactly like
     the dense lane, W*page_size == Sk — and proceeds unchanged, which is
     what makes paged attention bit-identical to dense.
+
+    ``layer`` (int32 scalar) marks k/v as the stacked cache leaves (L, B,
+    Sk, Hkv, D): the pallas decode step reads layer ``layer`` of them in
+    place, every other path slices that layer out first.
     """
     B, Sq, Hq, D = q.shape
-    Hkv = k.shape[2]
+    Hkv = k.shape[-2]
     G = Hq // Hkv
     scale = scale if scale is not None else D ** -0.5
 
@@ -312,8 +317,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         k = gather_pages(k, ptab)
         v = gather_pages(v, ptab)
 
-    Sk = k.shape[1]
-
     if (Sq == 1 and causal and kv_len is not None and prefix_len is None
             and resolve_backend(backend) == "pallas"):
         from repro.kernels.ops import decode_attention_op
@@ -321,8 +324,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             jnp.asarray(q_offset, jnp.int32).reshape(-1), (B,))
         out = decode_attention_op(q.reshape(B, Hkv, G, D), k, v,
                                   kv_len=kv_len, q_pos=q_pos, active=active,
-                                  scale=scale, chunk=chunk)
+                                  layer=layer, scale=scale, chunk=chunk)
         return out.reshape(B, Sq, Hq, D).astype(q.dtype)
+    if layer is not None:
+        k, v = k[layer], v[layer]
+    Sk = k.shape[1]
 
     qf = q.reshape(B, Sq, Hkv, G, D).astype(jnp.float32) * scale
     qf = qf.transpose(0, 2, 3, 1, 4)                           # (B,Hkv,G,Sq,D)

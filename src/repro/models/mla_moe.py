@@ -45,8 +45,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.qtensor import QTensor
 from repro.models import layers as L
-from repro.models.common import Ctx, DEFAULT_CTX, layer_loop, maybe_remat, \
-    zeros_jit
+from repro.models.common import Ctx, DEFAULT_CTX, cache_layer_loop, \
+    layer_loop, maybe_remat, write_rows, zeros_jit
 
 # the stacked parameter groups, in forward order, and their cache leaves
 STACKS = (("dense_blocks", "latent_dense"), ("blocks", "latent"))
@@ -223,9 +223,10 @@ def _attend_decompressed(bp, q_nope, q_pe, latent, cfg: ModelConfig,
 
 
 def _attend_absorbed(bp, q_nope, q_pe, cache, pos, active, cfg: ModelConfig,
-                     ctx: Ctx):
-    """One query token per slot over the latent cache (B, S_max, D), in the
-    absorbed form.  Returns (B, 1, H * v)."""
+                     ctx: Ctx, layer=None):
+    """One query token per slot over the latent cache (B, S_max, D), or
+    layer ``layer`` of the stacked leaf (L, B, S_max, D), in the absorbed
+    form.  Returns (B, 1, H * v)."""
     m, H = cfg.mla, cfg.num_heads
     B = q_nope.shape[0]
     dt = q_nope.dtype
@@ -241,8 +242,11 @@ def _attend_absorbed(bp, q_nope, q_pe, cache, pos, active, cfg: ModelConfig,
         from repro.kernels.ops import decode_attention_op
         o_lat = decode_attention_op(
             q[:, None], cache, None, kv_len=kv_len, q_pos=pos,
-            active=active, scale=scale, dv=m.kv_lora_rank)[:, 0]
+            active=active, layer=layer, scale=scale,
+            dv=m.kv_lora_rank)[:, 0]
     else:
+        if layer is not None:
+            cache = cache[layer]
         s = jnp.einsum("bhd,bsd->bhs", q.astype(jnp.float32),
                        cache.astype(jnp.float32)) * scale
         k_pos = jnp.arange(cache.shape[1])
@@ -257,13 +261,11 @@ def _attend_absorbed(bp, q_nope, q_pe, cache, pos, active, cfg: ModelConfig,
 
 
 def _write_latent(cache, latent, pos):
-    """Insert latent rows (B, S_new, D) into ``cache`` (B, S_max, D) at
-    ``pos`` (B,); one-token writes are a masked select (a position past the
-    cache, as inactive slots get, writes nothing)."""
+    """Insert a prompt's latent rows (B, S_new, D) into one layer's
+    ``cache`` (B, S_max, D) at ``pos`` (B,).  A decode step's one row a
+    slot goes into the stacked leaf in place instead (:func:`block` with
+    ``layer``)."""
     B, S_new = latent.shape[:2]
-    if S_new == 1:
-        m = (jnp.arange(cache.shape[1])[None, :] == pos[:, None])[..., None]
-        return jnp.where(m, latent.astype(cache.dtype), cache)
     idx = pos[:, None] + jnp.arange(S_new)[None, :]
     return cache.at[jnp.arange(B)[:, None], idx].set(latent.astype(cache.dtype))
 
@@ -385,20 +387,28 @@ def moe_ffn(mp, h, cfg: ModelConfig, ctx: Ctx, valid=None):
 # --------------------------------------------------------------------------
 
 def block(bp: dict, x, cfg: ModelConfig, ctx: Ctx = DEFAULT_CTX, *,
-          positions, cache=None, pos=None, active=None):
+          positions, cache=None, pos=None, active=None, layer=None):
     """One layer over x (B, S, d).  Without ``cache``: causal attention over
-    x itself.  With ``cache`` (B, S_max, D) and ``pos`` (B,): S > 1 is a
-    prefill from position 0 that writes its latents at ``pos``; S == 1 is a
-    decode step in the absorbed form.  Returns (x, cache, record), the
-    expert layer's record (:func:`moe_ffn`), None for a dense layer."""
+    x itself.  With ``cache`` (B, S_max, D) and ``pos`` (B,): a prefill
+    from position 0 that writes its latents at ``pos`` (a one-token prompt
+    attends in the absorbed form, over the cache).  With ``layer``
+    (int32 scalar), ``cache`` is the stacked leaf (L, B, S_max, D) and x one
+    token a slot: a decode step that writes each slot's latent row in place
+    at ``(layer, b, pos[b])`` (``pos == S_max`` writes nothing) and attends
+    in the absorbed form.  Returns (x, cache, record), the expert layer's
+    record (:func:`moe_ffn`), None for a dense layer."""
     h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
     if ctx.act_bits:
         h = L.fake_quant_act(h, ctx.act_bits)
     q_nope, q_pe, latent = _project(bp, h, cfg, ctx, positions)
-    if cache is not None:
+    if layer is not None:
+        cache = write_rows(cache, layer, latent[:, 0], pos,
+                           ctx.kernel_backend)
+    elif cache is not None:
         cache = _write_latent(cache, latent, pos)
     if cache is not None and x.shape[1] == 1:
-        o = _attend_absorbed(bp, q_nope, q_pe, cache, pos, active, cfg, ctx)
+        o = _attend_absorbed(bp, q_nope, q_pe, cache, pos, active, cfg, ctx,
+                             layer)
     else:
         o = _attend_decompressed(bp, q_nope, q_pe, latent, cfg, ctx)
     if ctx.act_bits:
@@ -489,20 +499,20 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos,
     """One decode step.  Returns (logits, cache, record): ``record["step"]``
     holds :data:`STEP_COUNTERS`, (expert layers,) int32 each, and
     ``record["token"][EXPERTS]``, (expert layers, B, top_k) int32, the
-    experts each slot's token was routed to."""
+    experts each slot's token was routed to.  Each of :data:`STACKS`
+    carries its own stacked leaf through its layer loop, written in place
+    one row a slot (:func:`~repro.models.common.cache_layer_loop`)."""
     _no_pages(ctx, ptab)
     x = params["embed"][tokens][:, None, :]
 
-    def step(h, layer):
-        bp, c = layer
-        h, c, record = block(bp, h, cfg, ctx, positions=pos[:, None],
-                             cache=c, pos=pos, active=active)
-        return h, (c, record)
+    def step(h, c, i, bp):
+        return block(bp, h, cfg, ctx, positions=pos[:, None], cache=c,
+                     pos=pos, active=active, layer=i)
 
     new, record = {}, None
     for key, leaf in STACKS:
-        x, (new[leaf], record) = layer_loop(
-            step, x, (params[key], cache[leaf]), cfg.unroll_layers)
+        x, new[leaf], record = cache_layer_loop(
+            step, x, cache[leaf], params[key], cfg.unroll_layers)
     record = {"step": {k: record[k] for k in STEP_COUNTERS},
               "token": {EXPERTS: record[EXPERTS][:, :, 0]}}
     return _unembed(params, cfg, x, ctx)[:, 0], new, record

@@ -14,8 +14,9 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
-from repro.models.common import (Ctx, DEFAULT_CTX, gather_pages, layer_loop,
-                                 maybe_remat, page_update_cache, update_cache,
+from repro.models.common import (Ctx, DEFAULT_CTX, cache_layer_loop,
+                                 gather_pages, layer_loop, maybe_remat,
+                                 page_update_cache, update_cache, write_rows,
                                  zeros_jit)
 from repro.models.moe import init_moe_ffn, moe_ffn
 
@@ -72,8 +73,14 @@ def init_params(cfg: ModelConfig, key) -> dict:
 
 def attention(bp: dict, x: jax.Array, cfg: ModelConfig, ctx: Ctx, *,
               positions, kv_cache=None, cache_pos=None, kv_len=None,
-              prefix_len: Optional[int] = None, active=None, ptab=None):
+              prefix_len: Optional[int] = None, active=None, ptab=None,
+              layer=None):
     """Self-attention with optional KV cache.  Returns (out, new_kv or None).
+
+    ``layer`` (int32 scalar, one-token decode over a dense store only):
+    ``kv_cache`` holds the stacked leaves (L, B, S_max, H, D), this layer's
+    rows are written into them in place and attention reads them by index;
+    the stacked leaves come back as ``new_kv``.
 
     ``ptab`` (B, W) int32 + ``ctx.page_size > 0`` switches the cache to
     paged mode: the k/v leaves are page POOLS (num_pages, page_size, H, D)
@@ -111,15 +118,21 @@ def attention(bp: dict, x: jax.Array, cfg: ModelConfig, ctx: Ctx, *,
         if paged:
             ck, cv = page_update_cache(kv_cache["k"], kv_cache["v"], ks, vs,
                                        cache_pos, ptab, ctx.page_size)
+        elif layer is not None:
+            ck = write_rows(kv_cache["k"], layer, ks[:, 0], cache_pos, kb)
+            cv = write_rows(kv_cache["v"], layer, vs[:, 0], cache_pos, kb)
         else:
             ck, cv = update_cache(kv_cache["k"], kv_cache["v"], ks, vs,
                                   cache_pos)
         new_kv = {"k": ck, "v": cv}
         if ctx.kv_bits:
             # int8 pools dequantize AFTER gathering (the pallas paged walk
-            # is fp-only, so paged int8 KV takes the gather + dense path)
+            # is fp-only, so paged int8 KV takes the gather + dense path);
+            # an int8 stacked leaf dequantizes its layer's slice
             if paged:
                 ck, cv = gather_pages(ck, ptab), gather_pages(cv, ptab)
+            if layer is not None:
+                ck, cv, layer = ck[layer], cv[layer], None
             attn_k = ck.astype(x.dtype) * jnp.asarray(ctx.kv_scale, x.dtype)
             attn_v = cv.astype(x.dtype) * jnp.asarray(ctx.kv_scale, x.dtype)
         else:
@@ -136,7 +149,7 @@ def attention(bp: dict, x: jax.Array, cfg: ModelConfig, ctx: Ctx, *,
     o = L.flash_attention(q, attn_k, attn_v, causal=True, q_offset=q_offset,
                           kv_len=valid, chunk=ctx.attn_chunk,
                           prefix_len=prefix_len, backend=kb, active=active,
-                          pages=pages_arg)
+                          pages=pages_arg, layer=layer)
     o = o.reshape(Bb, S, cfg.num_heads * hd)
     if ctx.act_bits:
         o = L.fake_quant_act(o, ctx.act_bits)
@@ -160,11 +173,11 @@ def ffn(bp: dict, x: jax.Array, cfg: ModelConfig, ctx: Ctx) -> jax.Array:
 
 def block(bp: dict, x: jax.Array, cfg: ModelConfig, ctx: Ctx = DEFAULT_CTX, *,
           positions, kv_cache=None, cache_pos=None, kv_len=None,
-          prefix_len=None, active=None, ptab=None):
+          prefix_len=None, active=None, ptab=None, layer=None):
     a, new_kv = attention(bp, x, cfg, ctx, positions=positions,
                           kv_cache=kv_cache, cache_pos=cache_pos,
                           kv_len=kv_len, prefix_len=prefix_len, active=active,
-                          ptab=ptab)
+                          ptab=ptab, layer=layer)
     x = x + a
     x = x + ffn(bp, x, cfg, ctx)
     x = ctx.shard(x, ("batch", "res_seq", "embed"))
@@ -264,19 +277,33 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos,
     """One decode step. tokens: (B,), pos: (B,) current write position.
     ``active``: (B,) slot-occupancy vector from the scheduler — the
     slot-aware decode attention kernel skips dead slots entirely.
-    ``ptab``: (B, W) page table when the cache is a page pool."""
+    ``ptab``: (B, W) page table when the cache is a page pool.
+
+    Over a dense store the stacked cache rides in the layer loop's carry
+    (:func:`~repro.models.common.cache_layer_loop`): each layer writes one
+    row a slot in place and attention reads the layer by index.  The page
+    pools keep the scan over ``xs``; their writes are already a scatter."""
     x = embed_tokens(params, cfg, tokens)[:, None, :]
     x = ctx.shard(x, ("batch", "res_seq", "embed"))
+    kw = dict(positions=pos[:, None], cache_pos=pos, kv_len=pos + 1,
+              active=active, ptab=ptab)
 
-    def step(h, layer):
-        bp, kv = layer
-        h, new_kv = block(bp, h, cfg, ctx, positions=pos[:, None],
-                          kv_cache=kv, cache_pos=pos, kv_len=pos + 1,
-                          active=active, ptab=ptab)
-        return h, new_kv
+    if ptab is None:
+        def carried(h, kv, i, bp):
+            h, kv = block(bp, h, cfg, ctx, kv_cache=kv, layer=i, **kw)
+            return h, kv, ()
 
-    x, new_cache = layer_loop(step, x, (params["blocks"], cache),
-                              cfg.unroll_layers)
+        x, new_cache, _ = cache_layer_loop(carried, x, cache,
+                                           params["blocks"],
+                                           cfg.unroll_layers)
+    else:
+        def step(h, layer):
+            bp, kv = layer
+            h, new_kv = block(bp, h, cfg, ctx, kv_cache=kv, **kw)
+            return h, new_kv
+
+        x, new_cache = layer_loop(step, x, (params["blocks"], cache),
+                                  cfg.unroll_layers)
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = unembed(params, cfg, x, ctx)[:, 0]
     return ctx.shard(logits, ("batch", "vocab")), new_cache

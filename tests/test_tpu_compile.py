@@ -3,7 +3,8 @@ TPU v5e chip, at tinyllama-1.1b widths (K=2048, N=5632; 4 KV heads of 64,
 8 query heads per KV head), for the expert GEMM at qwen3-moe-30b-a3b's
 (K=2048, N=768 per expert), and for the grouped expert matmul and the
 latent decode attention at Moonlight-16B-A3B's (64 experts of 2048 x 1408;
-a 576-wide latent read by 16 heads over 64 slots of 1472).
+a 576-wide latent read by 16 heads over 64 slots of 1472), and for the
+decode step's in-place cache write at Mistral-7B's and Moonlight's caches.
 
 Interpret mode, which the rest of the suite runs the kernels in, accepts
 layouts the chip's compiler refuses (uint8 -> float casts, scale blocks
@@ -20,6 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.qtensor import PACK_FACTOR
+from repro.kernels.cache_write import cache_write
 from repro.kernels.decode_attention import (decode_attention,
                                             paged_decode_attention)
 from repro.kernels.quant_gemv import quant_gemv
@@ -144,10 +146,41 @@ def test_latent_decode_attention_compiles(one_chip):
         sds((slots,), jnp.bool_))
 
 
+@pytest.mark.parametrize("leaf,dtype", [
+    pytest.param((32, 32, 1472, 8, 128), jnp.bfloat16, id="mistral"),
+    pytest.param((32, 32, 1472, 8, 128), jnp.int8, id="mistral-int8"),
+    pytest.param((26, 64, 1472, 576), jnp.bfloat16, id="latent")])
+def test_cache_write_compiles(one_chip, leaf, dtype):
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    B = leaf[1]
+    _compile(lambda c, r, i, p: cache_write(c, r, i, p), sds(leaf, dtype),
+             sds((B,) + leaf[3:], dtype), sds((), jnp.int32),
+             sds((B,), jnp.int32))
+
+
 def _attn_operands(one_chip):
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     return (sds((B, HKV, G, D), jnp.bfloat16), sds((B,), jnp.int32),
             sds((B,), jnp.int32), sds((B,), jnp.bool_), sds)
+
+
+def test_stacked_decode_attention_compiles(one_chip):
+    """The decode step's call: the stacked leaf and the layer's index, for
+    GQA at this file's widths (3 layers) and at Moonlight's latent leaf
+    (26 layers x 64 slots x 1472 x 576)."""
+    q, kv_len, q_pos, active, sds = _attn_operands(one_chip)
+    layer = sds((), jnp.int32)
+    kv = sds((3, B, 1024, HKV, D), jnp.bfloat16)
+    _compile(lambda q, k, v, n, p, a, i: decode_attention(
+        q, k, v, kv_len=n, q_pos=p, active=a, layer=i), q, kv, kv, kv_len,
+        q_pos, active, layer)
+    slots, heads, width = 64, 16, 576
+    vec = sds((slots,), jnp.int32)
+    _compile(lambda q, k, n, p, a, i: decode_attention(
+        q, k, None, kv_len=n, q_pos=p, active=a, layer=i, chunk=1 << 30,
+        dv=512), sds((slots, 1, heads, width), jnp.bfloat16),
+        sds((26, slots, 1472, width), jnp.bfloat16), vec, vec,
+        sds((slots,), jnp.bool_), layer)
 
 
 def test_decode_attention_compiles(one_chip):
@@ -220,3 +253,24 @@ def test_decode_attention_keeps_its_name_inside_a_step(one_chip,
                                active).compile().as_text()
     assert _custom_call_names(text) == {"decode_attention_op",
                                         "paged_decode_attention_op"}
+
+
+def test_cache_write_is_one_named_kernel_that_aliases_the_leaf(one_chip,
+                                                              monkeypatch):
+    """Inside a step the write is one kernel named ``cache_write_op``, and
+    a donated leaf is written in place: the program's temporaries hold no
+    second copy of it."""
+    from repro.kernels import ops
+    from repro.launch.steps import cache_donate_argnums
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    leaf = sds((32, 32, 1472, 8, 128), jnp.bfloat16)
+
+    def step(c, r, i, p):
+        return ops.cache_write_op(c, r * 2, i, p), r.sum()
+
+    compiled = jax.jit(step, donate_argnums=cache_donate_argnums(0)).lower(
+        leaf, sds((32, 8, 128), jnp.bfloat16), sds((), jnp.int32),
+        sds((32,), jnp.int32)).compile()
+    assert _custom_call_names(compiled.as_text()) == {"cache_write_op"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
