@@ -3,8 +3,7 @@ PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 export PYTHONPATH
 
 .PHONY: test test-slow test-multidevice lint lint-contracts sanitize-smoke \
-	bench-smoke bench bench-serve bench-serve-smoke bench-paged-smoke \
-	bench-serve-tp-smoke eval eval-smoke
+	bench-smoke bench eval eval-smoke
 
 # tier-1: fast suite, slow-marked tests deselected (pyproject addopts)
 test:
@@ -46,32 +45,6 @@ sanitize-smoke:
 # BENCH_recon.json (the CI perf trajectory artifact)
 bench-smoke:
 	$(PY) -m benchmarks.recon_speed --dryrun
-
-# serving-path speed bench (Table 8 axis): FP baseline + packed W2/W3/W4
-# under both kernel backends, with a cross-backend logits parity gate,
-# plus the heterogeneous-workload continuous-batching section (scheduler
-# goodput >= lock-step and bit-identity-to-standalone gates per backend);
-# emits BENCH_serve.json (the CI serving-perf trajectory artifact)
-bench-serve:
-	$(PY) -m benchmarks.serve_speed
-
-bench-serve-smoke:
-	$(PY) -m benchmarks.serve_speed --smoke
-
-# quick local loop for the paged-vs-dense KV cache sweep only (the
-# paged_vs_dense_goodput / paged_cache_bytes / identity gates); the
-# full CI serve-smoke leg runs the same section inside bench-serve-smoke
-bench-paged-smoke:
-	$(PY) -m benchmarks.serve_speed --smoke --paged-only --json BENCH_paged.json
-
-# tensor-parallel serving section only, on a fake 8-device host platform
-# (2x4 mesh): tp_serve_parity (tokens bit-identical to the no-mesh path,
-# logits within the psum tolerance) and tp_serve_decode_vs_single goodput
-# gates; emits BENCH_serve_tp.json (audited by the CI multidevice leg)
-bench-serve-tp-smoke:
-	XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-		$(PY) -m benchmarks.serve_speed --smoke --tp 4 --tp-only \
-		--json BENCH_serve_tp.json
 
 # one-command quality harness: FP vs RTN/AWQ/TesseraQ perplexity + choice
 # accuracy + packed-model eval + xla/pallas logits-parity gate; emits

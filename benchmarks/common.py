@@ -140,9 +140,9 @@ def sanitizer_gate(out: dict) -> bool:
 
 def gate(out: dict, name: str, *, threshold, measured, ok, cmp) -> bool:
     """One machine-readable gate record appended to ``out["gates"]`` — THE
-    shared schema ({name, threshold, measured, ok, cmp}) every bench
-    artifact (BENCH_recon.json, BENCH_serve.json) uses; a bench run must
-    fail if any gate is not ok."""
+    shared schema ({name, threshold, measured, ok, cmp}) of the bench
+    artifacts (BENCH_recon.json); a bench run must fail if any gate is not
+    ok."""
     out["gates"].append({"name": name, "threshold": float(threshold),
                          "measured": float(measured), "ok": bool(ok),
                          "cmp": cmp})

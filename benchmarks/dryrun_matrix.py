@@ -56,8 +56,9 @@ def run_one(arch, shape, mesh, quant="", **kw):
 
 
 def run_tp_serve_cell(tp: int = 4, n_devices: int = 8):
-    """TP serving sanitizer cell: the shard-mapped decode loop end to end
-    under ``sanitized(transfer_guard=True)`` — same exemption rules as the
+    """TP serving sanitizer cell: uniform requests through the shard-mapped
+    ``serve_scheduled`` loop end to end under
+    ``sanitized(transfer_guard=True)`` — same exemption rules as the
     recon mesh path (explicit ``device_put`` placements are allowed, any
     implicit dispatch-time reshard of params/cache trips the guard).
 
@@ -70,7 +71,8 @@ def run_tp_serve_cell(tp: int = 4, n_devices: int = 8):
     from repro.configs import get_reduced_config
     from repro.core import pack_model, quantize_model
     from repro.launch.mesh import serve_mesh
-    from repro.launch.serve import parse_quant, serve_requests
+    from repro.launch.scheduler import Request, serve_scheduled
+    from repro.launch.serve import parse_quant
     from repro.models import get_model
 
     path = os.path.join(ART, f"tp_serve_sanitize__tp{tp}.json")
@@ -85,13 +87,14 @@ def run_tp_serve_cell(tp: int = 4, n_devices: int = 8):
     mesh = serve_mesh(tp=tp, n_devices=n_devices)
     prompts = np.random.RandomState(0).randint(
         1, cfg.vocab_size, size=(2, 8)).astype(np.int32)
+    reqs = [Request(rid=b, prompt=p, max_new_tokens=4)
+            for b, p in enumerate(prompts)]
+    kw = dict(slots=len(reqs), mesh=mesh, tp_shard=True)
     t0 = time.time()
     # warm compile outside the guard (compilation device_puts constants);
     # the guarded run below must then dispatch with zero implicit transfers
-    serve_requests(cfg, model, packed, prompts, gen=4,
-                   mesh=mesh, tp_shard=True)
-    run_sanitized(lambda: serve_requests(cfg, model, packed, prompts,
-                                         gen=4, mesh=mesh, tp_shard=True))
+    serve_scheduled(cfg, packed, reqs, **kw)
+    run_sanitized(lambda: serve_scheduled(cfg, packed, reqs, **kw))
     res = {"cell": "tp_serve_sanitize", "tp": tp, "mesh": str(mesh),
            "quant": "W4A16g16",
            "status": "ok" if SANITIZER["clean"] else "error",

@@ -351,16 +351,15 @@ def one_chip(cfg, seed: int, phases: Phases) -> None:
     # serving speed with the per-step logits fetch off (compiled steps reused)
     for backend in ("xla", "pallas"):
         steps = runs[(backend, "dense")][1]
-        res = phases.run(f"serve {backend}/dense timed", serve_scheduled, cfg,
-                         packed, reqs, slots=SLOTS, max_seq=max_seq,
+        name = f"serve {backend}/dense timed"
+        res = phases.run(name, serve_scheduled, cfg, packed, reqs,
+                         slots=SLOTS, max_seq=max_seq,
                          kernel_backend=backend, compiled=steps)
-        # host seconds: an admission's first-token sync also waits for the
-        # decode steps dispatched ahead of it, so "prefill" counts those too
-        print(f"[serve] {backend}/dense: decode {res.decode_tok_s:.1f} tok/s "
-              f"over host seconds outside admissions, prompt "
-              f"{res.prefill_tok_s:.1f} tok/s over host seconds inside "
-              f"admissions, {res.steps} decode steps over {SLOTS} slots, "
-              f"occupancy {res.occupancy:.2f}", flush=True)
+        wall = phases.rows[name]["wall_s"]
+        print(f"[serve] {backend}/dense: {res.useful_tokens / wall:.1f} "
+              f"tok/s ({res.useful_tokens} tokens over {wall:.2f} s wall), "
+              f"{res.steps} decode steps over {SLOTS} slots, occupancy "
+              f"{res.occupancy:.2f}", flush=True)
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,7 @@ Runs, for FP and each PTQ method (RTN / AWQ / TesseraQ) at one quant config:
     agree to bf16 tolerance, otherwise the harness exits non-zero.
 
 Results land in a machine-readable JSON (``--json``, default ``EVAL.json``)
-so CI can archive a quality trajectory next to BENCH_serve.json.
+so CI can archive a quality trajectory.
 """
 from __future__ import annotations
 
@@ -32,7 +32,8 @@ from repro.core.tesseraq import TesseraQConfig
 from repro.data.pipeline import (DataConfig, SyntheticCorpus,
                                  calibration_batches, eval_batches)
 from repro.eval.ppl import choice_accuracy, make_choice_tasks, perplexity
-from repro.launch.serve import parse_quant, serve_requests
+from repro.launch.scheduler import Request, serve_scheduled
+from repro.launch.serve import parse_quant
 from repro.models import get_model
 
 # method rows: (label, quantize_model method, init)
@@ -44,9 +45,9 @@ METHODS = (("rtn", "none", "rtn"),
 def parity_gate(a: np.ndarray, b: np.ndarray, *, atol: float,
                 rtol: float) -> dict:
     """THE cross-backend logits comparison — symmetric rtol reference
-    (max of both magnitudes).  Every parity gate (this harness, tests,
-    benchmarks/serve_speed.py) must call this one helper so the gates
-    cannot drift apart semantically or in tolerance."""
+    (max of both magnitudes).  Every parity gate (this harness, the tests,
+    ``chip_smoke.py``) must call this one helper so the gates cannot drift
+    apart semantically or in tolerance."""
     diff = np.abs(a - b)
     scale = np.maximum(np.abs(a), np.abs(b))
     ok = bool(np.all(diff <= atol + rtol * scale))
@@ -54,11 +55,16 @@ def parity_gate(a: np.ndarray, b: np.ndarray, *, atol: float,
             "steps_compared": int(a.shape[1]), "atol": atol, "rtol": rtol}
 
 
-def logits_parity(cfg, model, packed, prompts, *, gen: int, atol: float,
+def logits_parity(cfg, packed, prompts, *, gen: int, atol: float,
                   rtol: float) -> dict:
-    """Prefill + (gen-1) decode steps under both backends; allclose gate."""
-    runs = {b: serve_requests(cfg, model, packed, prompts, gen=gen,
-                              kernel_backend=b) for b in ("xla", "pallas")}
+    """Each row of ``prompts`` served as a request of ``gen`` tokens, all
+    at once over one slot each: prefill + (gen-1) decode steps under both
+    backends; allclose gate over every step's logits."""
+    reqs = [Request(rid=b, prompt=p, max_new_tokens=gen)
+            for b, p in enumerate(prompts)]
+    runs = {b: serve_scheduled(cfg, packed, reqs, slots=len(reqs),
+                               kernel_backend=b, collect_logits=True)
+            for b in ("xla", "pallas")}
     return parity_gate(runs["xla"].logits_matrix(),
                        runs["pallas"].logits_matrix(),
                        atol=atol, rtol=rtol)
@@ -109,7 +115,7 @@ def run_harness(args) -> dict:
               f"acc={row['choice_acc']:.3f} "
               f"packed_xla_ppl={row['ppl_packed_xla']:.3f}")
         if label == args.parity_method:
-            gate = logits_parity(cfg, model, packed, prompts,
+            gate = logits_parity(cfg, packed, prompts,
                                  gen=args.parity_steps + 1,
                                  atol=args.parity_atol, rtol=args.parity_rtol)
             out["parity"][label] = gate

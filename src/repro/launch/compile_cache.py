@@ -1,9 +1,10 @@
 """JAX's persistent compilation cache, placed from outside the program.
 
 Entry points that compile the model (``chip_smoke.py``, ``python -m
-repro.launch.serve`` and the two benches) call :func:`enable_compile_cache`
-once before their first compile.  Tests never call it: an AOT compile for a
-described chip writes entries that cannot be read back without that chip.
+repro.launch.serve``, ``benchmarks/recon_speed.py`` and ``bench/run.py``)
+call :func:`enable_compile_cache` once before their first compile.  Tests
+never call it: an AOT compile for a described chip writes entries that
+cannot be read back without that chip.
 """
 from __future__ import annotations
 

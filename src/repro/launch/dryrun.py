@@ -34,9 +34,10 @@ import time
 import jax
 
 from repro.configs import SHAPES_BY_NAME, get_config
-from repro.configs.base import ModelConfig, QuantConfig, ShapeConfig
+from repro.configs.base import ModelConfig, ShapeConfig
 from repro.launch import hlo_stats
 from repro.launch.mesh import make_mesh, make_production_mesh
+from repro.launch.serve import parse_quant
 from repro.launch.sharding import (batch_shardings, cache_shardings,
                                    param_shardings)
 from repro.launch.steps import (jit_train_step, make_serve_steps,
@@ -44,19 +45,6 @@ from repro.launch.steps import (jit_train_step, make_serve_steps,
                                 quantize_param_struct, serve_input_specs,
                                 train_input_specs)
 from repro.models import get_model
-
-
-def parse_quant(tag):
-    """'W2A16g128' -> QuantConfig."""
-    if not tag or tag == "none":
-        return None
-    import re
-    m = re.match(r"W(\d+)A(\d+)(?:g(\d+))?$", tag)
-    if not m:
-        raise ValueError(f"bad quant tag {tag}")
-    bits, act, g = int(m.group(1)), int(m.group(2)), m.group(3)
-    return QuantConfig(bits=bits, group_size=int(g) if g else None,
-                       act_bits=None if act >= 16 else act)
 
 
 def mem_dict(compiled):
@@ -155,7 +143,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, quant: str = "",
     else:
         mesh = make_mesh(tuple(int(x) for x in mesh_kind.split(",")))
     chips = mesh.size
-    qcfg = parse_quant(quant)
+    qcfg = parse_quant(quant) if quant and quant != "none" else None
     opts = dict(attn_chunk=attn_chunk, microbatches=microbatches,
                 seq_parallel=seq_parallel, grad_compression=grad_compression,
                 serve_sharding=serve_sharding,

@@ -1,8 +1,6 @@
-"""Slot-based continuous-batching request scheduler (the real serve loop).
+"""Slot-based continuous-batching request scheduler: the one serve loop.
 
-``launch/serve.py``'s old loop decoded every request in lock-step for a fixed
-``gen``: no completion, no admission, every heterogeneous batch paid for its
-longest member.  This module is the scheduler that docstring promised:
+Every request is served here, by :func:`serve_scheduled`:
 
   * a FIFO **request queue** with per-request arrival times (decode-step
     units, from a seeded plan — see :func:`make_workload`);
@@ -34,8 +32,9 @@ runs sync per step and are NOT timing-comparable (parity and debug callers
 don't time themselves anyway).
 
 Per-request outputs are bit-identical to serving the same request alone
-through ``serve_requests`` at the same cache width: active rows see exactly
-the arguments the plain loop passes, and every op in the decode path is
+through a plain greedy prefill-then-decode loop over ``make_serve_steps``'
+step pair at the same cache width (the tests' oracle): active rows see
+exactly the arguments that loop passes, and every op in the decode path is
 batch-row independent.  (Exception: the ``moe`` family's capacity dispatch
 couples rows by construction — tokens compete for per-expert capacity
 slots — so it gets determinism, not alone-parity; the ``mla_moe`` family
@@ -65,7 +64,6 @@ was in.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
@@ -75,6 +73,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.launch.mesh import validate_single_pod
+from repro.launch.sharding import ServeSpec
 from repro.launch.steps import (cache_donate_argnums, make_paged_install_step,
                                 make_sched_steps)
 from repro.models.common import (DenseCacheStore, PagedCacheStore, write_slot)
@@ -107,35 +106,11 @@ class Request:
     extras: Optional[Dict[str, np.ndarray]] = None
 
 
-def _push(host_arr: np.ndarray):
-    """Host->device transfer of a buffer the scheduler will keep MUTATING.
-
-    jax's CPU client zero-copies 64-byte-aligned numpy buffers into device
-    arrays (alignment is allocator luck for small arrays), so handing it
-    ``active_h`` directly would let later in-place mutations retroactively
-    corrupt the mask a dispatched step still references — a sporadic,
-    alignment-dependent heisenbug.  Always transfer a private copy that
-    nothing ever writes again — via ``device_put``, the explicit-transfer
-    form the sanitizer's ``transfer_guard("disallow")`` permits."""
-    return jax.device_put(host_arr.copy())
-
-
-def _i32(v) -> jax.Array:
-    """Explicitly placed int32 scalar: python ints handed to a jitted step
-    as traced args are device_put implicitly per call, which the sanitizer's
-    transfer_guard rejects; this is the explicit-transfer spelling."""
-    return jax.device_put(np.int32(v))
-
-
 # jitted single-slot scatter for the admission bookkeeping: eager
 # ``a.at[s].set(v)`` device_puts its scalar index/value per call, which the
 # sanitizer's transfer_guard rejects; the operands enter via explicit
 # device_put instead
 _set_slot_jit = jax.jit(lambda a, s, v: a.at[s].set(v))
-
-
-def _set_slot(a, s: int, v: int):
-    return _set_slot_jit(a, _i32(s), _i32(v))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -152,31 +127,20 @@ class SchedSteps:
 
 @dataclasses.dataclass(frozen=True)
 class ServeResult:
-    """The one result surface every serve entry point returns
-    (``serve_requests``, ``serve_scheduled``, ``serve_lockstep``).
+    """What one :func:`serve_scheduled` run served.
 
     ``requests`` maps rid -> per-request record (``tokens`` (gen,) int32,
-    ``logits`` (gen, V) or None, admission/finish bookkeeping where the
-    mode tracks it).  ``latency_steps`` holds mean/p50/p90/p99 percentiles
-    in decode-step units.  ``cache_stats`` is the cache store's accounting
-    (``CacheStore.stats()``: bytes always; page-pool counters when paged).
-    Mode-specific extras (e.g. lock-step's wasted-token accounting) ride in
-    ``extra``.  Mapping-style ``result["key"]`` access resolves attributes
-    (falling back to ``extra``) so result handling can migrate gradually.
-
-    The timings are host seconds, not a split of the device's time.  For
-    ``serve_scheduled``, ``prefill_secs`` is the host time spent inside
-    admissions, from the prefill's dispatch through the first token's sync
-    and the slot install; that sync also waits for every decode step
-    dispatched ahead of it, so it counts decode work too.  ``decode_secs``
-    is the rest of the loop, ``prefill_tok_s`` and ``decode_tok_s`` are
-    prompt and decode tokens over those seconds.  The device's own split is
-    in a profiler trace: the ``SPAN_*`` host spans beside the prefill and
-    decode programs' executions.  ``serve_requests`` blocks on its prefill
-    before decoding, so its two timings do split the work (``serve_lockstep``
-    sums them over its batches).
+    ``logits`` (gen, V) or None, ``arrival``/``admit_step``/
+    ``finish_step``/``latency_steps`` in decode steps, plus the family's
+    per-token record entries).  ``latency_steps`` holds mean/p50/p90/p99
+    percentiles in decode-step units.  ``cache_stats`` is the cache store's
+    accounting (``CacheStore.stats()``: bytes always; page-pool counters
+    when paged).  ``prefill_chunk`` and ``share_prefix`` are the chunk
+    size and prefix sharing the run actually used (0 / False where the
+    family or store could not).  Times are not recorded here: the device's
+    split is in a profiler trace, the ``SPAN_*`` host spans beside the
+    prefill and decode programs' executions.
     """
-    mode: str                               # "uniform"|"scheduled"|"lockstep"
     store: str                              # "dense" | "paged"
     requests: Dict[int, Dict[str, Any]]
     slots: int
@@ -184,14 +148,11 @@ class ServeResult:
     steps: int
     useful_tokens: int
     decode_tokens: int
-    prefill_secs: float
-    decode_secs: float
-    prefill_tok_s: float
-    decode_tok_s: float
     occupancy: float
     latency_steps: Dict[str, float]
     cache_stats: Dict[str, Any]
-    extra: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    prefill_chunk: int
+    share_prefix: bool
     # per decode step, the "step" entries of the family's step record (for
     # mla_moe "experts_touched" / "largest_group", (steps, expert layers)
     # int32), fetched after the loop with the tokens; the "token" entries
@@ -199,14 +160,6 @@ class ServeResult:
     # decode (for mla_moe "experts", (expert layers, positions, top_k))
     step_counters: Dict[str, np.ndarray] = dataclasses.field(
         default_factory=dict)
-
-    def __getitem__(self, key: str):
-        if key in self.extra:
-            return self.extra[key]
-        try:
-            return getattr(self, key)
-        except AttributeError:
-            raise KeyError(key) from None
 
     # reprolint: ok[host-sync] — cold accessor over already-fetched host arrays; runs after the timed loop
     def token_matrix(self) -> np.ndarray:
@@ -225,14 +178,6 @@ class ServeResult:
         return np.stack([np.asarray(self.requests[r]["logits"], np.float32)
                          for r in rids], 0)
 
-    @property
-    def tokens(self) -> np.ndarray:
-        return self.token_matrix()
-
-    @property
-    def logits(self) -> Optional[np.ndarray]:
-        return self.logits_matrix()
-
 
 # reprolint: ok[host-sync] — pure host statistics over python floats; no device values involved
 def _latency_stats(latencies) -> Dict[str, float]:
@@ -249,8 +194,7 @@ def make_workload(vocab_size: int, *, n_requests: int, seed: int,
     """Seeded heterogeneous request plan: mixed prompt lengths, mixed token
     budgets, Poisson inter-arrival gaps in decode-step units.  A pure
     function of its arguments, so the same seed yields the same plan on
-    every run — the admission-determinism tests and the bench gate both
-    lean on that.
+    every run — the admission-determinism tests lean on that.
 
     ``long_frac > 0`` makes the plan LONG-TAILED: that fraction of requests
     draws from ``long_prompt_lens``/``long_budgets`` instead — the
@@ -342,10 +286,7 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
 
     Returns a :class:`ServeResult`; per-request records are keyed by rid
     (``tokens`` is exactly ``max_new_tokens`` long: the prefill token plus
-    its decode steps).  ``decode_tok_s`` counts USEFUL tokens only — every
-    request's own budget, which is also the number actually generated; the
-    lock-step baseline reports the same numerator so the two compose into
-    an apples-to-apples goodput gate.
+    its decode steps).
 
     ``store="paged"``: token-leaf KV lives in a pool of ``num_pages``
     pages of ``page_size`` tokens (default pool: capacity parity with the
@@ -389,34 +330,20 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
     # ServeSpec placement ONCE.  Anything left committed to device 0 would
     # be resharded onto the mesh at every jitted step dispatch: an implicit
     # device-to-device transfer per step, slow and rejected by the serving
-    # sanitizer's transfer_guard.
-    tp_rep = None
-    if tp_shard and mesh is not None:
-        from repro.launch.sharding import ServeSpec
-        tp_spec = ServeSpec.for_mesh(mesh, cfg)
-        if tp_spec.active:
-            tp_plan = tp_spec.plan(params)
-            params = tp_spec.place_params(params, tp_plan)
-            tp_rep = tp_spec.replicated()
-
-    def push(a):
-        return (jax.device_put(a.copy(), tp_rep) if tp_rep is not None
-                else _push(a))
-
-    def put(a):
-        return (jax.device_put(a, tp_rep) if tp_rep is not None
-                else jax.device_put(a))
-
-    def i32(v):
-        return (jax.device_put(np.int32(v), tp_rep) if tp_rep is not None
-                else _i32(v))
-
-    def set_slot(a, s, v):
-        return _set_slot_jit(a, i32(s), i32(v))
-
-    def place_cache(c):
-        return (tp_spec.place_cache(spec, c, tp_plan)
-                if tp_rep is not None else c)
+    # sanitizer's transfer_guard.  Without TP, ``sharding`` stays None:
+    # ``jax.device_put(x, None)`` is the default placement.  Every push is
+    # an explicit ``device_put`` (the form transfer_guard permits; python
+    # scalars handed to a jitted step would be put implicitly).  Host
+    # buffers the loop keeps MUTATING (``active_h``, the page table) are
+    # pushed as private copies: jax's CPU client zero-copies 64-byte-aligned
+    # numpy buffers, so a later in-place write would corrupt the value a
+    # dispatched step still reads.
+    tp_spec = ServeSpec.for_mesh(mesh if tp_shard else None, cfg)
+    tp_plan, sharding = {}, None
+    if tp_spec.active:
+        tp_plan = tp_spec.plan(params)
+        params = tp_spec.place_params(params, tp_plan)
+        sharding = tp_spec.replicated()
 
     if paged:
         if num_pages is None:
@@ -433,17 +360,18 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
                     f"num_pages or lower the request's length")
     else:
         cstore = DenseCacheStore(model, slots=slots, max_seq=max_seq)
-    cache = place_cache(cstore.cache)
-    ptab_d = push(cstore.ptab_h) if paged else None
+    cache = tp_spec.place_cache(spec, cstore.cache, tp_plan)
+    ptab_d = (jax.device_put(cstore.ptab_h.copy(), sharding) if paged
+              else None)
     # chunked prefill applies to chunkable families only; prefix sharing
     # additionally needs the paged store (pages are the sharing unit)
     chunk_ok = prefill_chunk > 0 and spec.chunkable
     share_ok = share_prefix and paged and chunk_ok and spec.shareable
 
-    tok = push(np.zeros((slots,), np.int32))
-    pos = push(np.zeros((slots,), np.int32))
+    tok, pos = jax.device_put((np.zeros((slots,), np.int32),
+                               np.zeros((slots,), np.int32)), sharding)
     active_h = np.zeros((slots,), bool)        # host mirror of occupancy
-    active_d = push(active_h)
+    active_d = jax.device_put(active_h.copy(), sharding)
     slot_rid = np.full((slots,), -1, np.int64)
     remaining = np.zeros((slots,), np.int64)   # decode steps left per slot
     res = {r.rid: {"arrival": r.arrival, "admit_step": None,
@@ -456,15 +384,15 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
     t = 0                 # scheduler clock, in decode steps dispatched
     steps = 0
     occupancy_acc = 0
-    prefill_secs = 0.0
-    prompt_tokens = sum(_prefill_len(cfg, r) for r in order)
-    t_start = time.time()
 
     def finish_prefill(s, req, tok0, lg1):
         """Common post-prefill bookkeeping (whole or final chunk)."""
         nonlocal tok, pos
-        tok = set_slot(tok, s, tok0)
-        pos = set_slot(pos, s, _prefill_len(cfg, req))
+        s_d, tok0_d, plen_d = jax.device_put(
+            (np.int32(s), np.int32(tok0), np.int32(_prefill_len(cfg, req))),
+            sharding)
+        tok = _set_slot_jit(tok, s_d, tok0_d)
+        pos = _set_slot_jit(pos, s_d, plen_d)
         r = res[req.rid]
         r["admit_step"] = t
         r["tokens"].append(tok0)
@@ -509,19 +437,20 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
                 # loop iteration, interleaved with decode below
                 inflight = {"req": req, "slot": s,
                             "cursor": plan.shared_tokens,
-                            "c1": (None if paged
-                                   else place_cache(model.init_cache(1, max_seq)))}
+                            "c1": (None if paged else tp_spec.place_cache(
+                                spec, model.init_cache(1, max_seq), tp_plan))}
                 continue
             # ---- whole prefill at full cache width ------------------------
-            tp0 = time.time()
             with jax.profiler.TraceAnnotation(
                     SPAN_ADMIT, rid=req.rid, slot=s,
                     plen=_prefill_len(cfg, req)):
                 with jax.profiler.TraceAnnotation(SPAN_PREFILL):
-                    batch = {"tokens": put(req.prompt[None])}
-                    for k, v in (req.extras or {}).items():
-                        batch[k] = put(v[None])
-                    c1 = place_cache(model.init_cache(1, max_seq))
+                    batch = jax.device_put(
+                        {"tokens": req.prompt[None],
+                         **{k: v[None] for k, v in (req.extras or {}).items()}},
+                        sharding)
+                    c1 = tp_spec.place_cache(
+                        spec, model.init_cache(1, max_seq), tp_plan)
                     lg1, c1, *rec = steps_.prefill(params, batch, c1)
                     if rec:
                         prefill_rec[req.rid] = rec[0]
@@ -529,51 +458,54 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
                     # reprolint: ok[host-sync] — the only per-admission sync (counted); explicit device_get so transfer_guard allows it
                     tok0 = int(np.asarray(jax.device_get(jnp.argmax(lg1, -1)))[0])
                 with jax.profiler.TraceAnnotation(SPAN_INSTALL):
+                    s_d = jax.device_put(np.int32(s), sharding)
                     if paged:
-                        cache = steps_.install(cache, c1, i32(s),
-                                               push(cstore.ptab_h[s]),
+                        row = jax.device_put(cstore.ptab_h[s].copy(),
+                                             sharding)
+                        cache = steps_.install(cache, c1, s_d, row,
                                                plen=_prefill_len(cfg, req))
                     else:
-                        cache = steps_.write_slot(cache, c1, i32(s))
+                        cache = steps_.write_slot(cache, c1, s_d)
                     # the argmax sync above already drained the dispatch
                     # queue, so blocking here charges ONLY the slot install
-                    # to the admission window instead of letting it leak
-                    # into decode_secs
-                    jax.block_until_ready(cache)   # reprolint: ok[host-sync] — admission-window timing boundary
+                    # to the install span instead of letting it leak into
+                    # the next decode step's
+                    jax.block_until_ready(cache)   # reprolint: ok[host-sync] — closes the install span
                 dirty |= finish_prefill(s, req, tok0, lg1)
                 ptab_dirty |= paged  # budget-1 admissions release pages
-            prefill_secs += time.time() - tp0
         # ---- one prefill chunk for the in-flight request ------------------
         if inflight is not None:
-            tp0 = time.time()
             req, s = inflight["req"], inflight["slot"]
             cur = inflight["cursor"]
             plen = len(req.prompt)   # chunkable families are text-only
             end = min(cur + prefill_chunk, plen)
             with jax.profiler.TraceAnnotation(SPAN_CHUNK, rid=req.rid, slot=s,
                                               start=cur, end=end):
-                chunk = {"tokens": put(req.prompt[None, cur:end])}
+                chunk, cur_d = jax.device_put(
+                    ({"tokens": req.prompt[None, cur:end]}, np.int32(cur)),
+                    sharding)
                 if paged:
                     lg1, cache, *_ = steps_.prefill(
-                        params, chunk, cache, i32(cur),
-                        push(cstore.ptab_h[s:s + 1]))
+                        params, chunk, cache, cur_d,
+                        jax.device_put(cstore.ptab_h[s:s + 1].copy(),
+                                       sharding))
                 else:
                     lg1, inflight["c1"], *_ = steps_.prefill(
-                        params, chunk, inflight["c1"], i32(cur))
+                        params, chunk, inflight["c1"], cur_d)
                 inflight["cursor"] = end
                 if end == plen:
                     # reprolint: ok[host-sync] — per-admission sync, chunked path (same contract as above)
                     tok0 = int(np.asarray(jax.device_get(jnp.argmax(lg1, -1)))[0])
                     if not paged:
-                        cache = steps_.write_slot(cache, inflight["c1"],
-                                                  i32(s))
-                    jax.block_until_ready(cache)   # reprolint: ok[host-sync] — admission-window timing boundary
+                        cache = steps_.write_slot(
+                            cache, inflight["c1"],
+                            jax.device_put(np.int32(s), sharding))
+                    jax.block_until_ready(cache)   # reprolint: ok[host-sync] — closes the last chunk's span
                     dirty |= finish_prefill(s, req, tok0, lg1)
                     ptab_dirty |= paged
                     inflight = None
                 else:
-                    jax.block_until_ready(lg1)   # reprolint: ok[host-sync] — honest prefill attribution
-            prefill_secs += time.time() - tp0
+                    jax.block_until_ready(lg1)   # reprolint: ok[host-sync] — the chunk's span holds its own prefill
         if not active_h.any():
             if not pending and inflight is None:
                 break
@@ -594,9 +526,9 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
         with jax.profiler.StepTraceAnnotation(SPAN_DECODE, step_num=steps,
                                               live=live):
             if dirty:
-                active_d = push(active_h)
+                active_d = jax.device_put(active_h.copy(), sharding)
             if ptab_dirty:
-                ptab_d = push(cstore.ptab_h)
+                ptab_d = jax.device_put(cstore.ptab_h.copy(), sharding)
             # ---- one masked decode step over every slot -------------------
             if paged:
                 logits, tok, pos, cache, *rec = steps_.decode(
@@ -626,25 +558,22 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
                     slot_rid[s] = -1
                     cstore.release(int(s))
                 active_h[done] = False
-                active_d = push(active_h)
+                active_d = jax.device_put(active_h.copy(), sharding)
                 if paged:
-                    ptab_d = push(cstore.ptab_h)
+                    ptab_d = jax.device_put(cstore.ptab_h.copy(), sharding)
 
     with jax.profiler.TraceAnnotation(SPAN_DRAIN):
-        tok.block_until_ready()                  # reprolint: ok[host-sync] — closes the timed region
-    total_secs = time.time() - t_start
-    decode_secs = max(total_secs - prefill_secs, 1e-9)
-
-    # ---- reconstruct per-request streams (host transfers OFF the clock) ---
+        tok.block_until_ready()                  # reprolint: ok[host-sync] — the last decode step's wait
+    # ---- reconstruct per-request streams (host transfers after the loop) --
     step_counters = {}
     with jax.profiler.TraceAnnotation(SPAN_FETCH, steps=len(trace)):
         for mask, rids, tok_d, _ in trace:
-            # reprolint: ok[host-sync] — off-clock stream reconstruction; timed region already closed
+            # reprolint: ok[host-sync] — stream reconstruction after the loop has drained
             tok_np = np.asarray(jax.device_get(tok_d))
             for s in np.flatnonzero(mask):
                 res[rids[s]]["tokens"].append(int(tok_np[s]))
         if prefill_rec:
-            # reprolint: ok[host-sync] — off-clock fetch of the step records, one call for the wave
+            # reprolint: ok[host-sync] — fetch of the step records after the loop, one call for the wave
             steps_rec, pre_rec = jax.device_get(
                 ([t[3] for t in trace], prefill_rec))
             step_counters = {name: np.stack([r["step"][name]
@@ -675,79 +604,14 @@ def serve_scheduled(cfg: ModelConfig, params, requests: List[Request], *,
         useful += r.max_new_tokens
     decode_tokens = useful - len(order)          # first tokens come from prefill
     return ServeResult(
-        mode="scheduled", store=cstore.kind, requests=res,
+        store=cstore.kind, requests=res,
         slots=slots, max_seq=max_seq, steps=steps,
         useful_tokens=useful, decode_tokens=decode_tokens,
-        prefill_secs=prefill_secs, decode_secs=decode_secs,
-        prefill_tok_s=prompt_tokens / max(prefill_secs, 1e-9),
-        decode_tok_s=decode_tokens / decode_secs,
         occupancy=(occupancy_acc / (steps * slots)) if steps else 0.0,
         latency_steps=_latency_stats(latencies),
         cache_stats=cstore.stats(),
-        extra={"prefill_chunk": prefill_chunk if chunk_ok else 0,
-               "share_prefix": share_ok},
+        prefill_chunk=prefill_chunk if chunk_ok else 0,
+        share_prefix=share_ok,
         step_counters=step_counters,
     )
 
-
-def serve_lockstep(cfg: ModelConfig, model, params, requests: List[Request],
-                   *, slots: int, kernel_backend=None, act_bits=None,
-                   compiled=None, pad_id: int = 0) -> ServeResult:
-    """The pre-scheduler serve loop as a baseline, at the SAME cache width.
-
-    FCFS static batching: requests are grouped ``slots`` at a time in
-    arrival order; each batch pads every prompt to the batch max length and
-    decodes in lock-step for the batch max budget — short requests pay for
-    the batch's longest member, and padded rows decode garbage (exactly the
-    deficiency the scheduler fixes; this baseline exists to be measured
-    against, its outputs are not parity-gated).  Arrival gaps are ignored,
-    which only flatters the baseline."""
-    from repro.launch.serve import compile_serve_steps, serve_requests
-    order = sorted(requests, key=lambda r: (r.arrival, r.rid))
-    if compiled is None:
-        compiled = compile_serve_steps(cfg, kernel_backend=kernel_backend,
-                                       act_bits=act_bits)
-    prefill_secs = decode_secs = 0.0
-    raw_decode_tokens = 0
-    prompt_tokens = 0
-    max_width = 0
-    steps = 0
-    for i in range(0, len(order), slots):
-        group = order[i:i + slots]
-        plen = max(len(r.prompt) for r in group)
-        gen = max(r.max_new_tokens for r in group)
-        prompts = np.full((len(group), plen), pad_id, np.int32)
-        for j, r in enumerate(group):
-            prompts[j, :len(r.prompt)] = r.prompt
-        st = serve_requests(cfg, model, params, prompts, gen=gen,
-                            compiled=compiled, collect_logits=False)
-        prefill_secs += st.prefill_secs
-        decode_secs += st.decode_secs
-        raw_decode_tokens += len(group) * (gen - 1)
-        prompt_tokens += len(group) * plen
-        max_width = max(max_width, plen + gen)
-        steps += gen - 1
-    useful = sum(r.max_new_tokens for r in order)
-    decode_tokens = useful - len(order)
-    decode_secs = max(decode_secs, 1e-9)
-    # every request's latency is its group's padded span (batch max budget),
-    # measured like the scheduler: decode steps from arrival-batch start
-    lats = []
-    for i in range(0, len(order), slots):
-        group = order[i:i + slots]
-        lats += [max(r.max_new_tokens for r in group)] * len(group)
-    return ServeResult(
-        mode="lockstep", store="dense", requests={},
-        slots=slots, max_seq=max_width, steps=steps,
-        useful_tokens=useful, decode_tokens=decode_tokens,
-        prefill_secs=prefill_secs, decode_secs=decode_secs,
-        prefill_tok_s=prompt_tokens / max(prefill_secs, 1e-9),
-        # useful-token goodput: same numerator the scheduler reports
-        decode_tok_s=decode_tokens / decode_secs,
-        occupancy=(decode_tokens / raw_decode_tokens
-                   if raw_decode_tokens else 0.0),
-        latency_steps=_latency_stats(lats),
-        cache_stats={"store": "dense"},
-        extra={"raw_decode_tokens": raw_decode_tokens,
-               "wasted_decode_tokens": raw_decode_tokens - decode_tokens},
-    )

@@ -88,7 +88,7 @@ def test_serve_path_backend_parity(tiny, bits):
     packed = _pack(cfg, m, params, batches, qcfg)
     rng = np.random.default_rng(bits)
     prompts = rng.integers(0, cfg.vocab_size, (2, 12)).astype(np.int32)
-    gate = logits_parity(cfg, m, packed, prompts, gen=4,
+    gate = logits_parity(cfg, packed, prompts, gen=4,
                          atol=5e-2, rtol=2e-2)
     assert gate["steps_compared"] == 4                # prefill + 3 decode
     assert gate["ok"], f"W{bits} backend divergence: {gate}"

@@ -10,13 +10,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _plain_loop import plain_serve
 from repro.configs import get_reduced_config
 from repro.configs.base import QuantConfig
 from repro.core import pack_model, quantize_model
 from repro.core.qtensor import QTensor, pack
 from repro.core.tesseraq import TesseraQConfig
 from repro.launch.scheduler import Request, make_workload, serve_scheduled
-from repro.launch.serve import serve_requests
 from repro.models import get_model
 from repro.models import mla_moe as M
 from repro.models import reference as R
@@ -208,21 +208,19 @@ def test_quantize_pack_serve_alone_parity(backend):
     cfg, packed = _packed()
     assert isinstance(packed["blocks"]["moe"]["w_gate"], QTensor)
     assert isinstance(packed["dense_blocks"]["wkv_b"], QTensor)
-    m = get_model(cfg)
     reqs = make_workload(cfg.vocab_size, n_requests=4, seed=1,
                          prompt_lens=(4, 8), budgets=(2, 5))
     sched = serve_scheduled(cfg, packed, reqs, slots=2,
                             kernel_backend=backend)
     for q in reqs:
-        alone = serve_requests(cfg, m, packed, q.prompt[None],
-                               gen=q.max_new_tokens,
-                               max_seq=sched["max_seq"],
-                               collect_logits=False, kernel_backend=backend)
-        np.testing.assert_array_equal(alone["tokens"][0],
-                                      sched["requests"][q.rid]["tokens"])
+        alone = plain_serve(cfg, packed, q.prompt[None],
+                            gen=q.max_new_tokens, max_seq=sched.max_seq,
+                            kernel_backend=backend)
+        np.testing.assert_array_equal(alone[0],
+                                      sched.requests[q.rid]["tokens"])
     n_moe = cfg.num_layers - cfg.moe.dense_layers
     for q in reqs:
-        experts = sched["requests"][q.rid]["experts"]
+        experts = sched.requests[q.rid]["experts"]
         assert experts.shape == (n_moe, len(q.prompt) + q.max_new_tokens - 1,
                                  cfg.moe.top_k)
         assert ((experts >= 0) & (experts < cfg.moe.num_experts)).all()
@@ -245,9 +243,9 @@ def test_recorded_experts_are_the_tokens_routing():
                          prompt_lens=(5, 9), budgets=(3, 6))
     sched = serve_scheduled(cfg, params, reqs, slots=2)
     for q in reqs:
-        got = sched["requests"][q.rid]["experts"]
+        got = sched.requests[q.rid]["experts"]
         seq = np.concatenate([q.prompt,
-                              sched["requests"][q.rid]["tokens"][:-1]])
+                              sched.requests[q.rid]["tokens"][:-1]])
         cache = m.init_cache(1, len(seq), dtype=jnp.float32)
         _, _, rec = m.prefill_record(params, {"tokens": jnp.asarray(
             seq[None])}, cache)
@@ -271,4 +269,4 @@ def test_paged_chunked_and_tp_are_refused_clearly():
                     max_new_tokens=3)]
     res = serve_scheduled(cfg, model.init_params(jax.random.PRNGKey(0)),
                           reqs, slots=1, prefill_chunk=4)
-    assert res.extra["prefill_chunk"] == 0
+    assert res.prefill_chunk == 0

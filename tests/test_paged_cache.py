@@ -142,7 +142,7 @@ def test_chunked_vs_whole_prefill_agree(dense):
     chunked = serve_scheduled(cfg, params, reqs, slots=2, max_seq=32,
                               prefill_chunk=4)
     _tokens_equal(chunked, whole, reqs)
-    assert chunked.extra["prefill_chunk"] == 4
+    assert chunked.prefill_chunk == 4
 
 
 # -- dense vs paged bit-identity, per family, both backends ------------------
@@ -212,3 +212,27 @@ def test_dense_vs_paged_logits_identity(dense):
     for q in reqs:
         np.testing.assert_array_equal(a.requests[q.rid]["logits"],
                                       b.requests[q.rid]["logits"])
+
+
+# -- memory ------------------------------------------------------------------
+
+def test_paged_pool_bytes_below_dense(dense):
+    """The paged store's reason to exist: on a LONG-TAILED plan, a dense
+    store reserves a full-width lane per slot, while a page pool sized to
+    hold the longest request with room to spare serves the same requests
+    with the same tokens in under half the dense store's bytes (pool plus
+    page table, as allocated)."""
+    cfg, m, params = dense
+    reqs = make_workload(cfg.vocab_size, n_requests=8, seed=19,
+                         prompt_lens=(4, 6), budgets=(2, 4), long_frac=0.25,
+                         long_prompt_lens=(40, 44), long_budgets=(8, 12))
+    assert any(len(r.prompt) >= 40 for r in reqs)          # genuinely long
+    assert sum(len(r.prompt) < 40 for r in reqs) > len(reqs) // 2
+    kw = dict(slots=4, max_seq=56)
+    dense_run = serve_scheduled(cfg, params, reqs, **kw)
+    paged_run = serve_scheduled(cfg, params, reqs, store="paged",
+                                page_size=8, num_pages=12, **kw)
+    _tokens_equal(paged_run, dense_run, reqs)
+    assert paged_run.cache_stats["pages_in_use"] == 0
+    assert (paged_run.cache_stats["cache_bytes"]
+            <= 0.5 * dense_run.cache_stats["cache_bytes"])

@@ -136,9 +136,8 @@ def test_jit_cache_flags_serve_step_builder_in_loop():
 
 
 def test_jit_cache_accepts_memoized_compile_in_loop():
-    # compile_serve_steps/compile_sched_steps memoize per
-    # (cfg, backend, mesh, tp_shard) — looping over them is the blessed
-    # spelling and must pass.
+    # compile_sched_steps memoizes per (cfg, width, backend, mesh,
+    # tp_shard) — looping over it is the blessed spelling and must pass.
     good = """
     from pkg.launch.scheduler import compile_sched_steps
 

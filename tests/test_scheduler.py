@@ -10,12 +10,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _plain_loop import plain_serve
 from repro.configs import get_reduced_config
 from repro.launch import scheduler as S
 from repro.launch.scheduler import (Request, compile_sched_steps,
-                                    make_workload, serve_lockstep,
-                                    serve_scheduled)
-from repro.launch.serve import serve_requests
+                                    make_workload, serve_scheduled)
 from repro.models import get_model
 from repro.models.common import read_slot, write_slot
 
@@ -28,15 +27,15 @@ def dense():
     return cfg, m, params
 
 
-def assert_alone_parity(cfg, m, params, reqs, sched, **serve_kw):
-    """Every scheduled request's tokens == serving it alone (same width)."""
+def assert_alone_parity(cfg, params, reqs, sched, **serve_kw):
+    """Every scheduled request's tokens == serving it alone through the
+    plain loop (same width)."""
     for q in reqs:
-        alone = serve_requests(cfg, m, params, q.prompt[None],
-                               gen=q.max_new_tokens,
-                               max_seq=sched["max_seq"],
-                               collect_logits=False, **serve_kw)
+        alone = plain_serve(cfg, params, q.prompt[None],
+                            gen=q.max_new_tokens, max_seq=sched.max_seq,
+                            **serve_kw)
         np.testing.assert_array_equal(
-            alone["tokens"][0], sched["requests"][q.rid]["tokens"],
+            alone[0], sched.requests[q.rid]["tokens"],
             err_msg=f"rid {q.rid} diverged from standalone serving")
 
 
@@ -73,15 +72,15 @@ def test_finished_request_is_frozen(dense):
     long_ = Request(rid=1, prompt=rng.integers(0, cfg.vocab_size, (9,))
                     .astype(np.int32), max_new_tokens=9)
     sched = serve_scheduled(cfg, params, [short, long_], slots=2, max_seq=24)
-    assert sched["requests"][0]["tokens"].shape == (2,)
-    assert sched["requests"][1]["tokens"].shape == (9,)
-    assert_alone_parity(cfg, m, params, [short, long_], sched)
+    assert sched.requests[0]["tokens"].shape == (2,)
+    assert sched.requests[1]["tokens"].shape == (9,)
+    assert_alone_parity(cfg, params, [short, long_], sched)
     # stretch the neighbor: the finished request's stream must not move
     longer = dataclasses.replace(long_, max_new_tokens=14)
     sched2 = serve_scheduled(cfg, params, [short, longer], slots=2,
                              max_seq=24)
-    np.testing.assert_array_equal(sched["requests"][0]["tokens"],
-                                  sched2["requests"][0]["tokens"])
+    np.testing.assert_array_equal(sched.requests[0]["tokens"],
+                                  sched2.requests[0]["tokens"])
 
 
 # -- admission ---------------------------------------------------------------
@@ -99,21 +98,21 @@ def test_admission_determinism_and_alone_parity(dense):
     s1 = serve_scheduled(cfg, params, reqs, slots=2)
     s2 = serve_scheduled(cfg, params, reqs, slots=2)
     for q in reqs:
-        np.testing.assert_array_equal(s1["requests"][q.rid]["tokens"],
-                                      s2["requests"][q.rid]["tokens"])
-        assert s1["requests"][q.rid]["admit_step"] == \
-            s2["requests"][q.rid]["admit_step"]
-    assert_alone_parity(cfg, m, params, reqs, s1)
+        np.testing.assert_array_equal(s1.requests[q.rid]["tokens"],
+                                      s2.requests[q.rid]["tokens"])
+        assert s1.requests[q.rid]["admit_step"] == \
+            s2.requests[q.rid]["admit_step"]
+    assert_alone_parity(cfg, params, reqs, s1)
     # queueing really happened: someone was admitted after its arrival
-    waits = [s1["requests"][q.rid]["admit_step"] - q.arrival for q in reqs]
+    waits = [s1.requests[q.rid]["admit_step"] - q.arrival for q in reqs]
     assert max(waits) > 0
-    assert s1["latency_steps"]["p99"] >= s1["latency_steps"]["p50"]
+    assert s1.latency_steps["p99"] >= s1.latency_steps["p50"]
 
 
 def test_uniform_workload_matches_lockstep_loop(dense):
     """Parity anchor: on a UNIFORM workload (same prompt len, same budget,
     all arrive at once, slots == requests) the scheduler reproduces the
-    plain lock-step ``serve_requests`` loop token-for-token."""
+    plain lock-step loop over a B-row prefill token-for-token."""
     cfg, m, params = dense
     rng = np.random.default_rng(1)
     prompts = rng.integers(0, cfg.vocab_size, (3, 8)).astype(np.int32)
@@ -121,11 +120,8 @@ def test_uniform_workload_matches_lockstep_loop(dense):
     reqs = [Request(rid=i, prompt=prompts[i], max_new_tokens=gen)
             for i in range(3)]
     sched = serve_scheduled(cfg, params, reqs, slots=3)
-    lock = serve_requests(cfg, m, params, prompts, gen=gen,
-                          max_seq=sched["max_seq"], collect_logits=False)
-    for i in range(3):
-        np.testing.assert_array_equal(lock["tokens"][i],
-                                      sched["requests"][i]["tokens"])
+    lock = plain_serve(cfg, params, prompts, gen=gen, max_seq=sched.max_seq)
+    np.testing.assert_array_equal(lock, sched.token_matrix())
 
 
 # -- compile-once decode -----------------------------------------------------
@@ -139,7 +135,7 @@ def test_decode_compiles_once_across_occupancy(dense):
     comp = compile_sched_steps(cfg, max_seq=14)
     sched = serve_scheduled(cfg, params, reqs, slots=2, max_seq=14,
                             compiled=comp)
-    assert sched["steps"] > 0
+    assert sched.steps > 0
     assert comp.decode._cache_size() == 1
     # a second workload at the same config keeps reusing it
     more = make_workload(cfg.vocab_size, n_requests=3, seed=9,
@@ -174,7 +170,7 @@ def test_scheduler_family_alone_parity(arch):
     reqs = make_workload(cfg.vocab_size, n_requests=4, seed=1,
                          prompt_lens=(4, 8), budgets=(2, 5))
     sched = serve_scheduled(cfg, params, reqs, slots=2)
-    assert_alone_parity(cfg, m, params, reqs, sched)
+    assert_alone_parity(cfg, params, reqs, sched)
 
 
 def test_scheduler_moe_deterministic():
@@ -188,9 +184,9 @@ def test_scheduler_moe_deterministic():
     s1 = serve_scheduled(cfg, params, reqs, slots=2)
     s2 = serve_scheduled(cfg, params, reqs, slots=2)
     for q in reqs:
-        assert s1["requests"][q.rid]["tokens"].shape == (q.max_new_tokens,)
-        np.testing.assert_array_equal(s1["requests"][q.rid]["tokens"],
-                                      s2["requests"][q.rid]["tokens"])
+        assert s1.requests[q.rid]["tokens"].shape == (q.max_new_tokens,)
+        np.testing.assert_array_equal(s1.requests[q.rid]["tokens"],
+                                      s2.requests[q.rid]["tokens"])
 
 
 def test_scheduler_vlm_extras():
@@ -214,9 +210,9 @@ def test_scheduler_vlm_extras():
     s1 = serve_scheduled(cfg, params, reqs, slots=2, max_seq=max_seq)
     s2 = serve_scheduled(cfg, params, reqs, slots=2, max_seq=max_seq)
     for q in reqs:
-        assert s1["requests"][q.rid]["tokens"].shape == (q.max_new_tokens,)
-        np.testing.assert_array_equal(s1["requests"][q.rid]["tokens"],
-                                      s2["requests"][q.rid]["tokens"])
+        assert s1.requests[q.rid]["tokens"].shape == (q.max_new_tokens,)
+        np.testing.assert_array_equal(s1.requests[q.rid]["tokens"],
+                                      s2.requests[q.rid]["tokens"])
 
 
 # -- packed QTensor backends -------------------------------------------------
@@ -241,26 +237,24 @@ def test_scheduler_packed_backend_alone_parity(dense):
     for backend in ("xla", "pallas"):
         sched = serve_scheduled(cfg, packed, reqs, slots=2,
                                 kernel_backend=backend)
-        assert_alone_parity(cfg, m, packed, reqs, sched,
+        assert_alone_parity(cfg, packed, reqs, sched,
                             kernel_backend=backend)
 
 
-# -- lock-step baseline ------------------------------------------------------
+# -- token accounting --------------------------------------------------------
 
-def test_lockstep_baseline_accounting(dense):
-    """The baseline pays for each batch's longest member; its waste and
-    useful-token accounting must line up with the scheduler's."""
+def test_scheduler_token_accounting(dense):
+    """Every request's budget is served, the first token of each from its
+    prefill and the rest from decode steps; occupancy and the latency
+    percentiles are well formed."""
     cfg, m, params = dense
     reqs = make_workload(cfg.vocab_size, n_requests=4, seed=5,
                          prompt_lens=(4, 8), budgets=(2, 8))
-    lock = serve_lockstep(cfg, m, params, reqs, slots=2)
     sched = serve_scheduled(cfg, params, reqs, slots=2)
-    assert lock["useful_tokens"] == sched["useful_tokens"] \
-        == sum(r.max_new_tokens for r in reqs)
-    assert lock["decode_tokens"] == sched["decode_tokens"]
-    assert lock["raw_decode_tokens"] >= lock["decode_tokens"]
-    assert lock["wasted_decode_tokens"] == \
-        lock["raw_decode_tokens"] - lock["decode_tokens"]
+    assert sched.useful_tokens == sum(r.max_new_tokens for r in reqs)
+    assert sched.decode_tokens == sched.useful_tokens - len(reqs)
+    assert 0 < sched.occupancy <= 1
+    assert sched.latency_steps["p50"] <= sched.latency_steps["p99"]
 
 
 # -- collect_logits memory regression ----------------------------------------
@@ -290,30 +284,29 @@ def test_collect_logits_bounded_device_memory(dense):
     spied = dataclasses.replace(comp, decode=counting_decode)
     sched = serve_scheduled(cfg, params, reqs, slots=2, max_seq=20,
                             compiled=spied, collect_logits=True)
-    assert sched["steps"] >= 6                      # a real multi-step run
-    assert len(counts) == sched["steps"]
+    assert sched.steps >= 6                      # a real multi-step run
+    assert len(counts) == sched.steps
     # flat, not linear: the leak made this grow by ~1 per step
     assert max(counts) - min(counts) <= 2, counts
     # and the logits still arrive, host-side, one row per generated token
     for q in reqs:
-        lg = sched["requests"][q.rid]["logits"]
+        lg = sched.requests[q.rid]["logits"]
         assert isinstance(lg, np.ndarray)
         assert lg.shape == (q.max_new_tokens, cfg.vocab_size)
 
 
 def test_collect_logits_matches_alone_serving(dense):
-    """The incrementally-fetched logits are the same ones the standalone
-    loop returns (active rows only, in request order)."""
+    """The incrementally-fetched logits are the same ones the plain loop
+    returns serving the request alone (active rows only, in order)."""
     cfg, m, params = dense
     rng = np.random.default_rng(8)
     req = Request(rid=0, prompt=rng.integers(0, cfg.vocab_size, (6,))
                   .astype(np.int32), max_new_tokens=4)
     sched = serve_scheduled(cfg, params, [req], slots=2, max_seq=16,
                             collect_logits=True)
-    alone = serve_requests(cfg, m, params, req.prompt[None], gen=4,
-                           max_seq=16, collect_logits=True)
-    np.testing.assert_allclose(sched["requests"][0]["logits"],
-                               np.asarray(alone["logits"][0], np.float32),
+    _, alone = plain_serve(cfg, params, req.prompt[None], gen=4,
+                           max_seq=16, logits=True)
+    np.testing.assert_allclose(sched.requests[0]["logits"], alone[0],
                                rtol=1e-5, atol=1e-5)
 
 
@@ -340,7 +333,7 @@ def test_decode_compiles_once_with_pallas_kernels(dense):
     comp = compile_sched_steps(cfg, max_seq=14, kernel_backend="pallas")
     sched = serve_scheduled(cfg, packed, reqs, slots=2, max_seq=14,
                             compiled=comp)
-    assert sched["steps"] > 0
+    assert sched.steps > 0
     assert comp.decode._cache_size() == 1
 
 
@@ -386,8 +379,8 @@ def test_serve_spans_mark_each_phase(dense, tmp_path, store):
     res, spans = _traced_spans(
         tmp_path, lambda: serve_scheduled(cfg, params, reqs, **kw))
     for q in reqs:
-        np.testing.assert_array_equal(res["requests"][q.rid]["tokens"],
-                                      plain["requests"][q.rid]["tokens"])
+        np.testing.assert_array_equal(res.requests[q.rid]["tokens"],
+                                      plain.requests[q.rid]["tokens"])
 
     admits = [x for x in spans if x[0] == S.SPAN_ADMIT]
     assert sorted(x[3]["rid"] for x in admits) == sorted(q.rid for q in reqs)
@@ -430,8 +423,8 @@ def test_serve_spans_one_per_prefill_chunk(dense, tmp_path, store):
     res, spans = _traced_spans(
         tmp_path, lambda: serve_scheduled(cfg, params, reqs, **kw))
     for q in reqs:
-        np.testing.assert_array_equal(res["requests"][q.rid]["tokens"],
-                                      plain["requests"][q.rid]["tokens"])
+        np.testing.assert_array_equal(res.requests[q.rid]["tokens"],
+                                      plain.requests[q.rid]["tokens"])
     chunks = [x[3] for x in spans if x[0] == S.SPAN_CHUNK]
     want = [(q.rid, lo, min(lo + chunk, len(q.prompt)))
             for q in sorted(reqs, key=lambda r: (r.arrival, r.rid))

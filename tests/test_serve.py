@@ -1,12 +1,13 @@
-"""Serving launcher: quant-tag parsing, the FP-baseline branch, and the
-reusable serve loop (the pieces benchmarks/serve_speed.py builds on)."""
+"""Serving launcher: quant-tag parsing, the FP-baseline branch, and
+uniform requests through the one serve loop (``serve_scheduled``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_reduced_config
-from repro.launch.serve import main, parse_quant, serve_requests
+from repro.launch.scheduler import Request, serve_scheduled
+from repro.launch.serve import main, parse_quant
 from repro.models import get_model
 
 
@@ -89,24 +90,32 @@ def test_serve_cli_quantized(capsys):
     assert "calibrating" in out
 
 
-# -- serve_requests ----------------------------------------------------------
+# -- uniform requests --------------------------------------------------------
 
-def test_serve_requests_shapes_and_rates():
+def _uniform(prompts, gen):
+    return [Request(rid=b, prompt=p, max_new_tokens=gen)
+            for b, p in enumerate(prompts)]
+
+
+def test_serve_uniform_shapes_and_accounting():
     cfg = get_reduced_config("tinyllama-1.1b")
     m = get_model(cfg)
     params = m.init_params(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     prompts = rng.integers(0, cfg.vocab_size, (3, 8)).astype(np.int32)
-    r = serve_requests(cfg, m, params, prompts, gen=3)
-    assert r["tokens"].shape == (3, 3)
-    assert r["logits"].shape == (3, 3, cfg.vocab_size)
-    assert r["prefill_tok_s"] > 0 and r["decode_tok_s"] > 0
+    r = serve_scheduled(cfg, params, _uniform(prompts, 3), slots=3,
+                        collect_logits=True)
+    assert r.token_matrix().shape == (3, 3)
+    assert r.logits_matrix().shape == (3, 3, cfg.vocab_size)
+    # all arrive at once over one slot each: every step decodes every row
+    assert r.steps == 2 and r.occupancy == 1.0
+    assert r.useful_tokens == 9 and r.decode_tokens == 6
     # deterministic: same params/prompts -> same generation
-    r2 = serve_requests(cfg, m, params, prompts, gen=3)
-    np.testing.assert_array_equal(r["tokens"], r2["tokens"])
+    r2 = serve_scheduled(cfg, params, _uniform(prompts, 3), slots=3)
+    np.testing.assert_array_equal(r.token_matrix(), r2.token_matrix())
 
 
-def test_serve_requests_decode_continues_prefill():
+def test_serve_uniform_decode_continues_prefill():
     """The first decode step must see the prefill cache: generating
     token-by-token matches a fresh prefill over prompt+generated."""
     cfg = get_reduced_config("tinyllama-1.1b")
@@ -114,10 +123,11 @@ def test_serve_requests_decode_continues_prefill():
     params = m.init_params(jax.random.PRNGKey(1))
     rng = np.random.default_rng(1)
     prompts = rng.integers(0, cfg.vocab_size, (2, 8)).astype(np.int32)
-    r = serve_requests(cfg, m, params, prompts, gen=3)
-    ext = np.concatenate([prompts, r["tokens"][:, :2]], axis=1)
+    toks = serve_scheduled(cfg, params, _uniform(prompts, 3),
+                           slots=2).token_matrix()
+    ext = np.concatenate([prompts, toks[:, :2]], axis=1)
     cache = m.init_cache(2, ext.shape[1] + 1)
     logits2, _ = jax.jit(m.prefill)(params, {"tokens": jnp.asarray(ext)},
                                     cache)
     tok = np.asarray(jnp.argmax(logits2, -1))
-    np.testing.assert_array_equal(tok, r["tokens"][:, 2])
+    np.testing.assert_array_equal(tok, toks[:, 2])

@@ -191,17 +191,14 @@ def test_validate_single_pod():
 
 
 def test_serving_entry_points_reject_multi_pod_mesh():
-    """compile_sched_steps / compile_serve_steps fail fast (before any
+    """compile_sched_steps, the serving entry point, fails fast (before any
     tracing) when handed a multi-pod mesh — serving has no cross-pod path;
     each pod gets its own submesh."""
     from repro.configs import get_reduced_config
     from repro.launch.scheduler import compile_sched_steps
-    from repro.launch.serve import compile_serve_steps
     cfg = get_reduced_config("tinyllama-1.1b")
     with pytest.raises(ValueError, match="compile_sched_steps"):
         compile_sched_steps(cfg, max_seq=32, mesh=FakeProductionMesh)
-    with pytest.raises(ValueError, match="compile_serve_steps"):
-        compile_serve_steps(cfg, mesh=FakeProductionMesh)
 
 
 def test_param_spec_placements_llama3_405b_smoke():

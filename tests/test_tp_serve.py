@@ -10,7 +10,7 @@ Three layers of pins:
   * **TP>1 parity** (needs >= 4 devices, the CI multidevice leg): tokens
     match the no-mesh path exactly; logits match within the documented
     psum tolerance (the in-channel reduction is the one reassociation
-    seam).  Covers the lock-step loop and the scheduler under dense,
+    seam).  Covers the plain loop and the scheduler under dense,
     paged, and chunked-prefill stores — all transfer-guard-clean via the
     explicit ``ServeSpec.place_params``/``place_cache`` placement.
   * **serve_plan pins** (pure shape logic, no devices): the per-leaf
@@ -27,13 +27,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _plain_loop import plain_serve
 from repro.configs import get_reduced_config
 from repro.configs.base import QuantConfig
 from repro.core import pack_model, quantize_model
 from repro.core.qtensor import PACK_FACTOR, QTensor
 from repro.launch.mesh import serve_mesh
 from repro.launch.scheduler import Request, serve_scheduled
-from repro.launch.serve import compile_serve_steps
 from repro.launch.sharding import (ServeSpec, serve_param_specs, serve_plan)
 from repro.models import get_model
 
@@ -74,37 +74,22 @@ def _packed(arch):
     return cfg, model, pack_model(cfg, pq, qmeta, qcfg)
 
 
-def _run_family(cfg, model, params, mesh, tp_shard, *, backend="xla",
+def _run_family(cfg, params, mesh, tp_shard, *, backend="xla",
                 B=2, S=8, gen=3):
-    """Lock-step prefill+decode through the compiled serve steps; returns
+    """The plain prefill+decode loop over the serve steps; returns
     (tokens (B, gen), logits (B, gen, V)) as host arrays."""
-    pstep, dstep = compile_serve_steps(cfg, kernel_backend=backend,
-                                       mesh=mesh, tp_shard=tp_shard)
     rng = np.random.default_rng(1)
     prompts = rng.integers(0, cfg.vocab_size, (B, S)).astype(np.int32)
-    extra = cfg.num_patches if cfg.family == "vlm" else 0
-    cache = model.init_cache(B, S + gen + extra)
-    batch = {"tokens": jnp.asarray(prompts)}
+    extras = {}
     if cfg.family == "encdec":
-        batch["frames"] = jnp.asarray(
-            rng.standard_normal((B, cfg.frontend_len or S, cfg.d_model)),
-            jnp.float32)
+        extras["frames"] = rng.standard_normal(
+            (B, cfg.frontend_len or S, cfg.d_model)).astype(np.float32)
     if cfg.family == "vlm":
-        batch["patches"] = jnp.asarray(
-            rng.standard_normal((B, cfg.num_patches, cfg.d_model)),
-            jnp.float32)
-    lg, cache = pstep(params, batch, cache)
-    tok = jnp.argmax(lg, -1).astype(jnp.int32)
-    pos = jnp.full((B,), S + extra, jnp.int32)
-    toks, lgs = [tok], [lg]
-    for _ in range(gen - 1):
-        lg, cache = dstep(params, cache, tok, pos)
-        tok = jnp.argmax(lg, -1).astype(jnp.int32)
-        pos = pos + 1
-        toks.append(tok)
-        lgs.append(lg)
-    return (np.stack([np.asarray(t) for t in toks], 1),
-            np.stack([np.asarray(g, np.float32) for g in lgs], 1))
+        extras["patches"] = rng.standard_normal(
+            (B, cfg.num_patches, cfg.d_model)).astype(np.float32)
+    return plain_serve(cfg, params, prompts, gen=gen, extras=extras,
+                       kernel_backend=backend, mesh=mesh, tp_shard=tp_shard,
+                       logits=True)
 
 
 # -- TP=1 on a mesh is the identity ------------------------------------------
@@ -112,8 +97,8 @@ def _run_family(cfg, model, params, mesh, tp_shard, *, backend="xla",
 @pytest.mark.parametrize("arch", FAMILY_ARCHS)
 def test_tp1_mesh_bit_identity(arch):
     cfg, model, packed = _packed(arch)
-    t0, l0 = _run_family(cfg, model, packed, None, False)
-    t1, l1 = _run_family(cfg, model, packed, serve_mesh(tp=1), True)
+    t0, l0 = _run_family(cfg, packed, None, False)
+    t1, l1 = _run_family(cfg, packed, serve_mesh(tp=1), True)
     assert np.array_equal(t0, t1)
     assert np.array_equal(l0, l1)
 
@@ -122,8 +107,8 @@ def test_tp1_mesh_bit_identity(arch):
 @pytest.mark.parametrize("arch", FAMILY_ARCHS)
 def test_tp1_mesh_bit_identity_pallas(arch):
     cfg, model, packed = _packed(arch)
-    t0, l0 = _run_family(cfg, model, packed, None, False, backend="pallas")
-    t1, l1 = _run_family(cfg, model, packed, serve_mesh(tp=1), True,
+    t0, l0 = _run_family(cfg, packed, None, False, backend="pallas")
+    t1, l1 = _run_family(cfg, packed, serve_mesh(tp=1), True,
                          backend="pallas")
     assert np.array_equal(t0, t1)
     assert np.array_equal(l0, l1)
@@ -164,8 +149,8 @@ def test_tp1_mesh_sched_bit_identity(store, kw):
 @pytest.mark.parametrize("arch", FAMILY_ARCHS)
 def test_tp4_serve_parity(arch):
     cfg, model, packed = _packed(arch)
-    t0, l0 = _run_family(cfg, model, packed, None, False)
-    t4, l4 = _run_family(cfg, model, packed, serve_mesh(tp=4), True)
+    t0, l0 = _run_family(cfg, packed, None, False)
+    t4, l4 = _run_family(cfg, packed, serve_mesh(tp=4), True)
     assert np.array_equal(t0, t4)
     np.testing.assert_allclose(l0, l4, rtol=5e-3, atol=5e-3)
 

@@ -5,7 +5,8 @@ CPU can ALIAS the numpy buffer instead of copying it; if the same
 function then mutates that buffer in place (``buf[...] = x``), the
 "device" value silently changes under an already-enqueued computation —
 a bit-flip that reproduces only under scheduler-dependent timing.  The
-fix (kept in ``scheduler._push``) is to push ``buf.copy()``.
+fix (kept in ``scheduler.serve_scheduled``'s pushes) is to push
+``buf.copy()``.
 
 Flagged: ``jnp.asarray(X)`` / ``jax.device_put(X)`` where ``X`` is a bare
 name the SAME function also mutates via subscript assignment, augmented

@@ -8,8 +8,7 @@ with a ``# reprolint: hot`` pragma on the def line.
 
 # path suffix (posix) -> names of hot root defs/classes in that module
 HOT_ROOTS = {
-    "launch/scheduler.py": {"serve_scheduled", "serve_lockstep"},
-    "launch/serve.py": {"serve_requests"},
+    "launch/scheduler.py": {"serve_scheduled"},
     "launch/steps.py": {"make_sched_steps", "make_serve_steps",
                         "_make_tp_serve_steps",
                         "make_paged_install_step"},
@@ -19,9 +18,9 @@ HOT_ROOTS = {
 # serve-step builders that construct fresh (shard_map-wrapped) step closures
 # per call: calling one inside a loop rebuilds and recompiles per iteration
 # (the PR 4 recompile class, reachable again via the serve `mesh=` plumbing).
-# compile_serve_steps / compile_sched_steps are memoized behind the
-# per-(cfg, backend, mesh, tp_shard) serve-step caches and deliberately
-# absent — they are the guard the rule points offenders at.
+# compile_sched_steps is memoized behind the per-(cfg, width, backend,
+# mesh, tp_shard) step-set cache and deliberately absent — it is the guard
+# the rule points offenders at.
 SERVE_STEP_BUILDERS = {"make_serve_steps", "make_sched_steps",
                        "_make_tp_serve_steps"}
 

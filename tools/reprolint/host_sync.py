@@ -4,7 +4,8 @@ The serving decode loop and the recon engine's scanned step are timed,
 device-resident code: one stray ``float(x)`` / ``np.asarray(x)`` /
 ``block_until_ready`` forces a device->host round trip per step and turns
 a pipelined loop into a lock-step one (the class of regression PR 5's
-``_push`` aliasing fix and the scheduler's sync accounting guard against).
+push-a-copy aliasing fix and the scheduler's sync accounting guard
+against).
 
 Hot roots come from ``config.HOT_ROOTS`` plus any def carrying a
 ``# reprolint: hot`` pragma; the pass closes over same-module callees by

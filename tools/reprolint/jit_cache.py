@@ -21,8 +21,8 @@ Four shapes of the PR 4 bug class:
    call constructs fresh (possibly shard_map-wrapped) step closures, so
    every iteration re-traces and recompiles — the same regression class
    reachable again through the serving ``mesh=`` plumbing.  Go through
-   ``compile_serve_steps``/``compile_sched_steps`` instead: they memoize
-   per (cfg, backend, mesh, tp_shard) key.
+   ``compile_sched_steps`` instead: it memoizes per (cfg, width, backend,
+   mesh, tp_shard) key.
 """
 from __future__ import annotations
 
@@ -119,9 +119,8 @@ def check(ctx: FileContext):
                 RULE, ctx.path, n.lineno,
                 f"serve-step builder `{name}` called inside a loop: each "
                 f"call builds fresh step closures (a re-trace and recompile "
-                f"per iteration); use compile_serve_steps/"
-                f"compile_sched_steps, which memoize per "
-                f"(cfg, backend, mesh, tp_shard)"))
+                f"per iteration); use compile_sched_steps, which "
+                f"memoizes per (cfg, width, backend, mesh, tp_shard)"))
 
     for fn in _functions(ctx):
         # jitted callables built per call of fn: name = jax.jit(...) or a
