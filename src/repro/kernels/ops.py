@@ -20,7 +20,8 @@ from repro.kernels.decode_attention import (decode_attention,
 from repro.kernels.int8_matmul import int8_matmul
 from repro.kernels.quant_gemv import quant_gemv
 from repro.kernels.quant_gmm import quant_gmm
-from repro.kernels.quant_matmul import quant_matmul, quant_matmul_experts
+from repro.kernels.quant_matmul import (qmm_tiles, quant_matmul,
+                                         quant_matmul_experts)
 from repro.kernels.soft_round import soft_round
 
 # decode batches (M = live slots) at or below this row count dispatch to the
@@ -57,8 +58,11 @@ def _pad_rows_to(x, target, axis=0):
 @functools.partial(jax.jit, static_argnames=("bits", "group_size",
                                              "block_m", "block_n", "block_k"))
 def quant_matmul_op(x, packed, scale, zero, *, bits: int, group_size: int,
-                    block_m=256, block_n=256, block_k=512):
-    """Shape-gluing wrapper: pads M/N/K to tile multiples, trims after.
+                    block_m=None, block_n=None, block_k=512):
+    """Shape-gluing wrapper: pads M to the sublane tile (or to whole M
+    tiles past the row cap), N to whole lane tiles and K to whole K tiles
+    (:func:`quant_matmul.qmm_tiles`), and trims after.  A prompt of a
+    multiple of 16 rows over the cells' widths pads nothing.
 
     K padding covers EVERY K-keyed operand consistently: x columns, packed
     rows (K // pack_factor) and scale/zero rows (K // group_size) all grow
@@ -68,16 +72,16 @@ def quant_matmul_op(x, packed, scale, zero, *, bits: int, group_size: int,
     M, K = x.shape
     N = packed.shape[1]
     ppb = PACK_FACTOR[bits]
-    # no row-floor: callers this small belong on the decode GEMV (see
-    # qtensor_matmul), and padding 1..7 live rows up to 8 just burns MXU rows
-    bm = min(block_m, M)
-    bn = min(block_n, N)
     bk, Kp = _snap_block_k(block_k, K, group_size, ppb, bits)
-    xp = _pad_to(_pad_rows_to(x, Kp, axis=1), bm, 0)
-    out = quant_matmul(xp,
-                       _pad_to(_pad_rows_to(packed, Kp // ppb), bn, 1),
-                       _pad_to(_pad_rows_to(scale, Kp // group_size), bn, 1),
-                       _pad_to(_pad_rows_to(zero, Kp // group_size), bn, 1),
+    bm, bn, Mp, Np = qmm_tiles(M, Kp, N, ppb=ppb, group_size=group_size,
+                               bk=bk, itemsize=jnp.dtype(x.dtype).itemsize,
+                               block_m=block_m, block_n=block_n)
+    out = quant_matmul(_pad_rows_to(_pad_rows_to(x, Kp, 1), Mp),
+                       _pad_rows_to(_pad_rows_to(packed, Kp // ppb), Np, 1),
+                       _pad_rows_to(_pad_rows_to(scale, Kp // group_size),
+                                    Np, 1),
+                       _pad_rows_to(_pad_rows_to(zero, Kp // group_size),
+                                    Np, 1),
                        bits=bits, group_size=group_size,
                        block_m=bm, block_n=bn, block_k=bk,
                        interpret=_interpret())
@@ -136,7 +140,8 @@ def qtensor_matmul(x: jax.Array, w: QTensor) -> jax.Array:
     flattened rows — one token per live slot) take the packed GEMV, which
     applies scale and zero per group to each group's partial product;
     prefill-sized batches keep the MXU-tiled quant_matmul, which
-    dequantizes each weight tile and shares it across 256-row M tiles."""
+    dequantizes each weight tile once per call and passes every row of
+    the call past it."""
     if w.act_scale is not None:
         x = x / w.act_scale.astype(x.dtype)
     lead = x.shape[:-1]
@@ -155,21 +160,25 @@ def qtensor_matmul(x: jax.Array, w: QTensor) -> jax.Array:
 @functools.partial(jax.jit, static_argnames=("bits", "group_size",
                                              "block_m", "block_n", "block_k"))
 def quant_matmul_experts_op(a, packed, scale, zero, *, bits: int,
-                            group_size: int, block_m=256, block_n=256,
+                            group_size: int, block_m=None, block_n=None,
                             block_k=512):
-    """Expert-batched shape glue: pads M/N/K (per-expert shapes are
-    homogeneous, so padding is shared) and trims after."""
+    """Expert-batched shape glue: pads M/N/K as ``quant_matmul_op`` does
+    (per-expert shapes are homogeneous, so padding is shared) and trims
+    after."""
     E, M, K = a.shape
     N = packed.shape[2]
     ppb = PACK_FACTOR[bits]
-    bm = min(block_m, M)
-    bn = min(block_n, N)
     bk, Kp = _snap_block_k(block_k, K, group_size, ppb, bits)
+    bm, bn, Mp, Np = qmm_tiles(M, Kp, N, ppb=ppb, group_size=group_size,
+                               bk=bk, itemsize=jnp.dtype(a.dtype).itemsize,
+                               block_m=block_m, block_n=block_n)
     out = quant_matmul_experts(
-        _pad_to(_pad_rows_to(a, Kp, axis=2), bm, 1),
-        _pad_to(_pad_rows_to(packed, Kp // ppb, axis=1), bn, 2),
-        _pad_to(_pad_rows_to(scale, Kp // group_size, axis=1), bn, 2),
-        _pad_to(_pad_rows_to(zero, Kp // group_size, axis=1), bn, 2),
+        _pad_rows_to(_pad_rows_to(a, Kp, axis=2), Mp, axis=1),
+        _pad_rows_to(_pad_rows_to(packed, Kp // ppb, axis=1), Np, axis=2),
+        _pad_rows_to(_pad_rows_to(scale, Kp // group_size, axis=1), Np,
+                     axis=2),
+        _pad_rows_to(_pad_rows_to(zero, Kp // group_size, axis=1), Np,
+                     axis=2),
         bits=bits, group_size=group_size,
         block_m=bm, block_n=bn, block_k=bk,
         interpret=_interpret())

@@ -1,8 +1,8 @@
 """Pallas TPU kernel: decode-shaped packed GEMV.
 
-``quant_matmul`` is prefill-shaped: 256-row M tiles share a full dequant
-of each weight tile among many activation rows.  Decode has 1..32 rows
-(the live slots), so a dequant per weight would be shared by a handful of
+``quant_matmul`` is prefill-shaped: it dequantizes each weight tile once
+per call and passes every prompt row past it.  Decode has 1..64 rows (the
+live slots), so a dequant per weight would be shared by a handful of
 products, and its vector work, not the HBM stream or the MXU, would set
 the pace.  This kernel takes the affine dequant off the per-weight path:
 

@@ -9,8 +9,17 @@ to the TPU memory hierarchy:
     what moves the HBM roofline term;
   * unpack is a vector shift+mask on the VPU (no shared-memory bank games —
     the TPU analogue of Triton's fast unpack is simply lane-wise bit ops);
-  * dequant (code - zero) * scale is fused in VMEM, then fed to the MXU with
-    128-aligned tiles and an fp32 VMEM accumulator across the K grid axis.
+  * dequant (code - zero) * scale is fused in VMEM, once per (bk x bn) tile
+    per call, into a scratch tile in the activation dtype; every row of the
+    call's ``x`` then passes that one tile on the MXU, ``_SUB_M`` rows a
+    dot, into an fp32 VMEM accumulator across the K grid axis.
+
+The dequant is vector work of ~15 ps a weight on a v5e, several times a
+tile's MXU time at a few hundred rows, so it is done once per call: the
+call's M extent is one block whenever that block fits ``_VMEM_BUDGET``
+(:func:`qmm_tiles`, sized from the call's static shapes), and the grid is
+``(N // bn, K // bk)``.  Only a call past that row cap gets a leading M
+axis of equal tiles, each of which dequantizes again.
 
 Group boundaries must align with the K tile (bk % group_size == 0 or
 group_size % bk == 0), enforced by the wrapper in ops.py.
@@ -32,6 +41,14 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.qtensor import PACK_FACTOR
 
 _SUBLANES = 8     # f32 rows per vreg: dynamic row offsets must align to it
+_LANES = 128
+# what a grid step's blocks (double-buffered) and scratch may take: a v5e
+# has 128 MiB of VMEM; this holds one block of 4096 rows at 512 columns
+# over any K, in bf16 or float32, with room for the compiler's own scratch
+_VMEM_BUDGET = 48 * 2**20
+_BLOCK_N = (512, 256, 128)
+# rows of x per dot against the dequantized tile
+_SUB_M = 256
 
 
 def unpack_tile(p, ppb: int, fbits: int):
@@ -97,26 +114,52 @@ def dequant_tile(packed, s, z, *, bits: int, dtype):
     return w.reshape(bk, bn).astype(dtype)
 
 
-def _qmm_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *,
+def _each_rows(rows: int, fn):
+    """``fn(pl.ds(r0, n))`` over ``rows`` rows in blocks of ``_SUB_M``: a
+    rolled loop over the whole blocks, then the remainder (a multiple of
+    the sublane tile) at a static offset."""
+    full, rem = divmod(rows, _SUB_M)
+    if full:
+        def body(i, carry):
+            fn(pl.ds(pl.multiple_of(i * _SUB_M, _SUB_M), _SUB_M))
+            return carry
+        jax.lax.fori_loop(0, full, body, 0)
+    if rem:
+        fn(pl.ds(full * _SUB_M, rem))
+
+
+def _qmm_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, w_ref, *,
                 bits: int, nk: int, bk: int, group_size: int, k_axis: int):
-    """One (bm, bn) output tile, accumulated over the K grid axis
-    ``k_axis`` (2, or 3 when a squeezed expert axis leads the grid)."""
+    """One (bm, bn) output block, accumulated over the K grid axis
+    ``k_axis`` (the grid's last): the K tile's weights are dequantized once
+    into ``w_ref`` and every row of ``x_ref`` passes them, ``_SUB_M`` rows
+    a dot."""
     k = pl.program_id(k_axis)
+    rows = x_ref.shape[0]
 
     @pl.when(k == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        def zero(r):
+            acc_ref[r, :] = jnp.zeros((r.size, acc_ref.shape[1]),
+                                      jnp.float32)
+        _each_rows(rows, zero)
 
-    rows = functools.partial(tile_group_rows, k=k, bk=bk,
-                             group_size=group_size)
-    w = dequant_tile(p_ref[...], rows(s_ref), rows(z_ref), bits=bits,
-                     dtype=x_ref.dtype)
-    acc_ref[...] += jnp.dot(x_ref[...], w,
-                            preferred_element_type=jnp.float32)
+    group_rows = functools.partial(tile_group_rows, k=k, bk=bk,
+                                   group_size=group_size)
+    w_ref[...] = dequant_tile(p_ref[...], group_rows(s_ref),
+                              group_rows(z_ref), bits=bits,
+                              dtype=x_ref.dtype)
+
+    def accumulate(r):
+        acc_ref[r, :] += jnp.dot(x_ref[r, :], w_ref[...],
+                                 preferred_element_type=jnp.float32)
+    _each_rows(rows, accumulate)
 
     @pl.when(k == nk - 1)
     def _done():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        def store(r):
+            o_ref[r, :] = acc_ref[r, :].astype(o_ref.dtype)
+        _each_rows(rows, store)
 
 
 def check_group_tile(bk: int, group_size: int):
@@ -132,15 +175,151 @@ def check_group_tile(bk: int, group_size: int):
                          f"need a divisor or a multiple of {_SUBLANES}")
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def row_align(itemsize: int) -> int:
+    """Rows of one sublane tile of x: 8 at float32, 16 at bfloat16."""
+    return _SUBLANES * 4 // itemsize
+
+
+def _vmem_parts(bn: int, bk: int, *, ppb: int, group_size: int, K: int,
+                itemsize: int):
+    """``(fixed, per_row)``: the VMEM bytes of a grid step at ``bn``
+    columns are ``fixed + bm * per_row``.  Per row: x and the output
+    blocks twice (the pipeline's double buffer) and the float32
+    accumulator.  Fixed: the packed codes and the K-resident scale and
+    zero strips twice, the dequantized tile, the dequant's temporaries
+    (int32 codes, float32 codes and product) and a row block's (a dot's
+    float32 result and the accumulator rows it adds to)."""
+    lanes_k, lanes_n = _round_up(bk, _LANES), _round_up(bn, _LANES)
+    per_row = 2 * (lanes_k + lanes_n) * itemsize + lanes_n * 4
+    codes = _round_up(bk // ppb, 4 * _SUBLANES) * lanes_n
+    strips = 2 * strip_rows(K // group_size) * lanes_n * 4
+    fixed = (2 * (codes + strips) + bk * lanes_n * itemsize
+             + 3 * bk * lanes_n * 4 + 2 * _SUB_M * lanes_n * 4)
+    return fixed, per_row
+
+
+def vmem_bytes(bm: int, bn: int, bk: int, **kw) -> int:
+    """VMEM a grid step of ``bm`` rows and ``bn`` columns needs
+    (:func:`_vmem_parts`)."""
+    fixed, per_row = _vmem_parts(bn, bk, **kw)
+    return fixed + bm * per_row
+
+
+def qmm_tiles(M: int, K: int, N: int, *, ppb: int, group_size: int, bk: int,
+              itemsize: int, block_m=None, block_n=None):
+    """``(bm, bn, Mp, Np)``: the row and column blocks of a call of ``M``
+    rows over K x N, and the row and column counts to pad to.
+
+    M pads to the sublane tile only.  Columns: ``N`` itself when it is at
+    most one lane tile, else ``N`` padded to whole lane tiles and the
+    widest of ``_BLOCK_N`` that divides it.  The row cap at each width is
+    the most rows whose grid step fits ``_VMEM_BUDGET``, and ``block_m``
+    when given and smaller.  The first width whose cap holds every row
+    gives one M block, so the call dequantizes each weight tile once;
+    past every cap, the widest width and equal M tiles of at most its cap.
+    ``block_n`` caps the width (below one lane tile, only in interpret
+    mode)."""
+    align = row_align(itemsize)
+    rows = _round_up(M, align)
+    cap_n = block_n or _BLOCK_N[0]
+    if N <= min(cap_n, _LANES):
+        Np, widths = N, (N,)
+    elif cap_n < _LANES:
+        Np, widths = _round_up(N, cap_n), (cap_n,)
+    else:
+        Np = _round_up(N, _LANES)
+        widths = tuple(w for w in _BLOCK_N if w <= cap_n and Np % w == 0)
+
+    def row_cap(bn):
+        fixed, per_row = _vmem_parts(bn, bk, ppb=ppb, group_size=group_size,
+                                     K=K, itemsize=itemsize)
+        cap = (_VMEM_BUDGET - fixed) // per_row // align * align
+        if block_m is not None:
+            cap = min(cap, _round_up(block_m, align))
+        return max(cap, align)
+
+    for bn in widths:
+        if rows <= row_cap(bn):
+            return rows, bn, rows, Np
+    bn = widths[0]
+    mt = -(-rows // row_cap(bn))
+    bm = _round_up(-(-rows // mt), align)
+    return bm, bn, mt * bm, Np
+
+
+def _qmm_call(x, packed, scale, zero, *, bits: int, group_size: int,
+              block_m, block_n, block_k: int, interpret: bool):
+    """The pallas_call of :func:`quant_matmul` (``x`` 2-D) and of
+    :func:`quant_matmul_experts` (a leading expert axis on every operand,
+    squeezed out of every block, so the body is the same)."""
+    lead = x.shape[:-2]
+    M, K = x.shape[-2:]
+    N = packed.shape[-1]
+    ppb = PACK_FACTOR[bits]
+    itemsize = jnp.dtype(x.dtype).itemsize
+    bk = min(block_k, K)
+    assert K % bk == 0, (K, bk)
+    check_group_tile(bk, group_size)
+    bm, bn, Mp, Np = qmm_tiles(M, K, N, ppb=ppb, group_size=group_size,
+                               bk=bk, itemsize=itemsize, block_m=block_m,
+                               block_n=block_n)
+    assert (Mp, Np) == (M, N), ("pad M and N to the tiles first (see "
+                                "ops.quant_matmul_op)", M, N, Mp, Np)
+    nk = K // bk
+    ns = strip_rows(K // group_size)
+    mt = M // bm
+    # a leading M axis only for a call past the row cap
+    grid = lead + ((mt,) if mt > 1 else ()) + (N // bn, nk)
+
+    def spec(block, index):
+        def imap(*g):
+            e, (j, k) = g[:len(lead)], g[-2:]
+            i = g[len(lead)] if mt > 1 else 0
+            return e + index(i, j, k)
+        return pl.BlockSpec((None,) * len(lead) + block, imap)
+
+    kernel = functools.partial(_qmm_kernel, bits=bits, nk=nk, bk=bk,
+                               group_size=group_size, k_axis=len(grid) - 1)
+    # a quarter over the count, asked for only past the 16 MiB default
+    ask = vmem_bytes(bm, bn, bk, ppb=ppb, group_size=group_size, K=K,
+                     itemsize=itemsize) * 5 // 4
+    return pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[
+            spec((bm, bk), lambda i, j, k: (i, k)),
+            spec((bk // ppb, bn), lambda i, j, k: (k, j)),
+            # K-resident scale/zero strips: fetched once per column block
+            spec((ns, bn), lambda i, j, k: (0, j)),
+            spec((ns, bn), lambda i, j, k: (0, j)),
+        ],
+        out_specs=spec((bm, bn), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct(lead + (M, N), x.dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
+                        pltpu.VMEM((bk, bn), x.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * (len(grid) - 1)
+            + ("arbitrary",),
+            vmem_limit_bytes=ask if ask > 16 * 2**20 else None),
+        interpret=interpret,
+    )(x, packed, scale, zero)
+
+
 def quant_matmul(x: jax.Array, packed: jax.Array, scale: jax.Array,
                  zero: jax.Array, *, bits: int, group_size: int,
-                 block_m: int = 256, block_n: int = 256, block_k: int = 512,
+                 block_m=None, block_n=None, block_k: int = 512,
                  interpret: bool = False) -> jax.Array:
     """x: (M, K) bf16/f32; packed: (K//ppb, N) uint8; scale/zero: (K//g, N).
 
-    Returns (M, N) in x.dtype.  All of M, N, K must divide by the block
-    sizes (the ops.py wrapper pads); block_k must be a multiple of
-    group_size or vice versa.
+    Returns (M, N) in x.dtype.  M and N must already be the padded counts
+    :func:`qmm_tiles` gives (the ops.py wrapper pads), and K must divide by
+    block_k, a multiple of group_size or a divisor of it.  ``block_m`` and
+    ``block_n`` cap the row and column blocks below what the shapes and
+    the VMEM budget give (tests force several M tiles with them).
     """
     M, K = x.shape
     ppb = PACK_FACTOR[bits]
@@ -163,38 +342,14 @@ def quant_matmul(x: jax.Array, packed: jax.Array, scale: jax.Array,
             "tensor-parallel serving these are SHARD-local shapes — an "
             "in-channel split must take whole quant groups (serve_plan "
             "requires ng % tp == 0)")
-    bm, bn, bk = min(block_m, M), min(block_n, N), min(block_k, K)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
-    check_group_tile(bk, group_size)
-    nk = K // bk
-    ns = strip_rows(K // group_size)
-
-    grid = (M // bm, N // bn, nk)
-    kernel = functools.partial(_qmm_kernel, bits=bits, nk=nk, bk=bk,
-                               group_size=group_size, k_axis=2)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk // ppb, bn), lambda i, j, k: (k, j)),
-            # K-resident scale/zero strips: fetched once per (i, j)
-            pl.BlockSpec((ns, bn), lambda i, j, k: (0, j)),
-            pl.BlockSpec((ns, bn), lambda i, j, k: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(x, packed, scale, zero)
+    return _qmm_call(x, packed, scale, zero, bits=bits,
+                     group_size=group_size, block_m=block_m,
+                     block_n=block_n, block_k=block_k, interpret=interpret)
 
 
 def quant_matmul_experts(x: jax.Array, packed: jax.Array, scale: jax.Array,
                          zero: jax.Array, *, bits: int, group_size: int,
-                         block_m: int = 256, block_n: int = 256,
-                         block_k: int = 512,
+                         block_m=None, block_n=None, block_k: int = 512,
                          interpret: bool = False) -> jax.Array:
     """Expert-batched fused dequant-matmul in ONE pallas_call.
 
@@ -202,7 +357,7 @@ def quant_matmul_experts(x: jax.Array, packed: jax.Array, scale: jax.Array,
     Returns (E, M, N) in x.dtype.  The expert dim is folded into the grid
     (leading parallel axis) instead of unrolling one kernel launch per
     expert — each expert's packed tiles are still DMA'd exactly once.
-    Same divisibility contract as quant_matmul, enforced per expert.
+    Same tiles and padding contract as quant_matmul, per expert.
     """
     E, M, K = x.shape
     ppb = PACK_FACTOR[bits]
@@ -216,31 +371,6 @@ def quant_matmul_experts(x: jax.Array, packed: jax.Array, scale: jax.Array,
         raise ValueError(
             f"expert scale/zero shapes {scale.shape}/{zero.shape} "
             f"inconsistent with (E={E}, K={K}, group_size={group_size})")
-    bm, bn, bk = min(block_m, M), min(block_n, N), min(block_k, K)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
-    check_group_tile(bk, group_size)
-    nk = K // bk
-    ns = strip_rows(ng)
-
-    # the expert dim is squeezed out of every block, so the body is the
-    # single-matrix kernel with its K axis moved to program_id(3)
-    kernel = functools.partial(_qmm_kernel, bits=bits, nk=nk, bk=bk,
-                               group_size=group_size, k_axis=3)
-    grid = (E, M // bm, N // bn, nk)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, bm, bk), lambda e, i, j, k: (e, i, k)),
-            pl.BlockSpec((None, bk // ppb, bn), lambda e, i, j, k: (e, k, j)),
-            pl.BlockSpec((None, ns, bn), lambda e, i, j, k: (e, 0, j)),
-            pl.BlockSpec((None, ns, bn), lambda e, i, j, k: (e, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((None, bm, bn), lambda e, i, j, k: (e, i, j)),
-        out_shape=jax.ShapeDtypeStruct((E, M, N), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(x, packed, scale, zero)
+    return _qmm_call(x, packed, scale, zero, bits=bits,
+                     group_size=group_size, block_m=block_m,
+                     block_n=block_n, block_k=block_k, interpret=interpret)

@@ -1,5 +1,8 @@
 """Per-kernel shape/dtype sweeps, allclose against the ref.py oracles
 (interpret mode executes the Pallas body on CPU)."""
+import sys
+from pathlib import Path
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -169,6 +172,101 @@ def test_quant_matmul_k_padding(bits, K, group, block_k):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=1e-3, atol=1e-3)
+
+
+# -- one M block a call: each weight tile dequantized once ------------------
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("group,block_k", [
+    (32, 64),      # bk % group_size == 0: two groups per K tile
+    (128, 64),     # group_size % bk == 0: each group spans two K tiles
+])
+@pytest.mark.parametrize("M,block_m,tiles", [
+    (600, None, 1),   # past one row block: the rolled row loop + remainder
+    (33, None, 1),    # M not a multiple of 16: pads to the sublane tile
+    (100, None, 1),
+    (48, 48, 1),      # M equal to the row cap: still one block
+    (96, 48, 2),      # past the cap: equal M tiles, each dequantizing
+    (100, 32, 4),
+])
+def test_quant_matmul_rows_per_call(M, block_m, tiles, group, block_k,
+                                    dtype):
+    """A call's rows are one M block up to the row cap, padded only to the
+    sublane tile; past the cap, equal tiles of at most the cap.  Both
+    agree with the ref.py oracle across the group/tile branches."""
+    from repro.kernels.quant_matmul import qmm_tiles, row_align
+    bits, K, N = 2, 256, 256
+    itemsize = jnp.dtype(dtype).itemsize
+    bm, bn, Mp, Np = qmm_tiles(M, K, N, ppb=4, group_size=group, bk=block_k,
+                               itemsize=itemsize, block_m=block_m)
+    align = row_align(itemsize)
+    assert (Mp // bm, Np, bn) == (tiles, N, N)
+    assert bm % align == 0 and Mp - M < tiles * align
+    rng = np.random.default_rng(M * 7 + group)
+    codes = rng.integers(0, 1 << bits, (K, N)).astype(np.uint8)
+    scale = (rng.random((K // group, N)).astype(np.float32) + 0.5) * 0.1
+    zero = rng.integers(0, 1 << bits, (K // group, N)).astype(np.float32)
+    packed = pack(jnp.asarray(codes), bits, axis=0)
+    x = jnp.asarray(rng.normal(size=(M, K)), dtype)
+    got = quant_matmul_op(x, packed, jnp.asarray(scale), jnp.asarray(zero),
+                          bits=bits, group_size=group, block_m=block_m,
+                          block_k=block_k)
+    want = ref.quant_matmul_ref(x, packed, jnp.asarray(scale),
+                                jnp.asarray(zero), bits=bits,
+                                group_size=group)
+    tol = 1e-3 if dtype == jnp.float32 else 5e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+
+
+def _cell_prefill_linears():
+    """``(name, K, N, M)`` of every packed-GEMM call the benchmark cells'
+    B=1 prefills make: Mistral-7B's block linears at each prompt length of
+    its mixes (and at its 4096 sliding window), Moonlight's non-routed
+    linears (latent attention, shared experts, the dense first layer) at
+    each of its own."""
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.append(root)
+    from bench import weights as W
+    from bench import weights_mla_moe as WM
+    from bench.run import cell_spec
+    mistral = [cell_spec(c) for c in ("mistral7b-w2-decode",
+                                      "mistral7b-w2-prefill")]
+    lens = sorted({n for c in mistral for n in c["mix"]["prompt_lens"]}
+                  | {mistral[0]["config"]["sliding_window"]})
+    out = [(f"mistral-{name}", K, N, M)
+           for name, (K, N) in W.linear_shapes(mistral[0]["config"]).items()
+           for M in lens]
+    moon = cell_spec("moonlight-w2-decode")
+    cfg = moon["config"]
+    shapes = dict(WM.linear_shapes(cfg, True))
+    shapes.update({f"shared_{k}": v for k, v in
+                   WM.linear_shapes(cfg, False)["shared"].items()})
+    return out + [(f"moonlight-{name}", K, N, M)
+                  for name, (K, N) in shapes.items()
+                  for M in moon["mix"]["prompt_lens"]]
+
+
+def test_cells_prefill_calls_plan_one_m_block():
+    """Every prefill call of the benchmark cells plans ONE M block within
+    the VMEM budget, with no row padding (the prompts are multiples of
+    16) and N padded only to whole lane tiles (Moonlight's kv_a, 576 ->
+    640): each weight tile is dequantized once per call."""
+    from repro.kernels.quant_matmul import (_VMEM_BUDGET, qmm_tiles,
+                                            vmem_bytes)
+    calls = _cell_prefill_linears()
+    assert {(K, N) for _, K, N, _ in calls} >= {(4096, 14336), (14336, 4096),
+                                                (2048, 576)}
+    for name, K, N, M in calls:
+        bm, bn, Mp, Np = qmm_tiles(M, K, N, ppb=4, group_size=128, bk=512,
+                                   itemsize=2)
+        need = vmem_bytes(bm, bn, 512, ppb=4, group_size=128, K=K,
+                          itemsize=2)
+        assert (bm, Mp) == (M, M), (name, M, bm, Mp)
+        assert Np == -(-N // 128) * 128 and Np % bn == 0, (name, N, Np, bn)
+        assert need <= _VMEM_BUDGET, (name, M, need)
 
 
 # -- decode-shaped fused dequant-GEMV ---------------------------------------

@@ -4,7 +4,8 @@ TPU v5e chip, at tinyllama-1.1b widths (K=2048, N=5632; 4 KV heads of 64,
 (K=2048, N=768 per expert), and for the grouped expert matmul and the
 latent decode attention at Moonlight-16B-A3B's (64 experts of 2048 x 1408;
 a 576-wide latent read by 16 heads over 64 slots of 1472), and for the
-decode step's in-place cache write at Mistral-7B's and Moonlight's caches.
+decode step's in-place cache write at Mistral-7B's and Moonlight's caches,
+and for the prefill GEMM at a whole prompt's rows (one M block a call).
 
 Interpret mode, which the rest of the suite runs the kernels in, accepts
 layouts the chip's compiler refuses (uint8 -> float casts, scale blocks
@@ -106,6 +107,21 @@ def test_quant_matmul_compiles(one_chip, bits, group, block_k):
                                              group_size=group,
                                              block_k=block_k),
              *_packed_operands(one_chip, bits, group, 256))
+
+
+# a B=1 prefill's whole prompt as one M block (W2g128): Mistral-7B's
+# gate/up and down at the long-prompt cell's 3072 rows, the prefill at
+# its 4096 sliding window, and Moonlight's kv_a (N 576, padded to 640 by
+# the wrapper) at 1280 rows; a VMEM overrun shows here, not on the chip
+@pytest.mark.parametrize("M,k,n", [
+    (3072, 4096, 14336), (3072, 14336, 4096), (4096, 14336, 4096),
+    (1280, 2048, 640),
+], ids=["mistral-gate-m3072", "mistral-down-m3072", "mistral-down-m4096",
+        "moonlight-kv_a-m1280"])
+def test_quant_matmul_one_block_compiles(one_chip, M, k, n):
+    _compile(lambda x, p, s, z: quant_matmul(x, p, s, z, bits=2,
+                                             group_size=128),
+             *_packed_operands(one_chip, 2, 128, M, k=k, n=n))
 
 
 def test_quant_matmul_experts_compiles(one_chip):
@@ -227,6 +243,29 @@ def test_quant_gemv_is_one_named_kernel_inside_a_step(one_chip,
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "jit(quant_gemv_op)/" not in text.replace(
         "jit(quant_gemv_op)/pallas_call", "")
+
+
+def test_quant_matmul_is_one_named_kernel_inside_a_step(one_chip,
+                                                       monkeypatch):
+    """Each prefill GEMM call of a prompt of a multiple of 16 rows inside
+    a larger jitted program is one Mosaic kernel named
+    ``quant_matmul_op`` and nothing else of its own (no pad, no slice):
+    ``qmm_roofline`` counts these kernels, one per block linear."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    x, p, s, z = _packed_operands(one_chip, 2, 128, 1040, k=4096, n=1024)
+    a = jax.ShapeDtypeStruct((4096,), jnp.bfloat16, sharding=one_chip)
+
+    def step(x, p, s, z, a):
+        h = ops.quant_matmul_op(x / a, p, s, z, bits=2, group_size=128)
+        return ops.quant_matmul_op(x * 2, p, s, z, bits=2,
+                                   group_size=128) + h
+
+    text = jax.jit(step).lower(x, p, s, z, a).compile().as_text()
+    assert _custom_call_names(text) == {"quant_matmul_op"}
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "jit(quant_matmul_op)/" not in text.replace(
+        "jit(quant_matmul_op)/pallas_call", "")
 
 
 def test_decode_attention_keeps_its_name_inside_a_step(one_chip,
